@@ -30,6 +30,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pyspark_tf_gke_tpu.ops.attention import (
@@ -38,8 +39,7 @@ from pyspark_tf_gke_tpu.ops.attention import (
     ulysses_attention,
 )
 from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
-from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES
-from pyspark_tf_gke_tpu.parallel.compat import shard_map
+from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES, ambient_mesh
 
 
 # Shared flash-vs-dense dispatch constants (ops/pallas/common.py) —
@@ -151,11 +151,13 @@ class FusedLayerNorm(nn.Module):
         if fused:
             from pyspark_tf_gke_tpu.ops.pallas.layernorm import fused_layernorm
 
-            n_shards = _data_shards(self.mesh, "dp", "fsdp", "sp")
-            if n_shards > 1:
-                # LN is row-wise: shard rows (batch and, if 3D, seq) and
-                # run the kernel per shard. Scale/bias replicated; the
-                # optional residual shards like x.
+            if self.mesh is not None and self.mesh.size > 1:
+                # Any multi-device jit: Mosaic kernels are never
+                # partitioned automatically. LN is row-wise: shard rows
+                # (batch and, if 3D, seq) and run the kernel per shard
+                # (on a tp-only mesh every shard runs all rows).
+                # Scale/bias replicated; the optional residual shards
+                # like x.
                 row_spec = (
                     P(DATA_AXES, "sp", None) if x.ndim == 3 else P(DATA_AXES, None)
                 )
@@ -185,11 +187,10 @@ class FusedLayerNorm(nn.Module):
         # tests/test_embedding.py). Pinning the broadcast results makes
         # the one reshard happen on the LN OUTPUT, an ordinary tensor.
         def pin(t):
-            from jax.interpreters import pxla
             from jax.sharding import NamedSharding
 
-            mesh = pxla.thread_resources.env.physical_mesh
-            if mesh is None or mesh.empty:
+            mesh = ambient_mesh()
+            if mesh is None:
                 return t
             return jax.lax.with_sharding_constraint(
                 t, NamedSharding(

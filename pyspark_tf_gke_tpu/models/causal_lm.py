@@ -33,12 +33,12 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 
 from pyspark_tf_gke_tpu.models.bert import _data_shards, _dense
 from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
 from pyspark_tf_gke_tpu.parallel.sharding import mesh_extent_for
-from pyspark_tf_gke_tpu.parallel.compat import shard_map
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
 
 NEG_INF = -1e30
@@ -354,18 +354,37 @@ class CausalSelfAttention(nn.Module):
         vp.value = vp.value.at[page, off].set(
             vrows.astype(vp.value.dtype), mode="drop")
         idx.value = jnp.maximum(idx.value, jnp.max(pos) + 1)
-        scales = dict(
-            k_scales=ks.value if ks is not None else None,
-            v_scales=vs.value if vs is not None else None)
-        if s == 1:
-            out = paged_attention(
-                q[:, 0], kp.value, vp.value, bt.value, pos[:, 0] + 1,
-                **scales)
-            return out[:, None]                              # [B,1,H,D]
         # fills = live tokens INCLUDING the chunk (positions must be
         # consecutive per row — the chunked-prefill contract)
-        return paged_attention_chunk(
-            q, kp.value, vp.value, bt.value, pos[:, -1] + 1, **scales)
+        fills = pos[:, -1] + 1
+        pool = [kp.value, vp.value]
+        if ks is not None:
+            pool += [ks.value, vs.value]
+
+        def attend(qq, tbl, fl, kpg, vpg, *sc):
+            sc = dict(zip(("k_scales", "v_scales"), sc))
+            if s == 1:
+                return paged_attention(qq[:, 0], kpg, vpg, tbl, fl,
+                                       **sc)[:, None]        # [B,1,H,D]
+            return paged_attention_chunk(qq, kpg, vpg, tbl, fl, **sc)
+
+        if self.mesh is not None and self.mesh.size > 1:
+            # Same rationale as _causal_attend: the partitioner can't
+            # split an opaque Pallas call — run it per shard. Heads are
+            # independent, so each tp shard attends its own query heads
+            # against its own slice of the page pool; the block table
+            # and fill levels are replicated. KV heads that tp does not
+            # divide (MQA on tp=2) stay whole on every shard.
+            from jax.sharding import PartitionSpec as P
+
+            ax = "tp" if hkv % self.mesh.shape["tp"] == 0 else None
+            heads, rep = P(None, None, ax, None), P()
+            attend = shard_map(
+                attend, mesh=self.mesh,
+                in_specs=(heads, rep, rep, heads, heads)
+                + (P(None, None, ax),) * (len(pool) - 2),
+                out_specs=heads, check_vma=False)
+        return attend(q, bt.value, fills, *pool)
 
     def _cache_vars(self, b, h, d, dtype):
         cfg = self.cfg

@@ -79,13 +79,12 @@ def _pin_to_batch_sharding(x: jnp.ndarray) -> jnp.ndarray:
     channel sharding, which the dp x fsdp partitioner could only reach
     by involuntary full rematerialization (a replicate-then-reshard
     warning per block on the MULTICHIP trail). No-op off-mesh."""
-    from jax.interpreters import pxla
     from jax.sharding import PartitionSpec as P
 
-    from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES
+    from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES, ambient_mesh
 
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty or mesh.shape.get("fsdp", 1) <= 1:
+    mesh = ambient_mesh()
+    if mesh is None or mesh.shape.get("fsdp", 1) <= 1:
         return x
     from jax.sharding import NamedSharding
 
@@ -99,10 +98,10 @@ def _pin_to_param_sharding(w: jnp.ndarray) -> jnp.ndarray:
     use), read from the ambient mesh context — a no-op off-mesh or
     without an fsdp axis, so single-chip and dp-only runs are
     untouched."""
-    from jax.interpreters import pxla
+    from pyspark_tf_gke_tpu.parallel.mesh import ambient_mesh
 
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty or mesh.shape.get("fsdp", 1) <= 1:
+    mesh = ambient_mesh()
+    if mesh is None or mesh.shape.get("fsdp", 1) <= 1:
         return w
     from jax.sharding import NamedSharding
 

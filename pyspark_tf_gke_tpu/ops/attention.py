@@ -28,9 +28,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from pyspark_tf_gke_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 

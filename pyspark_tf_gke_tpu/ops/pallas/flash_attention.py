@@ -31,14 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-only import; interpret mode works without it
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -129,7 +122,7 @@ def _flash_fwd_bh(q, k, v, bias, segs=None, *, causal: bool, block_q: int,
 
     kernel = functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
                                scale=scale, use_segs=segs is not None)
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     grid = (bh, s // block_q)
     qblock = pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j), **mem)
     full_row = pl.BlockSpec((1, 1, s), lambda i, j: (i, 0, 0), **mem)
@@ -285,7 +278,7 @@ def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
         delta = delta - delta_shift.astype(jnp.float32)
     use_segs = segs is not None
 
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     full = lambda last: pl.BlockSpec((1, s, last), lambda i, j: (i, 0, 0), **mem)
     full_row = pl.BlockSpec((1, 1, s), lambda i, j: (i, 0, 0), **mem)
     qrow = pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j), **mem)

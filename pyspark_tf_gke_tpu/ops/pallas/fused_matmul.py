@@ -51,14 +51,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover - exercised only on TPU images
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_M = 448   # divides B*H*W for every ResNet-50 stage at B=64k
 DEFAULT_BLOCK_N = 512
@@ -72,14 +65,10 @@ def _pick(n: int, desired: int, multiple: int) -> int:
 
 
 def _mem(spec_kwargs=None):
-    return {} if _VMEM is None else {"memory_space": _VMEM}
+    return {"memory_space": pltpu.VMEM}
 
 
 def _scratch(shape):
-    if pltpu is None:  # pragma: no cover - env without pallas TPU support
-        raise RuntimeError(
-            "fused_matmul needs jax.experimental.pallas.tpu for VMEM "
-            "scratch accumulators; unavailable in this environment")
     return pltpu.VMEM(shape, jnp.float32)
 
 

@@ -22,14 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -61,7 +54,7 @@ def _ln_forward(x2, scale, bias, eps, block_rows, interpret, r2=None):
     block_rows = min(block_rows, n)
     if n % block_rows:
         raise ValueError(f"rows {n} not divisible by block_rows {block_rows}")
-    mem = {} if _VMEM is None else {"memory_space": _VMEM}
+    mem = {"memory_space": pltpu.VMEM}
     row_spec = pl.BlockSpec((block_rows, d), lambda i: (i, 0), **mem)
     vec_spec = pl.BlockSpec((d,), lambda i: (0,), **mem)
     if r2 is None:

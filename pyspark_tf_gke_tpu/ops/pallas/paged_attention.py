@@ -51,11 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only import; interpret mode works without it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -127,8 +123,9 @@ def _paged_kernel(bt_ref, fills_ref, q_ref, kp_ref, vp_ref, *rest,
     # Shapes: q [1, S, H, D] (S = s_q query tokens — 1 on the decode
     # path); kp/vp [1, P, Hkv, D] (the table-gathered page); with quant
     # also ks/vs [1, P, Hkv] f32; o [1, S, H, D]; scratch m/l
-    # [S*H, 1] f32, acc [S*H, D] f32, rows laid out kv-head-major:
-    # row = hk * (S * G) + s * G + g.
+    # [H*S, 1] f32, acc [H*S, D] f32, rows laid out head-major:
+    # row = h * S + s (a KV head's G query heads are adjacent, so its
+    # group is the contiguous row block [hk*G*S, (hk+1)*G*S)).
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -157,22 +154,33 @@ def _paged_kernel(bt_ref, fills_ref, q_ref, kp_ref, vp_ref, *rest,
             v = (v.astype(jnp.float32) * vs_ref[0][..., None]).astype(q.dtype)
         # Per-KV-head 2D dots (Mosaic wants plain matmuls): each cached
         # KV head is read ONCE for its whole query group x chunk — the
-        # GQA bandwidth win survives paging and chunking alike.
+        # GQA bandwidth win survives paging and chunking alike. The
+        # group's query rows are stacked one head at a time: Mosaic
+        # has no layout for collapsing [S, G, D] to [S*G, D] when G is
+        # not a sublane-tile multiple (12 heads over 4 KV heads: G=3).
+        def group_rows(hk):
+            if s == 1:
+                return q[0, hk * g:(hk + 1) * g]             # [G, D]
+            return jnp.concatenate(
+                [q[:, hk * g + gi, :] for gi in range(g)], axis=0)
+
         rows = []
         for hk in range(hkv):
             rows.append(jax.lax.dot_general(
-                q[:, hk * g:(hk + 1) * g].reshape(s * g, d), k[:, hk, :],
+                group_rows(hk), k[:, hk, :],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32))
-        scores = jnp.concatenate(rows, axis=0) * scale   # [S*H, P] f32
-        # Causal mask per query row: row r holds query s_idx = (r mod
-        # S*G) // G at absolute position fill - S + s_idx; it sees keys
-        # at positions <= that. S = 1 degenerates to k_pos < fill (the
+        scores = jnp.concatenate(rows, axis=0) * scale   # [H*S, P] f32
+        # Causal mask per query row: row r holds query s_idx = r mod S
+        # at absolute position fill - S + s_idx; it sees keys at
+        # positions <= that. S = 1 degenerates to k_pos < fill (the
         # decode mask).
         k_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (s * h, page_size), 1)
-        r = jax.lax.broadcasted_iota(jnp.int32, (s * h, page_size), 0)
-        q_abs = fill - s + (r % (s * g)) // g
+        q_abs = fill - 1
+        if s > 1:
+            r = jax.lax.broadcasted_iota(jnp.int32, (s * h, page_size), 0)
+            q_abs = fill - s + r % s
         scores = jnp.where(k_pos <= q_abs, scores, NEG_INF)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, scores.max(axis=1, keepdims=True))
@@ -195,16 +203,15 @@ def _paged_kernel(bt_ref, fills_ref, q_ref, kp_ref, vp_ref, *rest,
         l = l_ref[:]
         valid = m > NEG_INF / 2      # query rows with >= 1 live key
         l = jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(valid, acc_ref[:] / l, 0.0)      # [S*H, D]
+        out = jnp.where(valid, acc_ref[:] / l, 0.0)      # [H*S, D]
         s, h, d = o_ref.shape[1:]
-        g = h // hkv
         if s_q == 1:
-            # kv-head-major row layout IS head order when S = 1 — keep
-            # the decode path free of the transpose below
+            # head-major rows ARE head order when S = 1 — keep the
+            # decode path free of the transpose below
             o_ref[0] = out.reshape(1, h, d).astype(o_ref.dtype)
         else:
-            out = out.reshape(hkv, s, g, d).transpose(1, 0, 2, 3)
-            o_ref[0] = out.reshape(s, h, d).astype(o_ref.dtype)
+            o_ref[0] = out.reshape(h, s, d).transpose(1, 0, 2).astype(
+                o_ref.dtype)
 
 
 def _paged_pallas(q, k_pages, v_pages, block_table, fills, k_scales,
@@ -305,7 +312,7 @@ def paged_attention_chunk(
     if interpret is None:
         from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu
 
-        if pltpu is None or not on_tpu():
+        if not on_tpu():
             return paged_attention_chunk_reference(
                 q, k_pages, v_pages, block_table, fills,
                 k_scales=k_scales, v_scales=v_scales)

@@ -1,28 +1,6 @@
-"""jax API compatibility shims.
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` to
-``jax.shard_map``, renaming ``check_rep`` to ``check_vma`` along the
-way. The sharded model/op code targets the new spelling; this shim
-keeps it importable on the older jax the CI image carries (where the
-experimental module is the only one and only ``check_rep`` exists).
-"""
+"""Flax metadata helper for the sharded trainers."""
 
 from __future__ import annotations
-
-try:  # jax >= 0.6: public API, check_vma kwarg
-    from jax import shard_map as _shard_map
-
-    _NEW_API = True
-except ImportError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NEW_API = False
-
-
-def shard_map(f, *args, **kwargs):
-    if not _NEW_API and "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, *args, **kwargs)
 
 
 def unbox_without_constraint(tree):

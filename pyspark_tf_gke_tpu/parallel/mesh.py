@@ -213,6 +213,18 @@ def mesh_from_spec(
     return make_mesh(ici_axes or None, devices)
 
 
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``with mesh:`` block (the context the
+    trainer and the serving engine run their jits under), or None
+    outside one. Readable at trace time inside ``jit``, which
+    ``jax.sharding.get_mesh`` is not; ``get_abstract_mesh`` only sees
+    ``jax.set_mesh`` contexts, which this code base does not enter."""
+    from jax._src.mesh import thread_resources
+
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
+
+
 def batch_sharding(mesh: Mesh, ndim: int = 1, extra: Optional[P] = None) -> NamedSharding:
     """Sharding for a host-fed batch: leading dim split over the data axes.
 

@@ -25,8 +25,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from pyspark_tf_gke_tpu.parallel.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES

@@ -182,27 +182,34 @@ def load_serving_bundle(bundle_dir: str) -> Tuple[CausalLM, Any, dict]:
     else:
         abstract_candidates = [abstract]
 
+    # Restore onto ONE named device, never into the layout recorded at
+    # export: a bundle written by a 4-chip trainer records 4-device
+    # shardings, and params restored into those make every serving jit
+    # a multi-device program (which Mosaic kernels refuse outside a
+    # shard_map) — and a bundle from another topology would not load at
+    # all. Callers place the result (shard_params_for_serving).
     if jax.process_count() > 1:
-        # Multi-process restore: orbax refuses sharding-less abstract
-        # arrays here ("sharding ... should be specified [and] concrete").
         # Every process restores the FULL array onto its own CPU backend
         # device — host RAM, NOT an accelerator: a model that needs tp
         # to fit would OOM a single chip's HBM before
-        # shard_params_for_serving ever placed its shards.
+        # shard_params_for_serving ever placed its shards. (orbax also
+        # refuses sharding-less abstract arrays here.)
         try:
-            host_dev = jax.local_devices(backend="cpu")[0]
+            target = jax.local_devices(backend="cpu")[0]
         except RuntimeError:  # pragma: no cover - cpu backend always exists
-            host_dev = jax.local_devices()[0]
-        local = jax.sharding.SingleDeviceSharding(host_dev)
+            target = jax.local_devices()[0]
+    else:
+        target = jax.devices()[0]
+    local = jax.sharding.SingleDeviceSharding(target)
 
-        def pin(leaf):
-            if isinstance(leaf, jax.ShapeDtypeStruct):
-                return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                            sharding=local)
-            return leaf
+    def pin(leaf):
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                        sharding=local)
+        return leaf
 
-        abstract_candidates = [jax.tree.map(pin, c)
-                               for c in abstract_candidates]
+    abstract_candidates = [jax.tree.map(pin, c)
+                           for c in abstract_candidates]
 
     ckptr = ocp.StandardCheckpointer()
     try:

@@ -338,4 +338,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from pyspark_tf_gke_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(sys.argv[1:])

@@ -1320,6 +1320,7 @@ class BundleServer:
                     f"draft bundle vocab {self.draft_model.cfg.vocab_size} "
                     f"!= target vocab {self.model.cfg.vocab_size}")
             if mesh is not None:
+                from pyspark_tf_gke_tpu.models import CausalLM
                 from pyspark_tf_gke_tpu.train.serving import (
                     shard_params_for_serving,
                 )
@@ -1327,6 +1328,7 @@ class BundleServer:
                 # the draft rides the same mesh — unsharded draft arrays
                 # would forfeit its tp memory/latency win and break on
                 # multi-host meshes
+                self.draft_model = CausalLM(self.draft_model.cfg, mesh=mesh)
                 self.draft_params = shard_params_for_serving(
                     self.draft_model, self.draft_params, mesh)
         self.bundle_dir = bundle_dir
@@ -1479,15 +1481,22 @@ class BundleServer:
 
         model, params, meta = retry_with_backoff(
             _load, op="bundle_load", give_up_on=_permanent)
-        if self._int8_kv and not model.cfg.kv_cache_quant:
+        cfg = model.cfg
+        if self._int8_kv and not cfg.kv_cache_quant:
             # cache layout is a serving-time choice (params unchanged) —
             # allow turning it on for bundles exported without the flag
             import dataclasses
 
+            cfg = dataclasses.replace(cfg, kv_cache_quant=True)
+        if cfg is not model.cfg or self.mesh is not None:
             from pyspark_tf_gke_tpu.models import CausalLM
 
-            model = CausalLM(
-                dataclasses.replace(model.cfg, kv_cache_quant=True))
+            # the model carries the mesh it runs under, as in the
+            # trainer: on the TPU its Pallas calls (layernorm, flash,
+            # paged attention) must sit in a shard_map inside any
+            # multi-device jit — Mosaic kernels are never partitioned
+            # automatically
+            model = CausalLM(cfg, mesh=self.mesh)
         tokenizer = get_tokenizer(meta.get("tokenizer", "byte"))
         if tokenizer.vocab_size > model.cfg.vocab_size:
             raise ValueError(
@@ -1748,6 +1757,7 @@ class BundleServer:
     # -- health ----------------------------------------------------------
 
     def health(self) -> dict:
+        devices = jax.devices()
         return {
             "status": "draining" if self.draining else "ok",
             "bundle": self.bundle_dir,
@@ -1757,7 +1767,11 @@ class BundleServer:
             "vocab_size": self.model.cfg.vocab_size,
             "max_seq_len": self.model.cfg.max_seq_len,
             "tokenizer": self.meta.get("tokenizer", "byte"),
-            "n_devices": len(jax.devices()),
+            "n_devices": len(devices),
+            # what the replica runs on, for a parent that must stay off
+            # JAX itself (chip_smoke.py; one process per chip)
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
             "processes": jax.process_count(),
             "tp": dict(self.mesh.shape).get("tp", 1) if self.mesh else 1,
             "speculative_draft": self.draft_bundle_dir or None,
@@ -3066,10 +3080,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "(scheduling, collect bookkeeping, delivery) "
                         "overlaps the in-flight chunk's compute "
                         "(default 1 — the async engine core; 0 = the "
-                        "serial A/B reference loop; measured +52%% "
-                        "engine tokens/sec over a remote-attached chip "
-                        "at chunk 64 depth 1; depth >=2 is single-host "
-                        "only — the engine enforces it; multi-host: "
+                        "serial A/B reference loop; the gain on a "
+                        "local chip is not measured; depth >=2 is "
+                        "single-host only — the engine enforces it; "
+                        "multi-host: "
                         "the chunk is announced dispatch-only and the "
                         "gathers replay at OP_CB_COLLECT)")
     p.add_argument("--schedule", choices=("fifo", "longest"),
@@ -3385,4 +3399,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from pyspark_tf_gke_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -376,7 +376,7 @@ class Trainer:
             specs = nn.get_partition_spec(abstract)
             shardings = nn.logical_to_mesh_sharding(specs, self.mesh, self.logical_rules)
 
-            # Unbox WITHOUT the in-jit constraint (see the shim's
+            # Unbox WITHOUT the in-jit constraint (see the helper's
             # docstring — raw-Partitioned LOGICAL names crash strict
             # NamedSharding validation); the jit's ``out_shardings``
             # below is the placement authority either way.
@@ -525,9 +525,9 @@ class Trainer:
 
     def multi_step(self, state: TrainState, batch: Dict[str, jax.Array], k: int):
         """Run ``k`` train steps on the same batch inside ONE dispatch via an
-        on-device ``lax.scan``. Amortizes per-dispatch host/RPC latency —
-        essential for honest step-time measurement on remote-attached chips
-        and for small models where dispatch dominates. Returns
+        on-device ``lax.scan``. Amortizes per-dispatch host latency — for
+        step-time measurement and for small models where dispatch
+        dominates. Returns
         (state, stacked metrics with leading dim k)."""
         if self._train_step is None:
             self._build_steps()

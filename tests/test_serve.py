@@ -56,6 +56,9 @@ def test_healthz(endpoint):
     assert health["quantized"] is True
     assert health["vocab_size"] == 259
     assert health["max_seq_len"] == 64
+    # a parent that may not touch JAX reads the replica's device here
+    assert (health["platform"], health["device_kind"]) == (
+        jax.devices()[0].platform, jax.devices()[0].device_kind)
 
 
 def test_generate_over_the_wire_batches_mixed_lengths(endpoint):

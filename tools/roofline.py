@@ -235,11 +235,9 @@ def analyze(name: str, batch: int, measure: bool, steps: int = 30) -> dict:
                 round(min(1.0, ai_upper * v5e_bw / v5e_peak), 4),
             ]
         if measure:
-            # --measure on a CPU backend means the chip dropped between
-            # the caller's probe and this run — there is no hardware
-            # timing to take, and a silent analytic-only JSON would be
-            # mistaken for a hardware roofline (bench_watch writes
-            # stdout to roofline_hw.json on rc=0).
+            # --measure on a CPU backend: there is no hardware timing
+            # to take, and a silent analytic-only JSON would be mistaken
+            # for a hardware roofline.
             out["measure_refused"] = ("backend is CPU - no hardware "
                                       "step timing; re-run on a TPU")
         return out
