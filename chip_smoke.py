@@ -25,10 +25,12 @@ the legs run as child processes one after another, each releasing the
 chip on exit. Children inherit JAX_COMPILATION_CACHE_DIR, or else share
 <repo>/.jax_cache (pyspark_tf_gke_tpu/utils/compile_cache.py).
 
-Exit code 0 and a last stdout line
-  {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}, ...}
-only when every leg passed on a TPU. With no accelerator it exits 3 and
-prints no result. ``--tiny`` is the CPU rehearsal (toy width,
+The last two stdout lines are JSON objects: first the report (device,
+jax/jaxlib/libtpu versions, pass/fail and seconds per leg, compile-cache
+location; also written to <out>/report.json), then the verdict, exactly
+  {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}
+Exit code 0 and "ok": true only when every leg passed on a TPU. With no
+accelerator it exits 3 and prints no result. ``--tiny`` is the CPU rehearsal (toy width,
 JAX_PLATFORMS=cpu, kernels in interpret mode, platform expected `cpu`);
 ``tools/smoke_check.py`` stays the CPU functional gate.
 """
@@ -481,14 +483,22 @@ def main(argv=None) -> int:
         legs["multi_chip"] = {"skipped": f"{n_dev} device(s)"}
 
     ok = all(leg.get("ok", True) for leg in legs.values())
-    print(json.dumps({
-        "ok": ok, "tiny": args.tiny, "device": ctx["device"],
+    dev = ctx["device"]
+    device = {"platform": str(dev["platform"]), "kind": str(dev["kind"]),
+              "count": int(dev["count"])}
+    report = json.dumps({
+        "ok": ok, "tiny": args.tiny, "device": device,
         "versions": ctx["versions"], "legs": legs,
         "compile_cache": {"dir": ctx["cache_dir"],
                           "entries_before": cache_before,
                           "entries_after": cache_entries(ctx["cache_dir"])},
         "seconds": round(time.monotonic() - t_start, 1),
-    }, separators=(",", ":")))
+    }, separators=(",", ":"))
+    with open(os.path.join(out, "report.json"), "w") as fh:
+        fh.write(report + "\n")
+    print(report)
+    # the verdict: these keys and no others, last on stdout
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 
 

@@ -108,9 +108,12 @@ def test_chip_smoke_tiny_runs_every_leg_on_the_cpu(tmp_path):
         env=_env(XLA_FLAGS="--xla_force_host_platform_device_count=4"),
         text=True, capture_output=True, timeout=900)
     assert done.returncode == 0, done.stdout[-2000:] + done.stderr[-4000:]
-    report = json.loads(done.stdout.strip().splitlines()[-1])
+    report, verdict = map(json.loads, done.stdout.strip().splitlines()[-2:])
+    # the last line carries exactly the verdict keys; the report precedes it
+    assert verdict == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 4}}
     assert report["ok"] is True and report["tiny"] is True
-    assert report["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+    assert report["device"] == verdict["device"]
     assert set(report["versions"]) == {"jax", "jaxlib", "libtpu"}
     for leg in ("kernels", "trainer", "server", "trainer_4chip",
                 "server_4chip"):
