@@ -69,6 +69,8 @@ TINY = dict(
     corpus_bytes=1 << 16)
 
 LEG_TIMEOUT_S = {"kernels": 420, "trainer": 600, "server": 600}
+ADMIN_TOKEN = "chip-smoke"        # the server is this run's own, on loopback
+PROFILE_STEPS = 6
 
 
 class LegFailed(Exception):
@@ -230,10 +232,11 @@ def leg_trainer(ctx: dict, name: str, extra: list) -> dict:
 # ---- leg: server -----------------------------------------------------------
 
 
-def http_json(url: str, payload=None, timeout: float = 300.0):
+def http_json(url: str, payload=None, timeout: float = 300.0, headers=None):
     data = None if payload is None else json.dumps(payload).encode()
     req = urllib.request.Request(
-        url, data=data, headers={"Content-Type": "application/json"})
+        url, data=data, headers={"Content-Type": "application/json",
+                                 **(headers or {})})
     with urllib.request.urlopen(req, timeout=timeout) as resp:
         return resp.status, json.loads(resp.read())
 
@@ -301,8 +304,11 @@ def leg_server(ctx: dict, name: str, bundle: str, extra: list) -> dict:
             "--prefill-chunk", str(cfg["prefill_chunk"]),
             "--drain-timeout", "60", *extra]
     deadline = time.monotonic() + LEG_TIMEOUT_S["server"]
+    # the token opens POST /admin/profile: one capture of the engine's
+    # steps, so that the gate also drives the profiler on this device
+    env = dict(ctx["env"], SERVE_ADMIN_TOKEN=ADMIN_TOKEN)
     with open(log_path, "w") as log:
-        proc = subprocess.Popen(argv, cwd=REPO, env=ctx["env"], stdout=log,
+        proc = subprocess.Popen(argv, cwd=REPO, env=env, stdout=log,
                                 stderr=log, start_new_session=True)
     try:
         return _drive_server(ctx, name, proc, url, deadline)
@@ -346,7 +352,18 @@ def _drive_server(ctx, name, proc, url, deadline) -> dict:
         ("stream", {"prompts": [text[:40]], "stream": True}),
     ]
     tokens = {}
+    trace_dir = os.path.join(ctx["out"], "traces", name)
     for tag, body in requests:
+        if tag == "long":
+            # armed here: the capture spans the chunked prefill's and the
+            # decode's steps (engine.<phase> annotations, the paged
+            # kernel's own name) and closes itself after PROFILE_STEPS
+            status, _ = http_json(
+                url + "/admin/profile",
+                {"output_dir": trace_dir, "steps": PROFILE_STEPS},
+                headers={"X-Admin-Token": ADMIN_TOKEN})
+            if status != 202:
+                raise LegFailed(f"/admin/profile: HTTP {status}")
         body["max_new_tokens"] = budget
         left = max(deadline - time.monotonic(), 1.0)
         if body.get("stream"):
@@ -386,6 +403,10 @@ def _drive_server(ctx, name, proc, url, deadline) -> dict:
     if rebuilds:
         raise LegFailed(f"serve_engine_rebuilds_total = {rebuilds}: an "
                         f"engine step failed and the server hid it")
+    written = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+               for f in fs if f.endswith(".xplane.pb")]
+    if not written:
+        raise LegFailed(f"the profiler capture left no trace in {trace_dir}")
 
     proc.send_signal(signal.SIGTERM)
     try:
@@ -397,7 +418,8 @@ def _drive_server(ctx, name, proc, url, deadline) -> dict:
     return {"new_tokens": tokens, "prefix_hits": eng["prefix_cache"]["hits"],
             "prefill_chunks": eng["prefill_chunks"],
             "pages_total": eng["paged"]["pages_total"],
-            "peak_pages_in_use": eng["paged"]["peak_pages_in_use"]}
+            "peak_pages_in_use": eng["paged"]["peak_pages_in_use"],
+            "profile_trace": os.path.relpath(written[0], ctx["out"])}
 
 
 # ---- driver ----------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 The reference platform's only observability was the Spark Web UI and
 ``kubectl top`` polling (SURVEY §5); our reproduction grew three
-disjoint stores in response — ``utils/profiling.StepTimer``,
+disjoint stores in response — an ad-hoc step timer,
 ``BundleServer.metrics_text``'s ad-hoc counters, and the bench
 evidence trail — that could not be correlated. This package is the
 single metrics plane they all converge on:
@@ -21,7 +21,12 @@ single metrics plane they all converge on:
   mounts;
 * :mod:`~pyspark_tf_gke_tpu.obs.trace` — end-to-end request tracing:
   W3C ``traceparent`` propagation, contextvar-carried spans, and a
-  bounded flight recorder with sampling + always-on slow capture.
+  bounded flight recorder with sampling + always-on slow capture; the
+  one span primitive (``span`` / ``annotate``) that also writes to the
+  profiler's trace, and the process-default tracer;
+* :mod:`~pyspark_tf_gke_tpu.obs.compiles` — JAX's own trace / lower /
+  compile as spans under whatever program span caused them, and
+  ``runtime_jit_compiles_total{fun}``.
 
 Naming scheme (enforced by tools/smoke_check.py's duplicate lint and
 documented in docs/OBSERVABILITY.md): ``<plane>_<thing>_<unit>`` with
@@ -58,10 +63,14 @@ from pyspark_tf_gke_tpu.obs.stepstats import (
 from pyspark_tf_gke_tpu.obs.trace import (
     Span,
     TraceRecorder,
+    annotate,
     current_span,
     current_trace_id,
     format_traceparent,
+    get_tracer,
     parse_traceparent,
+    set_tracer,
+    span,
     use_span,
 )
 
@@ -85,9 +94,13 @@ __all__ = [
     "flops_per_token",
     "Span",
     "TraceRecorder",
+    "annotate",
     "current_span",
     "current_trace_id",
     "format_traceparent",
+    "get_tracer",
     "parse_traceparent",
+    "set_tracer",
+    "span",
     "use_span",
 ]

@@ -523,6 +523,20 @@ def platform_families(registry: Optional[MetricsRegistry] = None) -> dict:
             "train_epochs_total", "Epochs completed"),
         "train_last_loss": r.gauge(
             "train_last_loss", "Mean loss of the last completed epoch"),
+        "train_input_wait_ms": r.histogram(
+            "train_input_wait_ms",
+            "Per optimizer step, time the loop waited for its next "
+            "device batch(es) (the train.input_wait annotation): near "
+            "zero while prefetch keeps up, the step time when the job "
+            "is input-starved"),
+        # JAX's own compiles (obs/compiles.py): which functions this
+        # process compiled or loaded from the cache, and how often
+        "runtime_jit_compiles_total": r.counter(
+            "runtime_jit_compiles_total",
+            "Backend compiles (persistent-cache loads included) by "
+            "Python function name; a count that grows in steady state "
+            "is a recompile (see the train_recompile event)",
+            labelnames=("fun",)),
         # serve plane (canonical names; BundleServer.metrics_text keeps
         # the legacy pyspark_tf_gke_tpu_serve_* aliases)
         "serve_requests_total": r.counter(

@@ -64,6 +64,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from pyspark_tf_gke_tpu.obs.trace import annotate
+
 # The phase vocabulary (docs/OBSERVABILITY.md "Step telemetry"):
 #   expire      — deadline sweep (queued + in-slot expiry)
 #   schedule    — admission work: DWRR/FIFO picks, prefill pieces,
@@ -166,22 +168,26 @@ class StepRecord:
         """Time a phase. Nesting pauses the enclosing phase: the
         elapsed span is attributed to exactly one phase at any
         instant, which is what makes the phase-sum-vs-wall invariant
-        checkable."""
-        now = self._clock()
-        if self._stack:
-            top = self._stack[-1]
-            self.phases[top[0]] = (self.phases.get(top[0], 0.0)
-                                   + (now - top[1]) * 1000.0)
-        self._stack.append([name, now])
-        try:
-            yield
-        finally:
+        checkable. The phase is also an ``engine.<phase>`` annotation
+        in the profiler's trace (``obs.trace.annotate``: inert unless a
+        capture runs), entered outside the clock reads so that it adds
+        nothing to the phase it names."""
+        with annotate("engine." + name):
             now = self._clock()
-            top = self._stack.pop()
-            self.phases[name] = (self.phases.get(name, 0.0)
-                                 + (now - top[1]) * 1000.0)
             if self._stack:
-                self._stack[-1][1] = now  # parent resumes from here
+                top = self._stack[-1]
+                self.phases[top[0]] = (self.phases.get(top[0], 0.0)
+                                       + (now - top[1]) * 1000.0)
+            self._stack.append([name, now])
+            try:
+                yield
+            finally:
+                now = self._clock()
+                top = self._stack.pop()
+                self.phases[name] = (self.phases.get(name, 0.0)
+                                     + (now - top[1]) * 1000.0)
+                if self._stack:
+                    self._stack[-1][1] = now  # parent resumes from here
 
     @property
     def device_wait_ms(self) -> float:

@@ -15,10 +15,20 @@ pieces, first token, terminal outcome) a human can read back from
 
 Design constraints, in order:
 
-* **Dependency-free.** stdlib only — no jax, no HTTP. The router (a
-  jax-free process) and the engine (which must never import HTTP
+* **Dependency-free.** stdlib only — no jax import, no HTTP. The router
+  (a jax-free process) and the engine (which must never import HTTP
   machinery) both use it; the engine annotates through a span attached
-  to the request object, so it stays transport-blind.
+  to the request object, so it stays transport-blind. The one touch of
+  jax is :func:`annotate`, which looks the module up in ``sys.modules``
+  and is a no-op where nobody imported it.
+* **One span primitive.** :func:`span` is how the program opens a host
+  span (the trainer's ``train.*`` spans, the pipeline's stages when they
+  want one): a ``jax.profiler.TraceAnnotation``, so the span lies in the
+  profiler's host plane beside the device planes, and a ring span under
+  the current span or a tracer. :func:`annotate` is the same without
+  the ring, for per-step call sites (``train.input_wait``,
+  ``engine.<phase>``). ``obs/compiles.py`` adds JAX's own trace / lower
+  / compile as finished spans (:meth:`TraceRecorder.record_span`).
 * **Hot-path cheap, overhead bounded.** Sampling decides at the root
   whether a trace RECORDS; an unsampled trace still carries ids (so
   ``X-Request-Id`` and downstream propagation work) but every
@@ -47,6 +57,7 @@ import contextlib
 import contextvars
 import os
 import random
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -161,12 +172,15 @@ class Span:
         if self.recording:
             self.attrs[str(key)] = value
 
-    def finish(self, status: Optional[str] = None) -> None:
-        """Close the span (idempotent) and hand it to the recorder."""
+    def finish(self, status: Optional[str] = None,
+               end: Optional[float] = None) -> None:
+        """Close the span (idempotent) and hand it to the recorder.
+        ``end`` is a wall-clock time already taken by whoever timed the
+        work (:meth:`TraceRecorder.record_span`); default is now."""
         if self._finished:
             return
         self._finished = True
-        self.end = time.time()
+        self.end = time.time() if end is None else float(end)
         if status is not None and self.recording:
             self.attrs["status"] = status
         if self.recorder is not None:
@@ -287,6 +301,17 @@ class TraceRecorder:
                 entry["open"] += 1
         return span
 
+    def record_span(self, name: str, start: float, end: float,
+                    parent: Span, attrs: Optional[dict] = None) -> Span:
+        """Add a span that is already over, with the wall-clock
+        ``start``/``end`` its timer took, as a child of ``parent``: for
+        work that reports itself after the fact (JAX's compile
+        listener, ``obs/compiles.py``)."""
+        span = self.start_span(name, parent=parent, attrs=attrs)
+        span.start = float(start)
+        span.finish(end=end)
+        return span
+
     # -- completion / retention -------------------------------------------
 
     def _finish(self, span: Span) -> None:
@@ -368,6 +393,15 @@ def current_trace_id() -> Optional[str]:
     return span.trace_id if span is not None else None
 
 
+def recording_parent() -> Optional[Span]:
+    """The current span if a child of it would be recorded (it records
+    and has a recorder to hand the child to), else None."""
+    span = _current_span.get()
+    if span is not None and span.recording and span.recorder is not None:
+        return span
+    return None
+
+
 @contextlib.contextmanager
 def use_span(span: Optional[Span]):
     """Make ``span`` the current span for the enclosed block (None is
@@ -412,10 +446,73 @@ def annotate_request_shape(span: Optional[Span], *, tenant,
         span.set("deadline_ms", round(float(deadline_s) * 1000.0, 3))
 
 
-# There is deliberately NO process-default recorder: each plane's entry
-# point (BundleServer, RouterServer, PipelineCoordinator) owns its own
-# TraceRecorder, and everything downstream reaches the live trace only
-# through an explicit span (request-attached in the engine) or the
-# contextvar (``current_span`` — what ``utils/profiling.annotate`` and
-# the log-record filter read). A hidden global would let two planes in
-# one process silently share a ring.
+# -- the span primitive --------------------------------------------------------
+
+def annotate(name: str):
+    """Context manager that writes ``name`` into the profiler's trace and
+    nowhere else: a ``jax.profiler.TraceAnnotation`` on this thread's
+    line of the host plane, in the same file and on the same timebase
+    as the device planes; inert (a few hundred nanoseconds) while no
+    profiler session runs. What per-step call sites use, so that a
+    ``fit`` of 100,000 steps grows no trace. ``jax`` is looked up, never
+    imported: a process that has not imported it (the router) has no
+    profiler session to write to."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+@contextlib.contextmanager
+def span(name: str, tracer: Optional[TraceRecorder] = None,
+         attrs: Optional[dict] = None):
+    """The one way the program opens a host span: an :func:`annotate`
+    in the profiler's trace and, when a recording span is current on
+    this thread, a child of it in ITS recorder's ring (a trainer run by
+    the pipeline coordinator joins the round's trace); with no such
+    parent, a root in ``tracer``'s ring; with neither, the annotation
+    alone. Yields the :class:`Span` (made current for the block) or
+    None."""
+    parent = recording_parent()
+    if parent is not None:
+        sp = parent.recorder.start_span(name, parent=parent, attrs=attrs)
+    elif tracer is not None:
+        sp = tracer.start_span(name, attrs=attrs)
+    else:
+        sp = None
+    with annotate(name):
+        if sp is None:
+            yield None
+            return
+        with use_span(sp), sp:
+            yield sp
+
+
+# -- process-default tracer ----------------------------------------------------
+
+# The server, the router and the pipeline coordinator each build and pass
+# their own TraceRecorder (``--trace-sample`` / ``--trace-slow-ms`` are
+# theirs), so two planes in one process never share a ring by accident.
+# The default below is for LIBRARY callers, beside ``get_registry()`` and
+# ``get_event_log()``: a caller that builds ``Trainer(model, task, mesh)``
+# and nothing else still leaves ``train.*`` and ``jax.*`` spans that can be
+# read back in the same process (``get_tracer().traces()``). It records
+# every trace into the same bounded ring (256 traces) and has no switch.
+_TRACER: Optional[TraceRecorder] = None
+_TRACER_LOCK = threading.Lock()
+
+
+def get_tracer() -> TraceRecorder:
+    global _TRACER
+    with _TRACER_LOCK:
+        if _TRACER is None:
+            _TRACER = TraceRecorder()
+        return _TRACER
+
+
+def set_tracer(tracer: Optional[TraceRecorder]) -> None:
+    """Swap the process default (tests; None resets to a fresh one on
+    the next :func:`get_tracer`)."""
+    global _TRACER
+    with _TRACER_LOCK:
+        _TRACER = tracer

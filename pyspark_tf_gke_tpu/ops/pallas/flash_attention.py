@@ -33,6 +33,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pyspark_tf_gke_tpu.ops.pallas.scope import kernel_scope
+
 NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 512
@@ -136,7 +138,7 @@ def _flash_fwd_bh(q, k, v, bias, segs=None, *, causal: bool, block_q: int,
     if segs is not None:
         in_specs += [qblock, full_row]   # segq view (q rows), segk view (all keys)
         args += [segs, segs]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -149,7 +151,9 @@ def _flash_fwd_bh(q, k, v, bias, segs=None, *, causal: bool, block_q: int,
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         interpret=interpret,
-    )(*args)
+    )
+    with kernel_scope("flash_fwd"):
+        return call(*args)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, delta_ref,
@@ -294,7 +298,7 @@ def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
     if use_segs:
         dq_specs += [qrow, full_row]
         dq_args += [segs, segs]
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, causal=causal,
                           scale=scale, use_segs=use_segs),
         grid=(bh, s // block_q),
@@ -302,7 +306,9 @@ def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
-    )(*dq_args)
+    )
+    with kernel_scope("flash_dq"):
+        dq = dq_call(*dq_args)
 
     dkv_specs = [
         full(d),
@@ -314,7 +320,7 @@ def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
     if use_segs:
         dkv_specs += [full_row, krow]
         dkv_args += [segs, segs]
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
                           scale=scale, use_segs=use_segs),
         grid=(bh, s // block_k),
@@ -328,7 +334,9 @@ def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         interpret=interpret,
-    )(*dkv_args)
+    )
+    with kernel_scope("flash_dkv"):
+        dk, dv = dkv_call(*dkv_args)
     return dq, dk, dv
 
 

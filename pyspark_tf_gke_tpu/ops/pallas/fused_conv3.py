@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 
 from pyspark_tf_gke_tpu.ops.pallas.fused_matmul import (
     _mem, _resolve_interpret)
+from pyspark_tf_gke_tpu.ops.pallas.scope import kernel_scope
 
 
 def _transform(x, a_ref, b_ref, transform: bool, relu: bool):
@@ -82,7 +83,7 @@ def _fwd_call(x, w, a, b, *, relu, want_stats, interpret):
     mem = _mem()
     kernel = functools.partial(_fwd_kernel, transform=transform, relu=relu,
                                want_stats=want_stats)
-    y, stats = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bsz,),
         in_specs=[
@@ -101,7 +102,9 @@ def _fwd_call(x, w, a, b, *, relu, want_stats, interpret):
         ],
         scratch_shapes=[_pad_scratch(h, w_, k, x.dtype)],
         interpret=interpret,
-    )(x, w, a.reshape(1, k), b.reshape(1, k))
+    )
+    with kernel_scope("fused_conv3_fwd"):
+        y, stats = call(x, w, a.reshape(1, k), b.reshape(1, k))
     return y, stats.sum(axis=0)
 
 
@@ -150,7 +153,7 @@ def _dx_call(dy, w, x, a, b, *, relu, interpret):
         b = jnp.zeros((k,), jnp.float32)
     mem = _mem()
     kernel = functools.partial(_dx_kernel, transform=transform, relu=relu)
-    dx, dstats = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bsz,),
         in_specs=[
@@ -170,7 +173,9 @@ def _dx_call(dy, w, x, a, b, *, relu, interpret):
         ],
         scratch_shapes=[_pad_scratch(h, w_, n, dy.dtype)],
         interpret=interpret,
-    )(dy, w, x, a.reshape(1, k), b.reshape(1, k))
+    )
+    with kernel_scope("fused_conv3_dx"):
+        dx, dstats = call(dy, w, x, a.reshape(1, k), b.reshape(1, k))
     return dx, dstats.sum(axis=0)
 
 
@@ -208,7 +213,7 @@ def _dw_call(x, dy, a, b, *, relu, interpret):
     # out index map is CONSTANT over the (only) grid dim, so the f32
     # accumulator block stays resident across consecutive steps — the
     # safe accumulation pattern (cf. fused_matmul's no-revisit rule)
-    dw = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(bsz,),
         in_specs=[
@@ -221,8 +226,9 @@ def _dw_call(x, dy, a, b, *, relu, interpret):
         out_shape=jax.ShapeDtypeStruct((3, 3, k, n), jnp.float32),
         scratch_shapes=[_pad_scratch(h, w_, k, x.dtype)],
         interpret=interpret,
-    )(x, dy, a.reshape(1, k), b.reshape(1, k))
-    return dw
+    )
+    with kernel_scope("fused_conv3_dw"):
+        return call(x, dy, a.reshape(1, k), b.reshape(1, k))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

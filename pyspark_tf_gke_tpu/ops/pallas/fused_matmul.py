@@ -53,6 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pyspark_tf_gke_tpu.ops.pallas.scope import kernel_scope
+
 DEFAULT_BLOCK_M = 448   # divides B*H*W for every ResNet-50 stage at B=64k
 DEFAULT_BLOCK_N = 512
 DEFAULT_BLOCK_K = 512
@@ -134,7 +136,7 @@ def _fwd_call(x, w, a, b, *, relu, want_stats, block_m, block_n, block_k,
     kernel = functools.partial(
         _fwd_kernel, nk=nk, transform=transform, relu=relu,
         want_stats=want_stats)
-    y, stats = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn, nk),
         in_specs=[
@@ -153,7 +155,9 @@ def _fwd_call(x, w, a, b, *, relu, want_stats, block_m, block_n, block_k,
         ],
         scratch_shapes=[_scratch((bm, bn))],
         interpret=interpret,
-    )(x, w, a.reshape(1, kdim), b.reshape(1, kdim))
+    )
+    with kernel_scope("fused_matmul_fwd"):
+        y, stats = call(x, w, a.reshape(1, kdim), b.reshape(1, kdim))
     # reduce the per-M-tile partials: (m_tiles, 2, n) f32 — a few MB at
     # most, one cheap XLA pass, no undefined revisit semantics
     return y, stats.sum(axis=0)
@@ -208,7 +212,7 @@ def _dx_call(dy, w, x, a, b, *, relu, block_m, block_n, block_k, interpret):
     mem = _mem()
     kernel = functools.partial(_dx_kernel, nn_=nn_, transform=transform,
                                relu=relu)
-    dx, dstats = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(m // bm, kdim // bk, nn_),
         in_specs=[
@@ -228,7 +232,9 @@ def _dx_call(dy, w, x, a, b, *, relu, block_m, block_n, block_k, interpret):
         ],
         scratch_shapes=[_scratch((bm, bk))],
         interpret=interpret,
-    )(dy, w, x, a.reshape(1, kdim), b.reshape(1, kdim))
+    )
+    with kernel_scope("fused_matmul_dx"):
+        dx, dstats = call(dy, w, x, a.reshape(1, kdim), b.reshape(1, kdim))
     return dx, dstats.sum(axis=0)
 
 
@@ -271,7 +277,7 @@ def _dw_call(x, dy, a, b, *, relu, block_m, block_n, block_k, interpret):
     mem = _mem()
     kernel = functools.partial(_dw_kernel, nm=nm, transform=transform,
                                relu=relu)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(kdim // bk, n // bn, nm),
         in_specs=[
@@ -284,7 +290,9 @@ def _dw_call(x, dy, a, b, *, relu, block_m, block_n, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((kdim, n), dy.dtype),
         scratch_shapes=[_scratch((bk, bn))],
         interpret=interpret,
-    )(x, dy, a.reshape(1, kdim), b.reshape(1, kdim))
+    )
+    with kernel_scope("fused_matmul_dw"):
+        return call(x, dy, a.reshape(1, kdim), b.reshape(1, kdim))
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pyspark_tf_gke_tpu.ops.pallas.scope import kernel_scope
+
 DEFAULT_BLOCK_ROWS = 256
 
 
@@ -69,14 +71,16 @@ def _ln_forward(x2, scale, bias, eps, block_rows, interpret, r2=None):
             [row_spec, row_spec, vec_spec, vec_spec],
             (x2, r2, scale, bias),
         )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(n // block_rows,),
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((n, d), x2.dtype),
         interpret=interpret,
-    )(*args)
+    )
+    with kernel_scope("layernorm_fwd"):
+        return call(*args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -53,6 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pyspark_tf_gke_tpu.ops.pallas.scope import kernel_scope
+
 NEG_INF = -1e30
 
 
@@ -258,12 +260,14 @@ def _paged_pallas(q, k_pages, v_pages, block_table, fills, k_scales,
     )
     kernel = functools.partial(_paged_kernel, page_size=p_sz, hkv=hkv,
                                scale=d ** -0.5, quant=quant, s_q=s_q)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s_q, h, d), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), fills.astype(jnp.int32), *args)
+    )
+    with kernel_scope("paged_attention_decode"):
+        return call(block_table.astype(jnp.int32), fills.astype(jnp.int32), *args)
 
 
 def paged_attention(

@@ -338,7 +338,9 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from pyspark_tf_gke_tpu.obs.compiles import install_compile_listener
     from pyspark_tf_gke_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
+    install_compile_listener()
     main(sys.argv[1:])

@@ -60,6 +60,7 @@ from pyspark_tf_gke_tpu.obs.runtime import install_runtime_metrics
 from pyspark_tf_gke_tpu.obs.stepstats import StepStatsRing
 from pyspark_tf_gke_tpu.obs.trace import (
     TraceRecorder,
+    annotate,
     annotate_request_shape,
     use_span,
 )
@@ -1099,7 +1100,10 @@ class _ContinuousFront:
                     finally:
                         self._step_started = None
                     t_deliver = time.monotonic()
-                    self._deliver_finished(finished)
+                    # the sixth phase runs outside engine.step() and so
+                    # outside StepRecord.phase: annotated here
+                    with annotate("engine.deliver"):
+                        self._deliver_finished(finished)
                     if busy:
                         # retire sweep after delivery: the in-flight
                         # chunk often goes ready while the host
@@ -3399,7 +3403,9 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from pyspark_tf_gke_tpu.obs.compiles import install_compile_listener
     from pyspark_tf_gke_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
+    install_compile_listener()
     sys.exit(main())

@@ -30,8 +30,10 @@ import optax
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from pyspark_tf_gke_tpu.obs.compiles import install_compile_listener, on_compile
 from pyspark_tf_gke_tpu.obs.events import get_event_log
 from pyspark_tf_gke_tpu.obs.metrics import get_registry, platform_families
+from pyspark_tf_gke_tpu.obs.trace import annotate, get_tracer, span
 from pyspark_tf_gke_tpu.parallel.mesh import batch_sharding
 from pyspark_tf_gke_tpu.parallel.sharding import (
     DEFAULT_MIN_SIZE,
@@ -271,6 +273,33 @@ class _CountingIterator:
         return batch
 
 
+class _LoopPhase:
+    """One host phase of the step loop (``train.input_wait``, ...): an
+    annotation in the profiler's trace and, from the same two clock
+    reads, the seconds the epoch's span and the registry are given.
+    Never a ring span: a ``fit`` of 100,000 steps must not grow a trace."""
+
+    __slots__ = ("name", "total", "_t0", "_annotation")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.total = 0.0
+
+    def __enter__(self):
+        self._annotation = annotate(self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.total += time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+
+
+# the jitted functions one optimizer step runs (plain and grad-accum): a
+# compile of one of these after a fit's first step is a recompile
+STEP_FUNCTIONS = ("train_step", "grad_step", "apply_mean")
+
+
 class Trainer:
     """Builds sharded state, compiles the step, runs the epoch loop."""
 
@@ -291,6 +320,7 @@ class Trainer:
         # reference-parity optimizer numerics; ignored when tx is given.
         metrics_registry=None,  # obs.MetricsRegistry (default: shared)
         event_log=None,  # obs.EventLog (default: shared trail)
+        tracer=None,  # obs.TraceRecorder (default: the process's)
     ):
         self.model = model
         self.task = task
@@ -315,6 +345,14 @@ class Trainer:
                                  is not None else get_registry())
         self._obs = platform_families(self.metrics_registry)
         self._event_log = event_log if event_log is not None else get_event_log()
+        # train.* spans (docs/OBSERVABILITY.md "Training spans"); JAX's
+        # trace / lower / compile hang under them (obs/compiles.py)
+        self._tracer = tracer if tracer is not None else get_tracer()
+        install_compile_listener()
+        self._input_wait = _LoopPhase("train.input_wait")
+        self._dispatch = _LoopPhase("train.step_dispatch")
+        self._first_sync = _LoopPhase("train.first_step_sync")
+        self._sync = _LoopPhase("train.epoch_sync")
 
     # ---- state construction -------------------------------------------------
 
@@ -362,6 +400,10 @@ class Trainer:
     def init_state(self, rng: jax.Array, sample_batch: Dict[str, np.ndarray]) -> TrainState:
         """Init params directly into their target shardings (jit with
         out_shardings) so large models never materialize unsharded."""
+        with span("train.init_state", tracer=self._tracer):
+            return self._init_state(rng, sample_batch)
+
+    def _init_state(self, rng, sample_batch):
         sample = self._sample_inputs(sample_batch)
         create = self._create_fn(sample)
         abstract = jax.eval_shape(create, rng)
@@ -502,12 +544,16 @@ class Trainer:
         with self.mesh:
             acc = None  # (grads_sum, metrics_sum, bs_sum)
             for _ in range(accum):
-                grads, metrics, new_bs = self._grad_step(state, next(batches))
-                new = (grads, metrics) if new_bs is None else (grads, metrics, new_bs)
-                acc = new if acc is None else self._accum_add(acc, new)
+                with self._input_wait:
+                    batch = next(batches)
+                with self._dispatch:
+                    grads, metrics, new_bs = self._grad_step(state, batch)
+                    new = (grads, metrics) if new_bs is None else (grads, metrics, new_bs)
+                    acc = new if acc is None else self._accum_add(acc, new)
             grads_sum, metrics_sum = acc[0], acc[1]
             bs_sum = acc[2] if len(acc) == 3 else None
-            state = self._apply_step(state, grads_sum, bs_sum, accum)
+            with self._dispatch:
+                state = self._apply_step(state, grads_sum, bs_sum, accum)
         return state, {k: v / accum for k, v in metrics_sum.items()}
 
     def debug_step(self, state: TrainState, batch: Dict[str, jax.Array]):
@@ -599,24 +645,30 @@ class Trainer:
         metrics (step_time_ms, examples_per_sec)."""
         from pyspark_tf_gke_tpu.data.pipeline import prefetch_to_device
 
-        data_sharding = batch_sharding(self.mesh)
-        history: Dict[str, list] = {}
-        # Host-side mirror of state.step: one sync here, then pure
-        # increments — no per-step device readback for liveness.
-        global_step = int(jax.device_get(state.step))
-        prefetched = prefetch_to_device(batches, data_sharding, size=prefetch)
-        device_batches = _CountingIterator(prefetched)
-        try:
-            return self._fit_epochs(
-                state, device_batches, epochs, steps_per_epoch, val_batches,
-                checkpoint_manager, log_every, heartbeat, fault_injector,
-                history, global_step, grad_accum, val_use_ema,
-            )
-        finally:
-            # Stop the prefetch worker: it must not keep draining the
-            # caller's iterator after fit returns or raises (restart
-            # wrappers reuse that iterator).
-            prefetched.close()
+        # one trace per call: train.fit > train.epoch > train.validate,
+        # train.checkpoint, jax.*; what lies outside the epochs is what
+        # a fit call costs besides its steps
+        with span("train.fit", tracer=self._tracer,
+                  attrs={"task": self.task.name, "epochs": epochs,
+                         "steps_per_epoch": steps_per_epoch}):
+            data_sharding = batch_sharding(self.mesh)
+            history: Dict[str, list] = {}
+            # Host-side mirror of state.step: one sync here, then pure
+            # increments — no per-step device readback for liveness.
+            global_step = int(jax.device_get(state.step))
+            prefetched = prefetch_to_device(batches, data_sharding, size=prefetch)
+            device_batches = _CountingIterator(prefetched)
+            try:
+                return self._fit_epochs(
+                    state, device_batches, epochs, steps_per_epoch, val_batches,
+                    checkpoint_manager, log_every, heartbeat, fault_injector,
+                    history, global_step, grad_accum, val_use_ema,
+                )
+            finally:
+                # Stop the prefetch worker: it must not keep draining the
+                # caller's iterator after fit returns or raises (restart
+                # wrappers reuse that iterator).
+                prefetched.close()
 
     def _fit_epochs(
         self, state, device_batches, epochs, steps_per_epoch, val_batches,
@@ -629,96 +681,132 @@ class Trainer:
             "train_fit_start", task=self.task.name, epochs=epochs,
             steps_per_epoch=steps_per_epoch, start_step=global_step,
             grad_accum=grad_accum)
+        start_step = global_step
+        phases = (self._input_wait, self._dispatch, self._first_sync, self._sync)
+
+        def recompiled(fun: str, seconds: float) -> None:
+            # "which step recompiled": the step's own function compiled
+            # again after this fit had already run a step
+            if fun in STEP_FUNCTIONS and global_step > start_step:
+                self._event_log.emit(
+                    "train_recompile", fun=fun, global_step=global_step,
+                    seconds=round(seconds, 3))
+
         for epoch in range(epochs):
-            # Metrics accumulate as device scalars — no host sync inside the
-            # step loop, so dispatch overlaps with next-batch preparation.
-            sums: Dict[str, jax.Array] = {}
-            t_first_step = 0.0
-            epoch_start = time.perf_counter()
-            examples = 0
-            for step_i in range(steps_per_epoch):
-                rows_before = device_batches.rows
-                t0 = time.perf_counter()
-                if grad_accum > 1:
-                    state, metrics = self.accum_step(state, device_batches, grad_accum)
-                else:
-                    state, metrics = self.step(state, next(device_batches))
-                if step_i == 0:
-                    # first step includes compilation; keep it out of step-time stats
-                    jax.block_until_ready(metrics)
-                    t_first_step = time.perf_counter() - t0
-                # global rows consumed this optimizer step
-                step_rows = device_batches.rows - rows_before
-                examples += step_rows
-                global_step += 1
-                # obs plane: counters record everything; the histogram
-                # records steady steps only — each epoch's step 0 is
-                # excluded (epoch 0's includes compile; later epochs'
-                # absorb the drained dispatch queue at the
-                # block_until_ready above), mirroring the history's
-                # steady_steps accounting. Steady observations are the
-                # host dispatch interval: with the step loop kept
-                # async by design, this equals device step time once
-                # the in-flight queue saturates, and under-reads it
-                # before then — the history's synced epoch-level
-                # step_time_ms stays the calibration reference.
-                self._obs["train_steps_total"].inc()
-                self._obs["train_examples_total"].inc(step_rows)
-                if step_i != 0:
-                    self._obs["train_step_time_ms"].observe(
-                        (time.perf_counter() - t0) * 1000.0)
-                if heartbeat is not None:
-                    heartbeat.beat(global_step)
-                if fault_injector is not None:
-                    fault_injector.maybe_fail(global_step)
-                for k, v in metrics.items():
-                    sums[k] = sums[k] + v if k in sums else v
-                if log_every and (step_i + 1) % log_every == 0:
+            with span("train.epoch", attrs={"epoch": epoch + 1}) as epoch_span, \
+                    on_compile(recompiled):
+                for phase in phases:
+                    phase.total = 0.0
+                # Metrics accumulate as device scalars — no host sync inside the
+                # step loop, so dispatch overlaps with next-batch preparation.
+                sums: Dict[str, jax.Array] = {}
+                t_first_step = 0.0
+                epoch_start = time.perf_counter()
+                examples = 0
+                for step_i in range(steps_per_epoch):
+                    rows_before = device_batches.rows
+                    waited = self._input_wait.total
+                    t0 = time.perf_counter()
+                    if grad_accum > 1:
+                        state, metrics = self.accum_step(state, device_batches, grad_accum)
+                    else:
+                        with self._input_wait:
+                            batch = next(device_batches)
+                        with self._dispatch:
+                            state, metrics = self.step(state, batch)
+                    if step_i == 0:
+                        # first step includes compilation; keep it out of step-time stats
+                        with self._first_sync:
+                            jax.block_until_ready(metrics)
+                        t_first_step = time.perf_counter() - t0
+                    # global rows consumed this optimizer step
+                    step_rows = device_batches.rows - rows_before
+                    examples += step_rows
+                    global_step += 1
+                    # obs plane: counters record everything; the histogram
+                    # records steady steps only — each epoch's step 0 is
+                    # excluded (epoch 0's includes compile; later epochs'
+                    # absorb the drained dispatch queue at the
+                    # block_until_ready above), mirroring the history's
+                    # steady_steps accounting. Steady observations are the
+                    # host dispatch interval: with the step loop kept
+                    # async by design, this equals device step time once
+                    # the in-flight queue saturates, and under-reads it
+                    # before then — the history's synced epoch-level
+                    # step_time_ms stays the calibration reference.
+                    self._obs["train_steps_total"].inc()
+                    self._obs["train_examples_total"].inc(step_rows)
+                    self._obs["train_input_wait_ms"].observe(
+                        (self._input_wait.total - waited) * 1000.0)
+                    if step_i != 0:
+                        self._obs["train_step_time_ms"].observe(
+                            (time.perf_counter() - t0) * 1000.0)
+                    if heartbeat is not None:
+                        heartbeat.beat(global_step)
+                    if fault_injector is not None:
+                        fault_injector.maybe_fail(global_step)
+                    # each sum is one more program in the device's queue:
+                    # where the runtime bounds what is in flight, this is
+                    # where a loop that runs ahead of the device is held
+                    with annotate("train.metrics_accumulate"):
+                        for k, v in metrics.items():
+                            sums[k] = sums[k] + v if k in sums else v
+                    if log_every and (step_i + 1) % log_every == 0:
+                        logger.info(
+                            "epoch %d step %d/%d loss=%.4f",
+                            epoch + 1, step_i + 1, steps_per_epoch,
+                            float(sums.get("loss", 0.0)) / (step_i + 1),
+                        )
+                with self._sync:
+                    sums_host = {k: float(jax.device_get(v)) for k, v in sums.items()}
+                    jax.block_until_ready(state.step)
+                epoch_time = time.perf_counter() - epoch_start
+                if epoch_span is not None:
+                    epoch_span.set("steps", steps_per_epoch)
+                    epoch_span.set("rows", examples)
+                    epoch_span.set("input_wait_ms", self._input_wait.total * 1e3)
+                    epoch_span.set("dispatch_ms", self._dispatch.total * 1e3)
+                    epoch_span.set(
+                        "sync_ms", (self._first_sync.total + self._sync.total) * 1e3)
+
+                for k, v in sums_host.items():
+                    history.setdefault(k, []).append(v / steps_per_epoch)
+                steady_steps = max(steps_per_epoch - 1, 1)
+                steady_time = max(epoch_time - t_first_step, 1e-9)
+                steady_examples = examples * steady_steps / steps_per_epoch
+                step_ms = steady_time / steady_steps * 1000.0
+                history.setdefault("step_time_ms", []).append(step_ms)
+                history.setdefault("examples_per_sec", []).append(steady_examples / steady_time)
+
+                msg = " - ".join(
+                    f"{k}: {history[k][-1]:.4f}" for k in sums
+                )
+                logger.info("Epoch %d/%d - %s - %.1f ms/step", epoch + 1, epochs, msg, step_ms)
+                self._obs["train_epochs_total"].inc()
+                if "loss" in history:
+                    self._obs["train_last_loss"].set(history["loss"][-1])
+                self._event_log.emit(
+                    "train_epoch_end", epoch=epoch + 1, global_step=global_step,
+                    step_time_ms=round(step_ms, 3),
+                    loss=history.get("loss", [None])[-1])
+
+                if val_batches is not None:
+                    with span("train.validate"):
+                        val_sharding = batch_sharding(self.mesh)
+                        val_iter = (
+                            put_global_batch(b, val_sharding) for b in val_batches()
+                        )
+                        val_metrics = self.evaluate(state, val_iter,
+                                                    use_ema=val_use_ema)
+                    for k, v in val_metrics.items():
+                        history.setdefault(f"val_{k}", []).append(v)
                     logger.info(
-                        "epoch %d step %d/%d loss=%.4f",
-                        epoch + 1, step_i + 1, steps_per_epoch,
-                        float(sums.get("loss", 0.0)) / (step_i + 1),
+                        "Epoch %d validation - %s", epoch + 1,
+                        " - ".join(f"{k}: {v:.4f}" for k, v in val_metrics.items()),
                     )
-            sums_host = {k: float(jax.device_get(v)) for k, v in sums.items()}
-            jax.block_until_ready(state.step)
-            epoch_time = time.perf_counter() - epoch_start
 
-            for k, v in sums_host.items():
-                history.setdefault(k, []).append(v / steps_per_epoch)
-            steady_steps = max(steps_per_epoch - 1, 1)
-            steady_time = max(epoch_time - t_first_step, 1e-9)
-            steady_examples = examples * steady_steps / steps_per_epoch
-            step_ms = steady_time / steady_steps * 1000.0
-            history.setdefault("step_time_ms", []).append(step_ms)
-            history.setdefault("examples_per_sec", []).append(steady_examples / steady_time)
-
-            msg = " - ".join(
-                f"{k}: {history[k][-1]:.4f}" for k in sums
-            )
-            logger.info("Epoch %d/%d - %s - %.1f ms/step", epoch + 1, epochs, msg, step_ms)
-            self._obs["train_epochs_total"].inc()
-            if "loss" in history:
-                self._obs["train_last_loss"].set(history["loss"][-1])
-            self._event_log.emit(
-                "train_epoch_end", epoch=epoch + 1, global_step=global_step,
-                step_time_ms=round(step_ms, 3),
-                loss=history.get("loss", [None])[-1])
-
-            if val_batches is not None:
-                val_sharding = batch_sharding(self.mesh)
-                val_iter = (
-                    put_global_batch(b, val_sharding) for b in val_batches()
-                )
-                val_metrics = self.evaluate(state, val_iter,
-                                            use_ema=val_use_ema)
-                for k, v in val_metrics.items():
-                    history.setdefault(f"val_{k}", []).append(v)
-                logger.info(
-                    "Epoch %d validation - %s", epoch + 1,
-                    " - ".join(f"{k}: {v:.4f}" for k, v in val_metrics.items()),
-                )
-
-            if checkpoint_manager is not None:
-                checkpoint_manager.maybe_save(state, history)
+                if checkpoint_manager is not None:
+                    with span("train.checkpoint"):
+                        checkpoint_manager.maybe_save(state, history)
 
         return state, history

@@ -10,7 +10,6 @@ from pyspark_tf_gke_tpu.evaluate.image_checker import ManualImageChecker
 from pyspark_tf_gke_tpu.models import BertConfig, BertForPretraining, CNNRegressor
 from pyspark_tf_gke_tpu.train.checkpoint import CheckpointManager
 from pyspark_tf_gke_tpu.train.trainer import TASKS, Trainer
-from pyspark_tf_gke_tpu.utils.profiling import StepTimer, profile_trace
 from pyspark_tf_gke_tpu.utils.seeding import make_rng
 
 
@@ -36,28 +35,6 @@ def test_image_checker_end_to_end(tmp_path, mesh_dp):
     assert result["mean_px_error"] >= 0
     plots = os.listdir(tmp_path / "plots")
     assert len(plots) == 8 and all(p.endswith("_eval.png") for p in plots)
-
-
-def test_step_timer_excludes_compile():
-    t = StepTimer()
-    for _ in range(5):
-        t.start()
-        t.stop()
-    assert t.count == 4  # first excluded
-    assert t.mean_ms >= 0 and t.p50_ms >= 0
-    assert t.examples_per_sec(32) > 0
-
-
-def test_profile_trace_writes(tmp_path, mesh_dp):
-    import jax
-
-    out = str(tmp_path / "trace")
-    with profile_trace(out):
-        jnp_sum = jax.jit(lambda x: x.sum())(jnp.ones((16, 16)))
-        jax.block_until_ready(jnp_sum)
-    assert os.path.isdir(out) and os.listdir(out)  # plugins/ trace files exist
-    with profile_trace(""):  # no-op path
-        pass
 
 
 def test_bert_flash_flag_interpret(mesh_dp):
