@@ -1,21 +1,57 @@
-"""Flash attention forward as a Pallas TPU kernel.
+"""Flash attention as Pallas TPU kernels, forward and backward.
 
 The S×S score matrix never touches HBM: each grid step owns one Q block in
-VMEM, loops over K/V blocks with the online-softmax recurrence (running
-max ``m``, normalizer ``l``, accumulator in f32), and writes one O block.
+VMEM, walks the K/V axis with the online-softmax recurrence (running max
+``m``, normalizer ``l``, accumulator in f32), and writes one O block.
 Q·Kᵀ and P·V hit the MXU with f32 accumulation.
 
 Layout: inputs are ``[BH, S, D]`` (batch×heads collapsed — each grid row
-is independent). Optional additive bias ``[BH, S]`` implements padding
-masks (0 for keep, -inf/NEG_INF for drop). ``causal=True`` masks with
-block-level skipping (a K block fully in the future is never read).
+is independent). An optional additive bias ``[BH, 1, S]`` implements
+padding masks (0 for keep, NEG_INF for drop); without a ``kv_mask`` there
+is no bias operand at all. Optional ``[BH, 1, S]`` segment ids confine
+attention within a packed sequence.
+
+**The schedule** (``_schedule``, a pure function of ``(S, block_q, block_k,
+causal)``; the three kernels take every loop bound from it through
+``_sweep``). The grid block is ``block_q`` rows (``block_k`` keys in dK/dV).
+Seen from a grid block, the other axis falls into three kinds of *tile*:
+
+* *skipped*: wholly above the diagonal. Never computed.
+* *full*: wholly on or under it. No iota, no compare, no select. The whole
+  block takes them ``walk`` positions a *full step*.
+* *diagonal*: crossed by the diagonal. These lie in the block's *diagonal
+  square* (the block against its own positions on the other axis), which
+  is cut into ``tile``-sided tiles and taken one *strip* at a time: a row
+  sub-block of ``tile`` rows against the keys of the square up to its own
+  tile (forward, dQ), or a key tile against the rows of the square from its
+  own tile on (dK/dV). Only the one diagonal tile of a strip passes
+  through the mask, a triangle that is the same for every diagonal tile and
+  is built once a grid step; the strip's other tiles are full, and the
+  tiles above the diagonal inside the square are skipped.
+
+Not causal, every tile is full and there is no square. Where a block has
+no 128-multiple divisor (the tests' 16- and 32-wide blocks, a sequence
+taken as one block) the tile is the whole block: one masked strip.
+
+Strips are static Python loops, and the full steps are unrolled where
+their number is static (not causal) and at most ``UNROLL``. On the v5e a
+step inside a dynamic ``fori_loop`` costs about twice what the same step
+costs in straight-line code, where the scheduler overlaps one strip's
+matmuls with the next one's vector work (PERF.md §6, PR 26); hence a
+sequence of up to ``WHOLE_SEQ`` positions is one grid block: a head is one
+grid step with no dynamic loop at all. Longer sequences keep the 512-row
+grid block, and causal ones walk their full tiles in a ``fori_loop`` whose
+bounds depend on the grid block.
 
 Backward: ``jax.custom_vjp`` with **Pallas backward kernels** — the
 forward additionally emits the per-row logsumexp ``L = m + log(l)``, and
-two kernels recompute P blockwise from (q, k, bias, L): one walks K
-blocks to produce dQ, the other walks Q blocks to produce dK/dV (the
-standard flash-attention backward split). No S×S tensor ever exists in
-either pass; residuals are (q, k, v, bias, L, D=rowsum(dO·O)).
+two kernels recompute P from (q, k, bias, L): one walks keys to produce
+dQ, the other walks rows to produce dK/dV (the standard flash-attention
+backward split). The dK/dV kernel computes the *transposed* tile (keys on
+sublanes, rows on lanes): ``L`` and ``delta`` are then read as the row
+vectors they are stored as, and every matmul contracts over a last or a
+first-of-rhs dimension. No S×S tensor ever exists in either pass;
+residuals are (q, k, v, bias, L, D=rowsum(dO·O)).
 
 The public entry ``flash_attention`` takes ``[B, S, H, D]`` like
 ``ops.attention.dot_product_attention`` and reshapes. Falls back to the
@@ -25,7 +61,8 @@ dense path on non-TPU backends unless ``interpret=True`` (used in tests).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,73 +70,206 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pyspark_tf_gke_tpu.ops.pallas.scope import kernel_scope
+from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu, pick_block
+from pyspark_tf_gke_tpu.ops.pallas.scope import caller_scope, kernel_scope
 
 NEG_INF = -1e30
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
+# Settled on the v5e (PERF.md §6, PR 26): the longest sequence taken as one
+# grid block, the side of a tile of the diagonal square, the width of a full
+# step, and the most full steps that are unrolled (each holds its tiles in
+# VMEM: 16 of them at head_dim 128 pass the 16 MiB a kernel may take).
+WHOLE_SEQ = 1024
+TILE = 128
+WALK = 512
+UNROLL = 8
+
+_NT = (((1,), (1,)), ((), ()))   # a [m, D] · b [n, D]ᵀ -> [m, n]
+_NN = (((1,), (0,)), ((), ()))   # a [m, n] · b [n, D]  -> [m, D]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, block_k: int,
-                causal: bool, scale: float, use_segs: bool):
+class _Schedule(NamedTuple):
+    """What one head's kernels compute of the [S, S] scores (module
+    docstring). Positions are along the grid axis (rows of q in forward and
+    dQ, keys in dK/dV) and the walk axis (the other one)."""
+
+    s: int
+    block: int        # grid block
+    walk: int         # width of one step over full tiles
+    tile: int         # side of a tile; a sub-block's extent along the grid axis
+    causal: bool
+    walks_rows: bool  # dK/dV: the visible side of the walk axis is the far one
+
+    def full_steps(self, i):
+        """Half-open range of walk steps wholly visible to grid block ``i``
+        (a Python or a traced integer)."""
+        per_block = self.block // self.walk
+        if not self.causal:
+            return 0, self.s // self.walk
+        if self.block == self.s:
+            return 0, 0
+        if self.walks_rows:
+            return (i + 1) * per_block, self.s // self.walk
+        return 0, i * per_block
+
+    def sub_blocks(self):
+        """``(first, size)`` of the block's sub-blocks along the grid axis."""
+        return [(i * self.tile, self.tile)
+                for i in range(self.block // self.tile)]
+
+    def strips(self):
+        """The diagonal square's strips, one a sub-block and in their order,
+        as ``(start, width)`` along the walk axis from the square's corner.
+        The diagonal tile is the strip's last (its first, where the kernel
+        walks rows)."""
+        if not self.causal:
+            return []
+        if self.walks_rows:
+            return [(first, self.block - first)
+                    for first, _ in self.sub_blocks()]
+        return [(0, first + size) for first, size in self.sub_blocks()]
+
+    def counts(self):
+        """(computed, through the mask, thrown away) score elements a head."""
+        if not self.causal:
+            return self.s * self.s, 0, 0
+        blocks, t = self.s // self.block, self.tile
+        diagonal_tiles = blocks * len(self.sub_blocks())
+        full = self.block ** 2 * blocks * (blocks - 1) // 2
+        square = t * sum(width for _, width in self.strips())
+        return (full + blocks * square, diagonal_tiles * t * t,
+                diagonal_tiles * t * (t - 1) // 2)
+
+
+def _schedule(s: int, block_q: int, block_k: int, causal: bool,
+              walks_rows: bool = False) -> _Schedule:
+    block, other = (block_k, block_q) if walks_rows else (block_q, block_k)
+    walk = pick_block(math.gcd(block, other) if causal else other, WALK, 128)
+    return _Schedule(s, block, walk, pick_block(block, TILE, 128), causal,
+                     walks_rows)
+
+
+def _sweep(sched: _Schedule, i, carry, piece):
+    """Everything grid block ``i`` computes: ``piece(carry, sub, start,
+    width, tri)`` updates the carry (a tuple of arrays whose first axis is
+    the block's) of the block's positions ``sub`` from ``width`` positions
+    of the walk axis at ``start``. The full steps take the whole block; then
+    each strip of the diagonal square takes its sub-block's slice."""
+    whole = slice(0, sched.block)
+
+    def step(j, carry):
+        return piece(carry, whole, pl.multiple_of(j * sched.walk, sched.walk),
+                     sched.walk, None)
+
+    lo, hi = sched.full_steps(i)
+    # a few steps with static bounds unroll into one basic block, where the
+    # scheduler overlaps a step's matmuls with its neighbour's vector work
+    few = isinstance(lo, int) and isinstance(hi, int) and hi - lo <= UNROLL
+    carry = jax.lax.fori_loop(lo, hi, step, carry, unroll=few or None)
+    if not sched.causal:
+        return carry
+    tri = _triangle(sched.tile, keys_first=sched.walks_rows)
+    corner = pl.multiple_of(i * sched.block, sched.block)
+    strips = [
+        piece(tuple(c[first:first + size] for c in carry),
+              slice(first, first + size), corner + start, width, tri)
+        for (first, size), (start, width) in zip(sched.sub_blocks(),
+                                                 sched.strips())]
+    return tuple(jnp.concatenate(c) for c in zip(*strips))
+
+
+def _scores(a, b, scale, bias, seg_a, seg_b, tri, diagonal_last):
+    """f32 scores of ``a`` [m, D] against ``b`` [n, D], masked: [m, n].
+    ``bias`` and the segment ids broadcast against [m, n]; ``tri`` [m, m]
+    masks the diagonal tile alone, the last m columns or the first."""
+    s = jax.lax.dot_general(a, b, _NT,
+                            preferred_element_type=jnp.float32) * scale
+    if bias is not None:
+        s = s + bias
+    if seg_a is not None:
+        s = jnp.where(seg_a == seg_b, s, NEG_INF)
+    if tri is not None:
+        t = tri.shape[0]
+        if s.shape[1] == t:
+            s = jnp.where(tri, s, NEG_INF)
+        elif diagonal_last:
+            s = jnp.concatenate(
+                [s[:, :-t], jnp.where(tri, s[:, -t:], NEG_INF)], axis=1)
+        else:
+            s = jnp.concatenate(
+                [jnp.where(tri, s[:, :t], NEG_INF), s[:, t:]], axis=1)
+    return s
+
+
+def _triangle(t: int, keys_first: bool):
+    """The diagonal tile's mask: row >= key, rows on sublanes (or on lanes,
+    for the transposed tile of dK/dV)."""
+    a = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    b = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+    return a <= b if keys_first else a >= b
+
+
+def _row(ref, start, width):
+    """[1, width] of a ``[1, 1, S]`` row-vector ref."""
+    return ref[0, 0, pl.ds(start, width)][None, :]
+
+
+def _split_refs(refs, n_in, use_bias, use_segs):
+    """(inputs, bias, segq, segk, outputs) of a kernel's positional refs."""
+    refs = list(refs)
+    ins, rest = refs[:n_in], refs[n_in:]
+    bias = rest.pop(0) if use_bias else None
+    segq, segk = (rest.pop(0), rest.pop(0)) if use_segs else (None, None)
+    return ins, bias, segq, segk, rest
+
+
+def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
+                use_segs: bool):
     # Shapes: q [1, bq, D], k/v [1, S, D], bias [1, 1, S], o [1, bq, D],
     # lse [1, 1, bq]; with use_segs also segq [1, 1, bq], segk [1, 1, S]
     # (int32 packed-sequence ids — tokens attend within their segment).
     # Row-vectors ride a leading singleton so their last two block dims
     # satisfy Mosaic's (8, 128)-or-full tiling rule.
-    if use_segs:
-        segq_ref, segk_ref, o_ref, lse_ref = rest
-    else:
-        o_ref, lse_ref = rest
-    bq = q_ref.shape[1]
-    s = k_ref.shape[1]
-    d = q_ref.shape[2]
+    (q_ref, k_ref, v_ref), bias_ref, segq_ref, segk_ref, (o_ref, lse_ref) = (
+        _split_refs(refs, 3, use_bias, use_segs))
+    bq, d = q_ref.shape[1], q_ref.shape[2]
     qi = pl.program_id(1)  # Q-block index
 
     # Matmul operands stay in the input dtype (bf16 hits the MXU at full
     # rate; f32 would run it 8x slower); accumulation and the softmax
-    # statistics are f32. The scale is folded into the f32 scores.
+    # statistics are f32.
     q = q_ref[0]                                         # [bq, D]
+    segq = segq_ref[0, 0][:, None] if use_segs else None  # [bq, 1]
 
-    m = jnp.full((bq, 1), NEG_INF, dtype=jnp.float32)
-    l = jnp.zeros((bq, 1), dtype=jnp.float32)
-    acc = jnp.zeros((bq, d), dtype=jnp.float32)
-
-    num_kb = s // block_k
-
-    def body(kb, carry):
+    def attend(carry, rows, start, width, tri):
+        # online-softmax update of the block's ``rows`` with keys
+        # [start, start + width)
         m, l, acc = carry
-        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        scores = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                        # [bq, bk] f32
-        scores += bias_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
-        if use_segs:
-            segq = segq_ref[0, 0][:, None]               # [bq, 1]
-            segk = segk_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
-            scores = jnp.where(segq == segk, scores, NEG_INF)
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-        m_new = jnp.maximum(m, scores.max(axis=1, keepdims=True))
-        p = jnp.exp(scores - m_new)
+        k_blk = k_ref[0, pl.ds(start, width), :]
+        v_blk = v_ref[0, pl.ds(start, width), :]
+        s = _scores(
+            q[rows], k_blk, scale,
+            _row(bias_ref, start, width) if use_bias else None,
+            segq[rows] if use_segs else None,
+            _row(segk_ref, start, width) if use_segs else None,
+            tri, True)                                   # [rows, width] f32
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(axis=1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+            p.astype(v_blk.dtype), v_blk, _NN,
+            preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    if causal:
-        # K blocks fully in the future of this Q block are skipped entirely.
-        last_kb = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, num_kb)
-    else:
-        last_kb = num_kb
-    m, l, acc = jax.lax.fori_loop(0, last_kb, body, (m, l, acc))
+    m, l, acc = _sweep(
+        sched, qi,
+        (jnp.full((bq, 1), NEG_INF, dtype=jnp.float32),
+         jnp.zeros((bq, 1), dtype=jnp.float32),
+         jnp.zeros((bq, d), dtype=jnp.float32)),
+        attend)
 
     valid = m > NEG_INF / 2                              # rows with >=1 unmasked key
     l = jnp.where(l == 0.0, 1.0, l)
@@ -110,20 +280,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, block_k: int,
     lse_ref[0, 0] = jnp.where(valid, m + jnp.log(l), jnp.inf)[:, 0]
 
 
-def _flash_fwd_bh(q, k, v, bias, segs=None, *, causal: bool, block_q: int,
-                  block_k: int, interpret: bool):
-    """q,k,v: [BH, S, D]; bias: [BH, 1, S] additive (0 / NEG_INF);
+def _flash_fwd_bh(q, k, v, bias=None, segs=None, *, causal: bool,
+                  block_q: int, block_k: int, interpret: bool):
+    """q,k,v: [BH, S, D]; bias: optional [BH, 1, S] additive (0 / NEG_INF);
     segs: optional [BH, 1, S] int32 packed-sequence ids.
     Returns (out [BH, S, D], lse [BH, 1, S])."""
-    bh, s, d = q.shape
+    s = q.shape[1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(f"seq len {s} must be divisible by blocks ({block_q},{block_k})")
-    scale = d ** -0.5
+    return _fwd_call(q, k, v, bias, segs, causal=causal, block_q=block_q,
+                     block_k=block_k, interpret=interpret, caller=caller_scope())
 
-    kernel = functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
-                               scale=scale, use_segs=segs is not None)
+
+# The launches are jitted so that a model's layers, which call with the same
+# shapes, share one trace and one lowering of a kernel's body: its strips are
+# static Python loops, and 72 traces of them a step program cost the set-up
+# 20 s (PERF.md §6, PR 26).
+_LAUNCH_STATICS = ("causal", "block_q", "block_k", "interpret", "caller")
+
+
+@functools.partial(jax.jit, static_argnames=_LAUNCH_STATICS)
+def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
+              caller):
+    bh, s, d = q.shape
+    kernel = functools.partial(
+        _fwd_kernel, sched=_schedule(s, block_q, block_k, causal),
+        scale=d ** -0.5, use_bias=bias is not None, use_segs=segs is not None)
     mem = {"memory_space": pltpu.VMEM}
     grid = (bh, s // block_q)
     qblock = pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j), **mem)
@@ -132,9 +316,11 @@ def _flash_fwd_bh(q, k, v, bias, segs=None, *, causal: bool, block_q: int,
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
         pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0), **mem),
         pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0), **mem),
-        full_row,
     ]
-    args = [q, k, v, bias]
+    args = [q, k, v]
+    if bias is not None:
+        in_specs += [full_row]
+        args += [bias]
     if segs is not None:
         in_specs += [qblock, full_row]   # segq view (q rows), segk view (all keys)
         args += [segs, segs]
@@ -152,191 +338,166 @@ def _flash_fwd_bh(q, k, v, bias, segs=None, *, causal: bool, block_q: int,
         ],
         interpret=interpret,
     )
-    with kernel_scope("flash_fwd"):
+    with kernel_scope("flash_fwd", caller):
         return call(*args)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, delta_ref,
-               *rest, block_k: int, causal: bool, scale: float,
+def _dq_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
                use_segs: bool):
     # Shapes: q/do/dq [1, bq, D], k/v [1, S, D], bias [1, 1, S],
-    # lse/delta [1, 1, bq]. One Q block per grid step, walking K blocks.
-    if use_segs:
-        segq_ref, segk_ref, dq_ref = rest
-    else:
-        (dq_ref,) = rest
+    # lse/delta [1, 1, bq]. One Q block per grid step, walking keys.
+    ((q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref), bias_ref, segq_ref,
+     segk_ref, (dq_ref,)) = _split_refs(refs, 6, use_bias, use_segs)
     bq = q_ref.shape[1]
-    s = k_ref.shape[1]
     qi = pl.program_id(1)
 
     q = q_ref[0]
     do = do_ref[0]
     lse = lse_ref[0, 0][:, None]                         # [bq, 1]
     delta = delta_ref[0, 0][:, None]                     # [bq, 1]
-    acc = jnp.zeros((bq, q_ref.shape[2]), dtype=jnp.float32)
+    segq = segq_ref[0, 0][:, None] if use_segs else None
 
-    num_kb = s // block_k
+    def grad(carry, rows, start, width, tri):
+        # dq of the block's ``rows`` from keys [start, start + width)
+        (acc,) = carry
+        k_blk = k_ref[0, pl.ds(start, width), :]
+        v_blk = v_ref[0, pl.ds(start, width), :]
+        s = _scores(
+            q[rows], k_blk, scale,
+            _row(bias_ref, start, width) if use_bias else None,
+            segq[rows] if use_segs else None,
+            _row(segk_ref, start, width) if use_segs else None,
+            tri, True)
+        p = jnp.exp(s - lse[rows])                       # exact probs via saved lse
+        dp = jax.lax.dot_general(do[rows], v_blk, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta[rows]) * scale
+        return (acc + jax.lax.dot_general(
+            ds.astype(k_blk.dtype), k_blk, _NN,
+            preferred_element_type=jnp.float32),)
 
-    def body(kb, acc):
-        k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        scores = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        scores += bias_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
-        if use_segs:
-            segq = segq_ref[0, 0][:, None]
-            segk = segk_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
-            scores = jnp.where(segq == segk, scores, NEG_INF)
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-        p = jnp.exp(scores - lse)                        # exact probs via saved lse
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta) * scale).astype(k_blk.dtype)
-        return acc + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    last_kb = (
-        jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, num_kb)
-        if causal else num_kb
-    )
-    acc = jax.lax.fori_loop(0, last_kb, body, acc)
+    (acc,) = _sweep(
+        sched, qi, (jnp.zeros((bq, q_ref.shape[2]), dtype=jnp.float32),), grad)
     dq_ref[0] = acc.astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, lse_ref, do_ref, delta_ref,
-                *rest, block_q: int, causal: bool, scale: float,
+def _dkv_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
                 use_segs: bool):
     # Shapes: k/v/dk/dv [1, bk, D], q/do [1, S, D], bias [1, 1, bk],
-    # lse/delta [1, 1, S]. One K block per grid step, walking Q blocks.
-    if use_segs:
-        segq_ref, segk_ref, dk_ref, dv_ref = rest
-    else:
-        dk_ref, dv_ref = rest
+    # lse/delta [1, 1, S]. One K block per grid step, walking rows; the
+    # tile is transposed, [keys, rows], so lse and delta stay row vectors.
+    ((q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref), bias_ref, segq_ref,
+     segk_ref, (dk_ref, dv_ref)) = _split_refs(refs, 6, use_bias, use_segs)
     bk = k_ref.shape[1]
-    s = q_ref.shape[1]
     ki = pl.program_id(1)
 
     k_blk = k_ref[0]
     v_blk = v_ref[0]
-    bias = bias_ref[0, 0][None, :]                       # [1, bk]
-    dk = jnp.zeros(k_blk.shape, dtype=jnp.float32)
-    dv = jnp.zeros(v_blk.shape, dtype=jnp.float32)
+    bias = bias_ref[0, 0][:, None] if use_bias else None  # [bk, 1]
+    segk = segk_ref[0, 0][:, None] if use_segs else None  # [bk, 1]
 
-    num_qb = s // block_q
-
-    def body(qb, carry):
+    def grad(carry, keys, start, width, tri):
+        # dk, dv of the block's ``keys`` from rows [start, start + width)
         dk, dv = carry
-        q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
-        scores = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale + bias
-        if use_segs:
-            segq = segq_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
-            segk = segk_ref[0, 0][None, :]               # [1, bk]
-            scores = jnp.where(segq == segk, scores, NEG_INF)
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-        p = jnp.exp(scores - lse)                        # [bq, bk] f32
+        q_blk = q_ref[0, pl.ds(start, width), :]
+        do_blk = do_ref[0, pl.ds(start, width), :]
+        s = _scores(
+            k_blk[keys], q_blk, scale,
+            bias[keys] if use_bias else None,
+            segk[keys] if use_segs else None,
+            _row(segq_ref, start, width) if use_segs else None,
+            tri, False)                                  # [keys, width] f32
+        p = jnp.exp(s - _row(lse_ref, start, width))
         dv = dv + jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            p.astype(do_blk.dtype), do_blk, _NN,
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_blk[keys], do_blk, _NT,
+                                 preferred_element_type=jnp.float32)
         # d(scale·q·kᵀ)/dk = scale·q; fold the scale into ds.
-        ds = (p * (dp - delta) * scale).astype(q_blk.dtype)
+        ds = p * (dp - _row(delta_ref, start, width)) * scale
         dk = dk + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            ds.astype(q_blk.dtype), q_blk, _NN,
+            preferred_element_type=jnp.float32)
         return dk, dv
 
-    # Causal: Q blocks strictly before this K block never attend to it.
-    first_qb = (ki * bk) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(first_qb, num_qb, body, (dk, dv))
+    dk, dv = _sweep(
+        sched, ki,
+        (jnp.zeros(k_blk.shape, dtype=jnp.float32),
+         jnp.zeros(v_blk.shape, dtype=jnp.float32)),
+        grad)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
                   block_k, interpret, delta_shift=None):
+    s = q.shape[1]
+    return _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift,
+                     causal=causal, block_q=min(block_q, s),
+                     block_k=min(block_k, s), interpret=interpret,
+                     caller=caller_scope())
+
+
+@functools.partial(jax.jit, static_argnames=_LAUNCH_STATICS)
+def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
+              block_q, block_k, interpret, caller):
     bh, s, d = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    scale = d ** -0.5
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = delta[:, None, :]                            # [BH, 1, S]
     if delta_shift is not None:
         # lse cotangent from _flash_bh_lse: ds = p*(dp - delta + g_lse).
         delta = delta - delta_shift.astype(jnp.float32)
-    use_segs = segs is not None
+    static = dict(scale=d ** -0.5, use_bias=bias is not None,
+                  use_segs=segs is not None)
 
     mem = {"memory_space": pltpu.VMEM}
-    full = lambda last: pl.BlockSpec((1, s, last), lambda i, j: (i, 0, 0), **mem)
+    full = pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0), **mem)
     full_row = pl.BlockSpec((1, 1, s), lambda i, j: (i, 0, 0), **mem)
+    qblock = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem)
+    kblock = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **mem)
     qrow = pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j), **mem)
     krow = pl.BlockSpec((1, 1, block_k), lambda i, j: (i, 0, j), **mem)
+    args = [q, k, v, lse, do, delta]
 
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
-        full(d), full(d), full_row, qrow,
-        pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
-        qrow,
-    ]
-    dq_args = [q, k, v, bias, lse, do, delta]
-    if use_segs:
+    dq_specs = [qblock, full, full, qrow, qblock, qrow]
+    dkv_specs = [full, kblock, kblock, full_row, full, full_row]
+    if bias is not None:
+        args += [bias]
+        dq_specs += [full_row]
+        dkv_specs += [krow]
+    if segs is not None:
+        args += [segs, segs]
         dq_specs += [qrow, full_row]
-        dq_args += [segs, segs]
+        dkv_specs += [full_row, krow]
+
     dq_call = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale, use_segs=use_segs),
+        functools.partial(
+            _dq_kernel, sched=_schedule(s, block_q, block_k, causal), **static),
         grid=(bh, s // block_q),
         in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
+        out_specs=qblock,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
     )
-    with kernel_scope("flash_dq"):
-        dq = dq_call(*dq_args)
+    with kernel_scope("flash_dq", caller):
+        dq = dq_call(*args)
 
-    dkv_specs = [
-        full(d),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **mem),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **mem),
-        krow, full_row, full(d), full_row,
-    ]
-    dkv_args = [q, k, v, bias, lse, do, delta]
-    if use_segs:
-        dkv_specs += [full_row, krow]
-        dkv_args += [segs, segs]
     dkv_call = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale, use_segs=use_segs),
+        functools.partial(
+            _dkv_kernel,
+            sched=_schedule(s, block_q, block_k, causal, walks_rows=True),
+            **static),
         grid=(bh, s // block_k),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **mem),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **mem),
-        ],
+        out_specs=[kblock, kblock],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         interpret=interpret,
     )
-    with kernel_scope("flash_dkv"):
-        dk, dv = dkv_call(*dkv_args)
+    with kernel_scope("flash_dkv", caller):
+        dk, dv = dkv_call(*args)
     return dq, dk, dv
 
 
@@ -400,17 +561,14 @@ _flash_bh_lse.defvjp(_flash_bh_lse_fwd, _flash_bh_lse_bwd)
 
 def _pick_seq_block(s: int, desired: int) -> int:
     """Largest Mosaic-valid sequence block: the [.., 1, S] row-vectors
-    make S a lane dim, so blocks must be multiples of 128 (or full S)."""
-    from pyspark_tf_gke_tpu.ops.pallas.common import pick_block
-
-    return pick_block(s, desired, 128)
+    make S a lane dim, so blocks must be multiples of 128 (or full S). A
+    sequence of up to ``WHOLE_SEQ`` is one block (module docstring)."""
+    return s if s <= WHOLE_SEQ else pick_block(s, desired, 128)
 
 
 def _prep_bh(q, k, v, kv_mask, segment_ids, block_q, block_k, interpret):
     b, s, h, d = q.shape
     if interpret is None:
-        from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu
-
         interpret = not on_tpu()
     if block_q is None:
         block_q = _pick_seq_block(s, DEFAULT_BLOCK_Q)
@@ -420,12 +578,10 @@ def _prep_bh(q, k, v, kv_mask, segment_ids, block_q, block_k, interpret):
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
-    if kv_mask is None:
-        bias = jnp.zeros((b, s), dtype=jnp.float32)
-    else:
+    bias = segs = None
+    if kv_mask is not None:
         bias = jnp.where(kv_mask.astype(bool), 0.0, NEG_INF).astype(jnp.float32)
-    bias = jnp.repeat(bias, h, axis=0)[:, None, :]  # [BH, 1, S]
-    segs = None
+        bias = jnp.repeat(bias, h, axis=0)[:, None, :]  # [BH, 1, S]
     if segment_ids is not None:
         segs = jnp.repeat(segment_ids.astype(jnp.int32), h, axis=0)[:, None, :]
     return to_bh(q), to_bh(k), to_bh(v), bias, segs, block_q, block_k, interpret

@@ -12,13 +12,27 @@ joins the kernel's name to the scope it is called from:
 ``%attention._causal_attend.flash_fwd.N``.
 """
 
+from typing import Optional
+
 import jax
 from jax.extend import source_info_util
 
 
-def kernel_scope(kernel_name: str):
-    """``jax.named_scope`` for one kernel launch: ``<caller's innermost
-    scope>.<kernel_name>``, or the kernel's name alone outside any scope."""
+def caller_scope() -> str:
+    """The innermost name scope of the code being traced, '' outside any."""
     scopes = [e.name for e in source_info_util.current_name_stack().stack
               if type(e).__name__ == "Scope"]      # not the jvp/transpose marks
-    return jax.named_scope(f"{scopes[-1]}.{kernel_name}" if scopes else kernel_name)
+    return scopes[-1] if scopes else ""
+
+
+def kernel_scope(kernel_name: str, caller: Optional[str] = None):
+    """``jax.named_scope`` for one kernel launch: ``<caller's innermost
+    scope>.<kernel_name>``, or the kernel's name alone outside any scope.
+    A launch that is traced apart from its call site (inside a ``jax.jit`` of
+    its own, whose name stack starts empty) passes the ``caller_scope()`` it
+    read at the call site. The name is then part of that ``jit``'s cache key:
+    a model's layers share one trace of a kernel only where their innermost
+    scope has the same name in every layer."""
+    if caller is None:
+        caller = caller_scope()
+    return jax.named_scope(f"{caller}.{kernel_name}" if caller else kernel_name)

@@ -318,3 +318,185 @@ def test_flash_causal_with_segment_ids_matches_dense():
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-2, rtol=5e-2)
+
+
+# -- PR 26: the causal schedule, no bias operand without a kv_mask -----------
+
+def _flash_module():
+    import importlib
+
+    return importlib.import_module(
+        "pyspark_tf_gke_tpu.ops.pallas.flash_attention")
+
+
+def _coverage(sched):
+    """[grid position, walk position] counts of what a kernel that follows
+    ``sched`` computes, and of what it passes through the mask, by brute
+    force over every step and strip of every grid block."""
+    s = sched.s
+    computed = np.zeros((s, s), np.int32)
+    masked = np.zeros((s, s), np.int32)
+    for i in range(s // sched.block):
+        rows = slice(i * sched.block, (i + 1) * sched.block)
+        lo, hi = sched.full_steps(i)
+        for j in range(lo, hi):
+            computed[rows, j * sched.walk:(j + 1) * sched.walk] += 1
+        corner = i * sched.block
+        for (first, size), (start, width) in zip(sched.sub_blocks(),
+                                                 sched.strips()):
+            g = slice(corner + first, corner + first + size)
+            computed[g, corner + start:corner + start + width] += 1
+            d0 = corner + start + (0 if sched.walks_rows else width - size)
+            masked[g, d0:d0 + size] += 1
+    return computed, masked
+
+
+@pytest.mark.parametrize("walks_rows", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("s,block_q,block_k", [
+    (128, 128, 128), (384, 128, 128), (384, 384, 384), (640, 128, 128),
+    (640, 640, 640), (1024, 512, 512), (1024, 256, 512), (1024, 512, 128),
+    (2048, 512, 512), (2048, 256, 256), (2048, 1024, 1024),
+    (1024, 1024, 1024), (64, 32, 32), (64, 16, 32)])
+def test_flash_schedule_covers_the_triangle_once(s, block_q, block_k, causal,
+                                                 walks_rows):
+    fa = _flash_module()
+    sched = fa._schedule(s, block_q, block_k, causal, walks_rows)
+    computed, masked = _coverage(sched)
+    grid_pos, walk_pos = np.mgrid[:s, :s]
+    row, key = (walk_pos, grid_pos) if walks_rows else (grid_pos, walk_pos)
+    wanted = (row >= key) if causal else np.ones((s, s), bool)
+    # every wanted pair exactly once, nothing twice
+    assert computed.max() == 1
+    assert (computed[wanted] == 1).all()
+    # what is computed and not wanted lies in a masked (diagonal) tile
+    assert (masked[(computed == 1) & ~wanted] == 1).all()
+    assert ((masked == 1) <= (computed == 1)).all()
+    if causal:
+        # a diagonal tile is square, on the diagonal, and holds wanted pairs:
+        # nothing is computed beyond the edge of the tile the diagonal crosses
+        t = sched.tile
+        assert (np.abs(row - key)[masked == 1] < t).all()
+        assert (row // t == key // t)[masked == 1].all()
+    else:
+        assert masked.sum() == 0 and sched.strips() == []
+    n_computed, n_masked, n_wasted = sched.counts()
+    assert n_computed == computed.sum()
+    assert n_masked == masked.sum()
+    assert n_wasted == ((computed == 1) & ~wanted).sum()
+
+
+def test_flash_schedule_counts_at_the_benchmark_cell():
+    fa = _flash_module()
+    block = fa._pick_seq_block(1024, fa.DEFAULT_BLOCK_Q)
+    assert block == fa._pick_seq_block(1024, fa.DEFAULT_BLOCK_K) == 1024
+    # longer sequences keep the 512-row grid block
+    assert fa._pick_seq_block(2048, fa.DEFAULT_BLOCK_Q) == 512
+    assert fa._pick_seq_block(1152, fa.DEFAULT_BLOCK_K) == 384
+    for walks_rows in (False, True):
+        sched = fa._schedule(1024, block, block, True, walks_rows)
+        # one block a head: no full step, eight strips, all of it static
+        assert (sched.tile, sched.full_steps(0)) == (128, (0, 0))
+        assert len(sched.strips()) == 8
+        assert sched.counts() == (589_824, 131_072, 65_024)
+        # the same triangle from 512-row grid blocks and a 512-wide walk
+        sched = fa._schedule(1024, 512, 512, True, walks_rows)
+        assert (sched.walk, sched.tile) == (512, 128)
+        assert sched.counts() == (589_824, 131_072, 65_024)
+    # the tests' narrow blocks and a sequence taken whole: one masked tile
+    assert fa._schedule(64, 32, 32, True).tile == 32
+    assert fa._schedule(200, 200, 200, True).counts() == (200 * 200, 200 * 200,
+                                                          200 * 199 // 2)
+    # not causal: the walk is capped, every step is full
+    sched = fa._schedule(2048, 1024, 1024, False)
+    assert (sched.walk, sched.full_steps(1), sched.strips()) == (512, (0, 4), [])
+
+
+def _real_tiling_inputs(d=64, dtype=jnp.bfloat16, seed=3, s=1024):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return tuple(jax.random.normal(k, (1, s, 2, d), jnp.float32).astype(dtype)
+                 for k in ks)
+
+
+def _out_and_grads(attend, q, k, v, g):
+    out, vjp = jax.vjp(attend, q, k, v)
+    return (out,) + vjp(g)
+
+
+_REAL_TILING_CASES = {
+    "causal": dict(causal=True),
+    "causal+segments": dict(causal=True, segments=True),
+    "causal+kv_mask": dict(causal=True, masked=True),
+    "full": dict(causal=False),
+    # 512-row grid blocks against a 256-wide walk: the dynamic loop over full
+    # steps, then the strips on its carry
+    "causal,blocks=512x256": dict(causal=True, block_q=512, block_k=256),
+    "causal+segments+kv_mask,blocks=256x512": dict(
+        causal=True, segments=True, masked=True, block_q=256, block_k=512),
+    # 16 full steps of 128 keys: more than are unrolled, a loop of static bounds
+    "full+kv_mask,S=2048,blocks=256x128": dict(
+        causal=False, masked=True, s=2048, block_q=256, block_k=128),
+}
+
+
+@pytest.mark.parametrize("case", list(_REAL_TILING_CASES))
+def test_flash_real_tiling_matches_dense(case):
+    """The benchmark cell's tiling (S 1024, head_dim 64, bf16, default
+    blocks: one grid block a head, tile 128) and narrower grid blocks:
+    forward and all three gradients."""
+    opts = _REAL_TILING_CASES[case]
+    q, k, v, g = _real_tiling_inputs(s=opts.get("s", 1024))
+    s = q.shape[1]
+    kv_mask = seg = None
+    mask = None
+    if opts.get("masked"):
+        kv_mask = jnp.asarray(np.arange(s) < s - 124)[None, :]
+        mask = kv_mask[:, None, None, :]
+    if opts.get("segments"):
+        seg = jnp.asarray(np.searchsorted([300, 512, 777], np.arange(s),
+                                          side="right"), jnp.int32)[None, :]
+        same = seg[:, None, :, None] == seg[:, None, None, :]
+        mask = same if mask is None else same & mask
+    got = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, kv_mask=kv_mask,
+                                        causal=opts["causal"], segment_ids=seg,
+                                        block_q=opts.get("block_q"),
+                                        block_k=opts.get("block_k"),
+                                        interpret=True), q, k, v, g)
+    want = _out_and_grads(
+        lambda q, k, v: dot_product_attention(q, k, v, mask=mask,
+                                              causal=opts["causal"]), q, k, v, g)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=2e-2, rtol=2e-2, err_msg=name)
+
+
+@pytest.mark.parametrize("d", [64, 96])
+def test_flash_head_dim_matches_dense(d):
+    """A scale that is a power of two (head_dim 64) and one that is not."""
+    q, k, v, g = _real_tiling_inputs(d=d, dtype=jnp.float32)
+    q, k, v, g = (x[:, :256] for x in (q, k, v, g))
+    got = _out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=True),
+        q, k, v, g)
+    want = _out_and_grads(
+        lambda q, k, v: dot_product_attention(q, k, v, causal=True), q, k, v, g)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=2e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_no_kv_mask_equals_all_true_kv_mask(causal):
+    q, k, v, g = _real_tiling_inputs()
+    q, k, v, g = (x[:, :256] for x in (q, k, v, g))
+    all_true = jnp.ones((1, 256), bool)
+    without, with_mask = (
+        _out_and_grads(
+            lambda q, k, v: flash_attention(q, k, v, kv_mask=m, causal=causal,
+                                            interpret=True), q, k, v, g)
+        for m in (None, all_true))
+    for a, b in zip(without, with_mask):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
