@@ -96,9 +96,11 @@ class BertConfig:
         return self.hidden_size // self.num_heads
 
 
-def _dense(features, kernel_axes, cfg: BertConfig, name=None):
+def _dense(features, kernel_axes, cfg: BertConfig, name=None,
+           use_bias: bool = True):
     return nn.Dense(
         features,
+        use_bias=use_bias,
         dtype=cfg.dtype,
         kernel_init=nn.with_logical_partitioning(
             nn.initializers.normal(stddev=0.02), kernel_axes

@@ -22,6 +22,7 @@ style:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -138,3 +139,162 @@ class MoELayer(nn.Module):
         ye = nn.with_logical_constraint(ye, ("expert", "batch", None, "embed"))
         out = jnp.einsum("bsec,ebch->bsh", combine.astype(self.dtype), ye)
         return out.astype(x.dtype), aux_loss.astype(jnp.float32)
+
+
+def _slab(start, xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
+          dtype):
+    """The held assignments ``[start, start + rows)`` of the sorted list
+    ``order``, as their weighted outputs scattered to their tokens
+    (``[T, H]`` float32). ``ends`` and ``loads`` are the held experts'
+    cumulative and own assignment counts."""
+    mine = jax.lax.dynamic_slice(order, (start,), (rows,))
+    valid = start + jnp.arange(rows) < ends[-1]
+    token = mine // k
+    sizes = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - loads - start, 0, rows)
+    # rows past the last assignment join the last group: they are computed
+    # on real tokens and discarded, never left undefined
+    sizes = sizes.at[-1].add(rows - jnp.sum(sizes))
+    cast = lambda w: w.astype(dtype)
+    xs = cast(xt[token])
+    mid = jax.nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), sizes)) \
+        * jax.lax.ragged_dot(xs, cast(w_up), sizes)
+    ys = jax.lax.ragged_dot(mid, cast(w_down), sizes,
+                            preferred_element_type=jnp.float32)
+    ys = jnp.where(valid[:, None], ys * flat_w[mine][:, None], 0.0)
+    return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _held_experts(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
+                  dtype):
+    """Sum of :func:`_slab` over the slabs that hold an assignment: a loop
+    whose trip count follows the load (``ceil(held assignments / rows)``), in
+    the forward and in the backward alike, so no slab's operands are kept."""
+    return _held_experts_fwd(xt, flat_w, w_gate, w_up, w_down, order, ends, loads,
+                             rows, k, dtype)[0]
+
+
+def _held_experts_fwd(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows,
+                      k, dtype):
+    diff, ints = (xt, flat_w, w_gate, w_up, w_down), (order, ends, loads)
+    out = jax.lax.fori_loop(
+        0, -(-ends[-1] // rows),
+        lambda i, acc: acc + _slab(i * rows, *diff, *ints, rows, k, dtype),
+        jnp.zeros(xt.shape, jnp.float32))
+    return out, (diff, ints)
+
+
+def _held_experts_bwd(rows, k, dtype, residuals, g):
+    diff, ints = residuals
+
+    def one(i, acc):
+        _, pull = jax.vjp(
+            lambda *d: _slab(i * rows, *d, *ints, rows, k, dtype), *diff)
+        return jax.tree.map(jnp.add, acc, pull(g))
+
+    grads = jax.lax.fori_loop(0, -(-ints[1][-1] // rows), one,
+                              jax.tree.map(jnp.zeros_like, diff))
+    return (*grads, None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+class SwiGLU(nn.Module):
+    """``(silu(x W_gate) * (x W_up)) W_down``, no biases."""
+
+    hidden_size: int
+    intermediate_size: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            kernel_init=nn.initializers.normal(stddev=0.02), name=name)
+
+        mid = jax.nn.silu(dense(self.intermediate_size, "gate")(x)) \
+            * dense(self.intermediate_size, "up")(x)
+        return dense(self.hidden_size, "down")(mid)
+
+
+class HeldExpertsLayer(nn.Module):
+    """One chip's share of a dropless expert layer (expert parallelism seen
+    from one member of the group).
+
+    The router keeps its full width ``num_experts`` and its ``top_k``; this
+    chip holds the experts ``held = (first, count)`` and computes their part
+    of ``y = sum_chosen w_e E_e(x)``, with ``E(x) = (silu(x W1) * (x W3)) W2``,
+    plus the ``shared`` always-on expert. What the absent experts would add is
+    left out; on one chip there is no exchange. Routing is sigmoid scores,
+    the top ``top_k`` of ``s + b`` (``router_bias``, a buffer: no gradient),
+    weights ``route_scale * s_e / sum_chosen s``. No capacity, no dropped
+    token, no auxiliary loss.
+
+    The assignments to held experts are sorted by expert and multiplied by
+    groups (``jax.lax.ragged_dot``) in slabs of ``slab_rows`` rows by a loop
+    that runs as many slabs as hold an assignment, so the work follows the
+    load, and however many tokens choose a held expert (at most ``top_k`` x
+    tokens assignments) every one is computed.
+
+    Shape-preserving on ``[B, S, H]``; returns ``(out, counters)`` with
+    ``held_assignments`` (assignments to held experts in this call) and
+    ``held_load_max`` (those of the busiest held expert), float32 scalars.
+    """
+
+    num_experts: int
+    held: Tuple[int, int]
+    top_k: int
+    hidden_size: int
+    intermediate_size: int
+    route_scale: float = 1.0
+    shared: int = 0               # shared experts, as one FFN of that many widths
+    slab_rows: int = 0            # 0 = twice the mean load of the held experts
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        b, s, h = x.shape
+        tokens, k = b * s, self.top_k
+        first, count = self.held
+        wide = self.intermediate_size
+        init = nn.initializers.normal(stddev=0.02)
+        bias = self.param("router_bias", nn.initializers.zeros_init(),
+                          (self.num_experts,), jnp.float32)
+        w_gate = self.param("w_gate", init, (count, h, wide), jnp.float32)
+        w_up = self.param("w_up", init, (count, h, wide), jnp.float32)
+        w_down = self.param("w_down", init, (count, wide, h), jnp.float32)
+        xt = x.reshape(tokens, h)
+
+        # ---- routing, float32 -------------------------------------------
+        scores = jax.nn.sigmoid(nn.Dense(
+            self.num_experts, use_bias=False, dtype=jnp.float32, kernel_init=init,
+            precision=jax.lax.Precision.HIGHEST, name="router")(
+                xt.astype(jnp.float32)))
+        _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)   # [T, k]
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = self.route_scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+
+        # ---- held assignments, sorted by expert -------------------------
+        local = chosen.reshape(-1) - first                  # [T * k]
+        # the held expert's index, or ``count`` for an expert held elsewhere
+        group = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(group, stable=True)
+        # counted by a one-hot sum: a bincount is a scatter, 1.2 ms a call on the
+        # v5e against 0.1 (PERF.md §6, PR 27)
+        loads = jnp.sum(jax.nn.one_hot(group, count, dtype=jnp.int32), axis=0)
+        ends = jnp.cumsum(loads)
+        total = ends[-1]
+        # a slab of twice the mean load, so that one slab is the usual case
+        mean_load = tokens * k * count // self.num_experts
+        rows = self.slab_rows or -(-2 * max(mean_load, 128) // 256) * 256
+        rows = min(rows, tokens * k)
+        slabs = -(-tokens * k // rows)
+        order = jnp.pad(order, (0, slabs * rows - tokens * k))
+        out = _held_experts(xt, weights.reshape(-1), w_gate, w_up, w_down,
+                            order, ends, loads, rows, k, jnp.dtype(self.dtype))
+        if self.shared:
+            out = out + SwiGLU(h, wide * self.shared, self.dtype, name="shared")(xt)
+        counters = {"held_assignments": total.astype(jnp.float32),
+                    "held_load_max": jnp.max(loads).astype(jnp.float32)}
+        return out.reshape(b, s, h).astype(x.dtype), counters

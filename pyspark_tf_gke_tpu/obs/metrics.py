@@ -523,6 +523,14 @@ def platform_families(registry: Optional[MetricsRegistry] = None) -> dict:
             "train_epochs_total", "Epochs completed"),
         "train_last_loss": r.gauge(
             "train_last_loss", "Mean loss of the last completed epoch"),
+        "train_moe_held_assignments": r.gauge(
+            "train_moe_held_assignments",
+            "Assignments of tokens to the experts this chip holds, a step, "
+            "summed over the expert layers (mean of the last epoch)"),
+        "train_moe_held_load_max": r.gauge(
+            "train_moe_held_load_max",
+            "Assignments to the busiest held expert of any expert layer, a "
+            "step (mean of the last epoch)"),
         "train_input_wait_ms": r.histogram(
             "train_input_wait_ms",
             "Per optimizer step, time the loop waited for its next "

@@ -6,7 +6,9 @@ VMEM, walks the K/V axis with the online-softmax recurrence (running max
 Q·Kᵀ and P·V hit the MXU with f32 accumulation.
 
 Layout: inputs are ``[BH, S, D]`` (batch×heads collapsed — each grid row
-is independent). An optional additive bias ``[BH, 1, S]`` implements
+is independent); ``v`` (and with it ``o``, ``dO`` and ``dV``) may be narrower
+or wider than ``q`` and ``k`` (MLA: keys of 192, values of 128), and the
+scale is that of the keys' width. An optional additive bias ``[BH, 1, S]`` implements
 padding masks (0 for keep, NEG_INF for drop); without a ``kv_mask`` there
 is no bias operand at all. Optional ``[BH, 1, S]`` segment ids confine
 attention within a packed sequence.
@@ -82,6 +84,10 @@ DEFAULT_BLOCK_K = 512
 # step, and the most full steps that are unrolled (each holds its tiles in
 # VMEM: 16 of them at head_dim 128 pass the 16 MiB a kernel may take).
 WHOLE_SEQ = 1024
+# whole-sequence operands a launch may hold under the default VMEM limit, and
+# the room the tiles and the blocks of a grid step take besides (``_vmem``)
+VMEM_ASK = 8 * 2 ** 20
+VMEM_TILES = 16 * 2 ** 20
 TILE = 128
 WALK = 512
 UNROLL = 8
@@ -225,16 +231,32 @@ def _split_refs(refs, n_in, use_bias, use_segs):
     return ins, bias, segq, segk, rest
 
 
+def _vmem(s: int, d: int, dv: int, dtype) -> dict:
+    """``compiler_params`` for a launch whose whole-sequence operands pass
+    what a kernel may take by default. A kernel holds two whole ``[S, D]`` /
+    ``[S, Dv]`` operands (k and v, or q and dO), twice each (the pipeline's
+    two buffers), lane-padded to 128; at ``VMEM_ASK`` and under the launch is
+    as it always was (PERF.md §6, PR 26: ``D`` 128 at ``S`` 8192 asks 11-12
+    MiB of the v5e's default 16), above it the limit is raised to hold them
+    with the tiles' room on top."""
+    lanes = lambda w: -(-w // 128) * 128
+    held = 2 * s * (lanes(d) + lanes(dv)) * jnp.dtype(dtype).itemsize
+    if held <= VMEM_ASK:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=int(held + VMEM_TILES))}
+
+
 def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
                 use_segs: bool):
-    # Shapes: q [1, bq, D], k/v [1, S, D], bias [1, 1, S], o [1, bq, D],
+    # Shapes: q [1, bq, D], k [1, S, D], v [1, S, Dv], bias [1, 1, S], o [1, bq, Dv],
     # lse [1, 1, bq]; with use_segs also segq [1, 1, bq], segk [1, 1, S]
     # (int32 packed-sequence ids — tokens attend within their segment).
     # Row-vectors ride a leading singleton so their last two block dims
     # satisfy Mosaic's (8, 128)-or-full tiling rule.
     (q_ref, k_ref, v_ref), bias_ref, segq_ref, segk_ref, (o_ref, lse_ref) = (
         _split_refs(refs, 3, use_bias, use_segs))
-    bq, d = q_ref.shape[1], q_ref.shape[2]
+    bq, dv = q_ref.shape[1], v_ref.shape[2]
     qi = pl.program_id(1)  # Q-block index
 
     # Matmul operands stay in the input dtype (bf16 hits the MXU at full
@@ -268,7 +290,7 @@ def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
         sched, qi,
         (jnp.full((bq, 1), NEG_INF, dtype=jnp.float32),
          jnp.zeros((bq, 1), dtype=jnp.float32),
-         jnp.zeros((bq, d), dtype=jnp.float32)),
+         jnp.zeros((bq, dv), dtype=jnp.float32)),
         attend)
 
     valid = m > NEG_INF / 2                              # rows with >=1 unmasked key
@@ -282,9 +304,9 @@ def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
 
 def _flash_fwd_bh(q, k, v, bias=None, segs=None, *, causal: bool,
                   block_q: int, block_k: int, interpret: bool):
-    """q,k,v: [BH, S, D]; bias: optional [BH, 1, S] additive (0 / NEG_INF);
-    segs: optional [BH, 1, S] int32 packed-sequence ids.
-    Returns (out [BH, S, D], lse [BH, 1, S])."""
+    """q,k: [BH, S, D]; v: [BH, S, Dv]; bias: optional [BH, 1, S] additive
+    (0 / NEG_INF); segs: optional [BH, 1, S] int32 packed-sequence ids.
+    Returns (out [BH, S, Dv], lse [BH, 1, S])."""
     s = q.shape[1]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
@@ -305,6 +327,7 @@ _LAUNCH_STATICS = ("causal", "block_q", "block_k", "interpret", "caller")
 def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
               caller):
     bh, s, d = q.shape
+    dv = v.shape[2]
     kernel = functools.partial(
         _fwd_kernel, sched=_schedule(s, block_q, block_k, causal),
         scale=d ** -0.5, use_bias=bias is not None, use_segs=segs is not None)
@@ -315,7 +338,7 @@ def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
         pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0), **mem),
-        pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0), **mem),
+        pl.BlockSpec((1, s, dv), lambda i, j: (i, 0, 0), **mem),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -329,14 +352,15 @@ def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0), **mem),
             qblock,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, s), jnp.float32),
         ],
         interpret=interpret,
+        **_vmem(s, d, dv, q.dtype),
     )
     with kernel_scope("flash_fwd", caller):
         return call(*args)
@@ -344,8 +368,9 @@ def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
 
 def _dq_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
                use_segs: bool):
-    # Shapes: q/do/dq [1, bq, D], k/v [1, S, D], bias [1, 1, S],
-    # lse/delta [1, 1, bq]. One Q block per grid step, walking keys.
+    # Shapes: q/dq [1, bq, D], do [1, bq, Dv], k [1, S, D], v [1, S, Dv],
+    # bias [1, 1, S], lse/delta [1, 1, bq]. One Q block per grid step,
+    # walking keys.
     ((q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref), bias_ref, segq_ref,
      segk_ref, (dq_ref,)) = _split_refs(refs, 6, use_bias, use_segs)
     bq = q_ref.shape[1]
@@ -383,8 +408,8 @@ def _dq_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
 
 def _dkv_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
                 use_segs: bool):
-    # Shapes: k/v/dk/dv [1, bk, D], q/do [1, S, D], bias [1, 1, bk],
-    # lse/delta [1, 1, S]. One K block per grid step, walking rows; the
+    # Shapes: k/dk [1, bk, D], v/dv [1, bk, Dv], q [1, S, D], do [1, S, Dv],
+    # bias [1, 1, bk], lse/delta [1, 1, S]. One K block per grid step, walking rows; the
     # tile is transposed, [keys, rows], so lse and delta stay row vectors.
     ((q_ref, k_ref, v_ref, lse_ref, do_ref, delta_ref), bias_ref, segq_ref,
      segk_ref, (dk_ref, dv_ref)) = _split_refs(refs, 6, use_bias, use_segs)
@@ -442,6 +467,7 @@ def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
 def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
               block_q, block_k, interpret, caller):
     bh, s, d = q.shape
+    dv = v.shape[2]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = delta[:, None, :]                            # [BH, 1, S]
     if delta_shift is not None:
@@ -451,16 +477,21 @@ def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
                   use_segs=segs is not None)
 
     mem = {"memory_space": pltpu.VMEM}
-    full = pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0), **mem)
+    # a block of q's or k's width, and one of v's (the same spec when equal)
+    def wide(rows, index, width):
+        return pl.BlockSpec((1, rows, width), index, **mem)
+
+    whole, by_block = (lambda i, j: (i, 0, 0)), (lambda i, j: (i, j, 0))
+    full, vfull = wide(s, whole, d), wide(s, whole, dv)
     full_row = pl.BlockSpec((1, 1, s), lambda i, j: (i, 0, 0), **mem)
-    qblock = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0), **mem)
-    kblock = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0), **mem)
+    qblock, doblock = wide(block_q, by_block, d), wide(block_q, by_block, dv)
+    kblock, vblock = wide(block_k, by_block, d), wide(block_k, by_block, dv)
     qrow = pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j), **mem)
     krow = pl.BlockSpec((1, 1, block_k), lambda i, j: (i, 0, j), **mem)
     args = [q, k, v, lse, do, delta]
 
-    dq_specs = [qblock, full, full, qrow, qblock, qrow]
-    dkv_specs = [full, kblock, kblock, full_row, full, full_row]
+    dq_specs = [qblock, full, vfull, qrow, doblock, qrow]
+    dkv_specs = [full, kblock, vblock, full_row, vfull, full_row]
     if bias is not None:
         args += [bias]
         dq_specs += [full_row]
@@ -478,6 +509,7 @@ def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
         out_specs=qblock,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
+        **_vmem(s, d, dv, q.dtype),
     )
     with kernel_scope("flash_dq", caller):
         dq = dq_call(*args)
@@ -489,12 +521,13 @@ def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
             **static),
         grid=(bh, s // block_k),
         in_specs=dkv_specs,
-        out_specs=[kblock, kblock],
+        out_specs=[kblock, vblock],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
         ],
         interpret=interpret,
+        **_vmem(s, d, dv, q.dtype),
     )
     with kernel_scope("flash_dkv", caller):
         dk, dv = dkv_call(*args)
@@ -576,7 +609,7 @@ def _prep_bh(q, k, v, kv_mask, segment_ids, block_q, block_k, interpret):
         block_k = _pick_seq_block(s, DEFAULT_BLOCK_K)
 
     def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        return x.transpose(0, 2, 1, 3).reshape(b * h, s, x.shape[-1])
 
     bias = segs = None
     if kv_mask is not None:
@@ -607,7 +640,7 @@ def flash_attention(
         q, k, v, kv_mask, segment_ids, block_q, block_k, interpret
     )
     out = _flash_bh(qb, kb, vb, bias, segs, causal, block_q, block_k, interpret)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
 def flash_attention_block(
@@ -631,7 +664,7 @@ def flash_attention_block(
     )
     out, lse = _flash_bh_lse(qb, kb, vb, bias, segs, False, block_q, block_k,
                              interpret)
-    out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
     lse = lse[:, 0, :].reshape(b, h, s).transpose(0, 2, 1)  # [B, S, H]
     lse = jnp.where(jnp.isposinf(lse), NEG_INF, lse)
     return out, lse

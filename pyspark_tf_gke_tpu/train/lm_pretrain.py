@@ -87,9 +87,17 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--ffn", default=e("FFN") or None,
                    choices=["gelu", "swiglu"])
     p.add_argument("--arch", default=e("ARCH", ""),
-                   choices=["", "gpt2", "llama"],
+                   choices=["", "gpt2", "llama", "kimi-linear"],
                    help="architecture preset: gpt2 = learned+layernorm+gelu "
-                        "(the defaults); llama = rope+rmsnorm+swiglu")
+                        "(the defaults); llama = rope+rmsnorm+swiglu; "
+                        "kimi-linear = the hybrid decoder of models/hybrid_lm.py "
+                        "(KDA + MLA layers, dense + expert FFNs), sized by "
+                        "--model-config")
+    p.add_argument("--model-config", default=e("MODEL_CONFIG", ""),
+                   help="configuration file with the family's published keys "
+                        "(--arch kimi-linear: e.g. benchmark/configs/"
+                        "kimi-linear-48b-a3b.json); it gives every size, the "
+                        "vocabulary among them")
     p.add_argument("--doc-masking", action="store_true",
                    default=_env_bool("DOC_MASKING", False),
                    help="confine attention within document boundaries in "
@@ -153,6 +161,19 @@ def parse_args(argv=None) -> argparse.Namespace:
     return p.parse_args(argv)
 
 
+def _hybrid_config(args, tokenizer, dtype):
+    """``--arch kimi-linear``: every size from ``--model-config``; the
+    tokenizer's ids have to fit the file's (possibly sliced) vocabulary."""
+    from pyspark_tf_gke_tpu.models.hybrid_lm import config_from_file
+
+    cfg = config_from_file(args.model_config, dtype=dtype, remat=args.remat)
+    if tokenizer.vocab_size > cfg.vocab_size:
+        raise SystemExit(
+            f"tokenizer {args.tokenizer!r} has {tokenizer.vocab_size} ids, the "
+            f"model configuration's vocabulary {cfg.vocab_size}")
+    return cfg
+
+
 def main(argv=None) -> dict:
     args = parse_args(argv)
     if not args.data_pattern:
@@ -170,7 +191,14 @@ def main(argv=None) -> dict:
                         "ffn": "gelu"},
                "": {}}
     builtin = {"pos_embedding": "learned", "norm": "layernorm", "ffn": "gelu"}
-    preset = presets[args.arch]
+    hybrid = args.arch == "kimi-linear"
+    if hybrid != bool(args.model_config):
+        raise SystemExit("--arch kimi-linear and --model-config go together")
+    if hybrid and (args.doc_masking or args.export_bundle):
+        raise SystemExit("--arch kimi-linear trains only: no --doc-masking (KDA's "
+                         "state is not reset inside a row) and no --export-bundle "
+                         "(no decode path) yet")
+    preset = presets.get(args.arch, {})
     for name, default in builtin.items():
         explicit = getattr(args, name)
         if explicit is None:
@@ -190,7 +218,8 @@ def main(argv=None) -> dict:
     banner(logger, f"Causal-LM pretraining: {args.data_pattern}")
 
     tokenizer = get_tokenizer(args.tokenizer)
-    cfg = CausalLMConfig(
+    dtype = jnp.bfloat16 if args.compute_dtype == "bfloat16" else jnp.float32
+    cfg = _hybrid_config(args, tokenizer, dtype) if hybrid else CausalLMConfig(
         vocab_size=tokenizer.vocab_size,
         hidden_size=args.hidden_size,
         num_layers=args.num_layers,
@@ -201,13 +230,18 @@ def main(argv=None) -> dict:
         ffn=args.ffn,
         intermediate_size=args.intermediate_size,
         max_seq_len=args.seq_len,
-        dtype=jnp.bfloat16 if args.compute_dtype == "bfloat16" else jnp.float32,
+        dtype=dtype,
         remat=args.remat,
         kv_cache_quant=args.kv_cache_quant,
     )
     mesh = mesh_from_spec(parse_mesh_shape(args.mesh_shape),
                           parse_mesh_shape(args.dcn_mesh_shape))
-    model = CausalLM(cfg, mesh=mesh)
+    if hybrid:
+        from pyspark_tf_gke_tpu.models.hybrid_lm import HybridLM
+
+        model = HybridLM(cfg, mesh=mesh)
+    else:
+        model = CausalLM(cfg, mesh=mesh)
     task = TASKS["causal_lm"](vocab_chunks=args.vocab_chunks or None)
     tx = make_optimizer(
         args.learning_rate, schedule=args.lr_schedule,

@@ -193,7 +193,18 @@ def causal_lm_task(vocab_chunks: Optional[int] = None) -> TrainerTask:
     fp32 ``[B, S, V]`` logits — the memory hog of LM training — never
     materialize. Numerics match the dense path to fp32 tolerance."""
 
-    def _reduce(per_tok, pred_ids, targets, mask):
+    def _apply(model, variables, batch, train, **kw):
+        """``(model's output, its step counters)``: a model that sows
+        counters (``HybridLM``'s expert layers) has them collected and handed
+        on as metrics; any other is applied as ever."""
+        kw.update(segment_ids=batch.get("segment_ids"), train=train)
+        if not getattr(model, "sows_counters", False):
+            return model.apply(variables, batch["input_ids"], **kw), {}
+        out, sown = model.apply(variables, batch["input_ids"],
+                                mutable=["counters"], **kw)
+        return out, model.step_counters(sown.get("counters", {}))
+
+    def _reduce(per_tok, pred_ids, targets, mask, counters):
         if mask is not None:
             m = mask[:, 1:].astype(jnp.float32)
             denom = jnp.maximum(m.sum(), 1.0)
@@ -202,18 +213,17 @@ def causal_lm_task(vocab_chunks: Optional[int] = None) -> TrainerTask:
         else:
             loss = per_tok.mean()
             acc = (pred_ids == targets).astype(jnp.float32).mean()
-        return loss, {"loss": loss, "next_token_accuracy": acc}
+        return loss, {"loss": loss, "next_token_accuracy": acc, **counters}
 
     if vocab_chunks:
         from pyspark_tf_gke_tpu.ops.chunked_ce import chunked_cross_entropy
 
         def forward(model, variables, batch, train, mutable):
-            hidden = model.apply(variables, batch["input_ids"],
-                                 segment_ids=batch.get("segment_ids"),
-                                 return_hidden=True, train=train)
+            hidden, counters = _apply(model, variables, batch, train,
+                                      return_hidden=True)
             head = variables["params"]["lm_head"]
             return {"hidden": hidden, "kernel": head["kernel"],
-                    "bias": head.get("bias")}, None
+                    "bias": head.get("bias"), "counters": counters}, None
 
         def lam(preds, batch):
             ids = batch["input_ids"]
@@ -225,22 +235,21 @@ def causal_lm_task(vocab_chunks: Optional[int] = None) -> TrainerTask:
                 targets.reshape(-1), num_chunks=vocab_chunks)
             return _reduce(per_tok.reshape(b, s1),
                            amax.reshape(b, s1), targets,
-                           batch.get("attention_mask"))
+                           batch.get("attention_mask"), preds["counters"])
 
         return TrainerTask("causal_lm", forward, lam)
 
     def forward(model, variables, batch, train, mutable):
-        return model.apply(variables, batch["input_ids"],
-                           segment_ids=batch.get("segment_ids"),
-                           train=train), None
+        return _apply(model, variables, batch, train), None
 
-    def lam(logits, batch):
+    def lam(preds, batch):
+        logits, counters = preds
         ids = batch["input_ids"]
         targets = ids[:, 1:]
         lg = logits[:, :-1].astype(jnp.float32)
         per_tok = optax.softmax_cross_entropy_with_integer_labels(lg, targets)
         return _reduce(per_tok, jnp.argmax(lg, -1), targets,
-                       batch.get("attention_mask"))
+                       batch.get("attention_mask"), counters)
 
     return TrainerTask("causal_lm", forward, lam)
 
@@ -785,6 +794,9 @@ class Trainer:
                 self._obs["train_epochs_total"].inc()
                 if "loss" in history:
                     self._obs["train_last_loss"].set(history["loss"][-1])
+                for key in ("moe_held_assignments", "moe_held_load_max"):
+                    if key in history:
+                        self._obs[f"train_{key}"].set(history[key][-1])
                 self._event_log.emit(
                     "train_epoch_end", epoch=epoch + 1, global_step=global_step,
                     step_time_ms=round(step_ms, 3),

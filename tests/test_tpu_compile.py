@@ -1,0 +1,71 @@
+"""The new kernels compiled for a described TPU v5e at the widths the
+Kimi-Linear cell runs them at: what interpret mode cannot show (tiling, VMEM,
+what Mosaic lowers). Nothing runs; no chip is needed. One file, so that one
+xdist worker loads the TPU's library."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe means no test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("mxu", ["bfloat16", "float32"])
+def test_kda_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu):
+    from pyspark_tf_gke_tpu.ops.pallas import kda as K
+
+    b, s, h, d = 1, 1024, 4, 128
+    mxu = jnp.dtype(mxu)
+    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32, sharding=one_chip)
+    kw = dict(heads=h, mxu=mxu, interpret=False, caller="")
+    fwd = jax.jit(lambda *a: K._forward(*a, **kw)).lower(x, x, x, x, g).compile()
+    assert fwd.as_text().count("tpu_custom_call") >= 1
+    kept = jax.ShapeDtypeStruct((b, h, s // K.block_rows(s), d, d), jnp.float32,
+                                sharding=one_chip)
+    bwd = jax.jit(lambda *a: K._backward(*a, **kw)).lower(x, x, x, x, g, kept, x).compile()
+    assert bwd.as_text().count("tpu_custom_call") >= 1
+
+
+def test_flash_kernels_compile_with_keys_of_192_and_values_of_128(one_chip, no_compile_cache):
+    """MLA at S 8192: k and q are lane-padded to 256 and held whole, past the
+    16 MiB a kernel may take by default; ``_vmem`` raises the limit."""
+    F = importlib.import_module("pyspark_tf_gke_tpu.ops.pallas.flash_attention")
+    bh, s, d, dv = 4, 8192, 192, 128
+    q = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((bh, s, dv), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, 1, s), jnp.float32, sharding=one_chip)
+    blk = F._pick_seq_block(s, F.DEFAULT_BLOCK_Q)
+    kw = dict(causal=True, block_q=blk, block_k=blk, interpret=False, caller="a")
+    # as the program runs them: tests/conftest.py's "highest" is for the CPU
+    # comparisons, and Mosaic refuses bf16 operands at it
+    with jax.default_matmul_precision("default"):
+        jax.jit(lambda q, k, v: F._fwd_call(q, k, v, None, None, **kw)).lower(q, q, v).compile()
+        jax.jit(lambda q, k, v, l, o, do: F._bwd_call(
+            q, k, v, None, l, o, do, None, None, **kw)).lower(q, q, v, lse, v, v).compile()
