@@ -11,7 +11,8 @@ blocks, no biases, no embedding scale, untied head.
   and a step size ``beta = sigmoid(x W_b)``, both float32; the chunked
   recurrence of ``ops/linear_attention.py::kda`` (Pallas kernels ``kda_fwd`` /
   ``kda_bwd`` on the TPU); ``RMSNorm(o) * sigmoid(x W_ga W_gb)`` and the
-  output projection.
+  output projection. Everything here stays ``[B, S, heads * 128]``: what is
+  per head (the L2 norms, ``beta``'s products, the output's RMS) is ``kda``'s.
 * **MLA without positions** (``MLAAttention``): queries of ``qk_nope + qk_rope``
   = 192 a head, a 512-wide normalised latent expanded to 128 of key and 128
   of value a head, 64 more key columns shared by all heads and not rotated;
@@ -48,7 +49,6 @@ from pyspark_tf_gke_tpu.models.moe import HeldExpertsLayer, SwiGLU
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
 from pyspark_tf_gke_tpu.ops.linear_attention import kda
 
-L2_EPS = 1e-6
 NOT_SERVED = ("HybridLM has no decode or prefill path: the engine's cache holds "
               "neither latent KV pages nor a per-slot recurrent state "
               "(ROADMAP Reach 3 and 4)")
@@ -159,8 +159,16 @@ class CausalConv(nn.Module):
         return sum(xp[:, j:j + s] * taps[j] for j in range(self.size))
 
 
-def _l2_norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
+class _Scale(nn.Module):
+    """A norm's ``scale [features]`` (ones, float32) where the statistics are
+    taken elsewhere: ``RMSNorm``'s leaf by name, shape and partitioning."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.with_logical_partitioning(
+            nn.initializers.ones_init(), ("embed",)), (self.features,), jnp.float32)
 
 
 class KDAAttention(nn.Module):
@@ -172,7 +180,6 @@ class KDAAttention(nn.Module):
         from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES
 
         cfg = self.cfg
-        b, s, _ = hidden.shape
         heads, dim = cfg.kda_heads, cfg.kda_head_dim
         wide = heads * dim
 
@@ -182,26 +189,25 @@ class KDAAttention(nn.Module):
         def mixed(name):
             y = CausalConv(cfg.conv_size, name=f"{name}_conv")(
                 dense(wide, f"{name}_proj")(hidden))
-            return jax.nn.silu(y).reshape(b, s, heads, dim)
+            return jax.nn.silu(y).astype(cfg.dtype)
 
-        q = (_l2_norm(mixed("q")) * dim ** -0.5).astype(cfg.dtype)
-        k = _l2_norm(mixed("k")).astype(cfg.dtype)
-        v = mixed("v").astype(cfg.dtype)
+        q, k, v = mixed("q"), mixed("k"), mixed("v")
         # the decay, beta and the output gate in float32
         a_log = self.param("A_log", nn.initializers.zeros_init(), (heads,), jnp.float32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros_init(), (wide,), jnp.float32)
         f = dense(wide, "f_b")(dense(cfg.gate_rank, "f_a")(hidden)).astype(jnp.float32)
-        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
-            (f + dt_bias).reshape(b, s, heads, dim))
+        g = jnp.repeat(-jnp.exp(a_log), dim) * jax.nn.softplus(f + dt_bias)
         beta = jax.nn.sigmoid(dense(heads, "b_proj")(hidden).astype(jnp.float32))
-        by_head = P(DATA_AXES, None, "tp", None)
-        o = _per_shard(kda, self.mesh, by_head, by_head, by_head, by_head,
-                       P(DATA_AXES, None, "tp"))(q, k, v, g, beta)
+
+        def attend(q, k, v, g, beta):        # under a mesh: a shard's heads
+            return kda(q, k, v, g, beta, heads=beta.shape[-1], eps=cfg.layer_norm_eps)
+
+        o = _per_shard(attend, self.mesh, *[P(DATA_AXES, None, "tp")] * 5)(q, k, v, g, beta)
         gate = dense(wide, "g_b")(dense(cfg.gate_rank, "g_a")(hidden))
-        o = RMSNorm(epsilon=cfg.layer_norm_eps, dtype=jnp.float32, name="o_norm")(o)
-        o = o * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(b, s, heads, dim))
+        scale = jnp.tile(_Scale(dim, name="o_norm")(), heads)
+        o = o.astype(jnp.float32) * scale * jax.nn.sigmoid(gate.astype(jnp.float32))
         return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, name="o_proj",
-                      use_bias=False)(o.reshape(b, s, wide).astype(cfg.dtype))
+                      use_bias=False)(o.astype(cfg.dtype))
 
 
 class MLAAttention(nn.Module):

@@ -26,13 +26,24 @@ token a channel over 15 tokens is not supported). ``(I + A)^-1`` of the
 strictly lower-triangular ``A`` is the product ``(I - A)(I + A^2)(I + A^4)...``,
 which ends because ``A^64 = 0``; it is computed in float32.
 
+The per-head element-wise work around the recurrence is part of the chunk
+(:func:`block_step`), done in float32 on the rows a step holds: the L2 norm of
+``q`` and ``k`` (``q`` also scaled by ``Dk^-1/2``), ``beta k`` and ``beta v``
+(not rounded before use), the log-decay summed from each chunk's first row
+(:func:`_running_sum` of each sub-block, a ``[SUB, SUB]`` triangle of ones
+times it, exact to float32, plus the sum of the sub-blocks before it) and,
+after the chunk, the output divided by the RMS of its head's columns. So the
+caller hands over what its projections write, ``[B, S, H*D]`` with a head a
+128-lane column slab, and never views it as ``[B, S, H, D]``.
+
 :func:`kda` is one ``jax.custom_vjp``: the forward keeps the state at the start
 of every block of ``BLOCK_CHUNKS`` chunks (float32) and the backward walks the
 blocks from the last to the first, recomputes each block from its kept state
-and pulls the cotangents back through it. On the TPU both walks are Pallas
-kernels (``ops/pallas/kda.py``: the state rides in VMEM scratch across a
-sequential grid axis); elsewhere the same algebra (:func:`block_step`) runs
-under ``lax.scan``. A per-token scan is the reference's
+and pulls the cotangents back through it; the residuals are the five operands
+and those states. On the TPU both walks are Pallas kernels
+(``ops/pallas/kda.py``: the state rides in VMEM scratch across a sequential
+grid axis); elsewhere the same algebra (:func:`block_step`) runs under
+``lax.scan``. A per-token scan is the reference's
 (``benchmark/reference/kimi_linear.py``), not the program's.
 """
 
@@ -49,6 +60,7 @@ from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu
 CHUNK = 64
 SUB = 16
 BLOCK_CHUNKS = 4          # chunks a grid step (or a scan step) takes
+L2_EPS = 1e-6             # under the root of q's and k's L2 norm
 _EXP_CAP = 80.0
 
 _NT = (((1,), (1,)), ((), ()))   # a [m, d] · b [n, d]^T -> [m, n]
@@ -67,6 +79,29 @@ def _dot(a, b, dims, mxu):
 def _dot32(a, b):
     return jax.lax.dot_general(a, b, _NN, precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _running_sum(g, reverse=False):
+    """Row ``r`` of ``g [n, D]`` (float32) summed over the rows up to and with
+    ``r`` (from ``r`` on when reversed, which is the transpose and so the
+    backward). A triangle of ones times ``g`` on the MXU, exact to float32 in
+    three bf16 passes: the ones are exact in bf16, and ``g`` is the sum of
+    three bf16 parts of 8 bits each (``HIGHEST`` would split the ones too,
+    and take six)."""
+    bf16, n = jnp.bfloat16, g.shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    ones = jnp.where((r <= col) if reverse else (r >= col), 1.0, 0.0)
+    hi = g.astype(bf16)
+    rest = g - hi.astype(g.dtype)
+    mid = rest.astype(bf16)
+    low = (rest - mid.astype(g.dtype)).astype(bf16)
+    return sum(_dot(ones, part, _NN, bf16) for part in (hi, mid, low))
+
+
+_running_sum.defvjp(lambda g, reverse: (_running_sum(g, reverse), None),
+                    lambda reverse, _, ct: (_running_sum(ct, not reverse),))
 
 
 def _unit_lower_inverse(a):
@@ -125,16 +160,39 @@ def _chunk(q, k, kb, vb, gc, state, mxu):
     return o, state * jnp.exp(g_end) + _dot(u, kd, _TN, mxu)
 
 
-def block_step(subs, state, mxu):
-    """A block of chunks, one after another. ``subs = (q, k, kb, vb, gc)``,
-    each the tuple of the block's ``SUB``-row sub-blocks in order. Returns
-    ``(tuple of o [CHUNK, Dv] per chunk, state after the block)``."""
+def _l2_norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
+
+
+def block_step(subs, state, head, *, mxu, eps):
+    """A block of chunks, one after another, of head ``head``.
+    ``subs = (q, k, v, g, beta)``, each the tuple of the block's ``SUB``-row
+    sub-blocks in order, as :func:`kda` takes them: ``q, k [SUB, Dk]`` and
+    ``v [SUB, Dv]`` not normalised, ``g [SUB, Dk]`` the log-decay a token,
+    ``beta [SUB, H]`` with all the heads' step sizes (column ``head`` is
+    picked here, so that its gradient comes out of the same ``vjp``).
+    Returns ``(tuple of o [CHUNK, Dv] per chunk, each row over its RMS,
+    state after the block)``. All of it float32 but the matmul operands."""
+    f32 = jnp.float32
+    q, k, v, g, beta = subs
+    dk = q[0].shape[-1]
+    mine = jax.lax.broadcasted_iota(jnp.int32, beta[0].shape, 1) == head
     per = CHUNK // SUB
     outs = []
-    for c in range(len(subs[0]) // per):
-        o, state = _chunk(*(list(x[c * per:(c + 1) * per]) for x in subs),
-                          state, mxu)
-        outs.append(o)
+    for c in range(len(q) // per):
+        qs, ks, kb, vb, gc = [], [], [], [], []
+        before = 0.0                  # the chunk's log-decay before this sub-block
+        for i in range(c * per, (c + 1) * per):
+            b_i = jnp.sum(jnp.where(mine, beta[i], 0.0), axis=1, keepdims=True)
+            k_i = _l2_norm(k[i].astype(f32))
+            qs.append(_l2_norm(q[i].astype(f32)) * dk ** -0.5)
+            ks.append(k_i)
+            kb.append(b_i * k_i)
+            vb.append(b_i * v[i].astype(f32))
+            gc.append(_running_sum(g[i]) + before)
+            before = before + jnp.sum(g[i], axis=0, keepdims=True)
+        o, state = _chunk(qs, ks, kb, vb, gc, state, mxu)
+        outs.append(o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps))
     return tuple(outs), state
 
 
@@ -155,9 +213,19 @@ def _split(x):
     return tuple(x[i:i + SUB] for i in range(0, x.shape[0], SUB))
 
 
-def _block_arrays(q, k, kb, vb, gc, state, mxu):
-    outs, state = block_step(tuple(_split(x) for x in (q, k, kb, vb, gc)), state, mxu)
+def _block_arrays(q, k, v, g, beta, state, head, mxu, eps):
+    outs, state = block_step(tuple(_split(x) for x in (q, k, v, g, beta)), state, head,
+                             mxu=mxu, eps=eps)
     return jnp.concatenate(outs, axis=0), state
+
+
+def _scan_step(mxu, eps):
+    """:func:`_block_arrays` over rows and heads: ``q, k, v, g [B, H, rows, D]``,
+    ``beta [B, rows, H]`` (every head reads the whole block), ``state
+    [B, H, Dv, Dk]``, ``head [H]``."""
+    step = functools.partial(_block_arrays, mxu=mxu, eps=eps)
+    return jax.vmap(jax.vmap(step, in_axes=(0, 0, 0, 0, None, 0, 0)),
+                    in_axes=(0, 0, 0, 0, 0, 0, None))
 
 
 def _to_blocks(x, heads, rows):
@@ -171,94 +239,101 @@ def _from_blocks(x):
     return x.transpose(1, 0, 3, 2, 4).reshape(b, nb * rows, h * d)
 
 
-def _fwd_scan(q, k, kb, vb, gc, heads, mxu):
+def _scan_operands(q, k, v, g, beta, heads, rows):
+    b, s, _ = q.shape
+    return tuple(_to_blocks(x, heads, rows) for x in (q, k, v, g)) + (
+        beta.reshape(b, s // rows, rows, heads).transpose(1, 0, 2, 3),)  # [NB, B, rows, H]
+
+
+def _fwd_scan(q, k, v, g, beta, heads, eps, mxu):
     b, s, hd = q.shape
-    rows, d = block_rows(s), hd // heads
-    dv = vb.shape[-1] // heads
-    step = jax.vmap(jax.vmap(functools.partial(_block_arrays, mxu=mxu)))
+    rows, d, dv = block_rows(s), hd // heads, v.shape[-1] // heads
+    step, head = _scan_step(mxu, eps), jnp.arange(heads)
 
     def body(state, xs):
-        o, new = step(*xs, state)
+        o, new = step(*xs, state, head)
         return new, (o, state)
 
-    xs = tuple(_to_blocks(x, heads, rows) for x in (q, k, kb, vb, gc))
-    _, (o, states) = jax.lax.scan(body, jnp.zeros((b, heads, dv, d), jnp.float32), xs)
-    return _from_blocks(o).astype(vb.dtype), states.transpose(1, 2, 0, 3, 4)
+    _, (o, states) = jax.lax.scan(body, jnp.zeros((b, heads, dv, d), jnp.float32),
+                                  _scan_operands(q, k, v, g, beta, heads, rows))
+    return _from_blocks(o).astype(v.dtype), states.transpose(1, 2, 0, 3, 4)
 
 
-def _bwd_scan(q, k, kb, vb, gc, states, do, heads, mxu):
-    rows = block_rows(q.shape[1])
-    step = jax.vmap(jax.vmap(functools.partial(_block_arrays, mxu=mxu)))
+def _bwd_scan(q, k, v, g, beta, states, do, heads, eps, mxu):
+    b, s, _ = q.shape
+    rows = block_rows(s)
+    step, head = _scan_step(mxu, eps), jnp.arange(heads)
 
     def body(dstate, xs):
         *ins, state, g_o = xs
-        _, pull = jax.vjp(step, *ins, state)
+        _, pull = jax.vjp(lambda *a: step(*a, head), *ins, state)
         *d_ins, d_prev = pull((g_o.astype(jnp.float32), dstate))
         return d_prev, tuple(d_ins)
 
-    ins = tuple(_to_blocks(x, heads, rows) for x in (q, k, kb, vb, gc))
-    xs = ins + (states.transpose(2, 0, 1, 3, 4), _to_blocks(do, heads, rows))
-    _, grads = jax.lax.scan(body, jnp.zeros_like(states[:, :, 0]), xs, reverse=True)
-    return tuple(_from_blocks(g).astype(x.dtype)
-                 for g, x in zip(grads, (q, k, kb, vb, gc)))
+    xs = _scan_operands(q, k, v, g, beta, heads, rows) + (
+        states.transpose(2, 0, 1, 3, 4), _to_blocks(do, heads, rows))
+    _, (*grads, dbeta) = jax.lax.scan(body, jnp.zeros_like(states[:, :, 0]), xs,
+                                      reverse=True)
+    return tuple(_from_blocks(d).astype(x.dtype) for d, x in zip(grads, (q, k, v, g))) + (
+        dbeta.transpose(1, 0, 2, 3).reshape(b, s, heads),)
 
 
 # -- one custom_vjp over either walk -----------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _kda_core(q, k, kb, vb, gc, heads, mxu, pallas, interpret):
-    return _core_fwd(q, k, kb, vb, gc, heads, mxu, pallas, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _kda_core(q, k, v, g, beta, heads, eps, mxu, pallas, interpret):
+    return _core_fwd(q, k, v, g, beta, heads, eps, mxu, pallas, interpret)[0]
 
 
-def _core_fwd(q, k, kb, vb, gc, heads, mxu, pallas, interpret):
+def _core_fwd(q, k, v, g, beta, heads, eps, mxu, pallas, interpret):
     if pallas:
         from pyspark_tf_gke_tpu.ops.pallas import kda as kernels
 
-        o, states = kernels.forward(q, k, kb, vb, gc, heads=heads, mxu=mxu,
+        o, states = kernels.forward(q, k, v, g, beta, heads=heads, eps=eps, mxu=mxu,
                                     interpret=interpret)
     else:
-        o, states = _fwd_scan(q, k, kb, vb, gc, heads, mxu)
-    return o, (q, k, kb, vb, gc, states)
+        o, states = _fwd_scan(q, k, v, g, beta, heads, eps, mxu)
+    return o, (q, k, v, g, beta, states)
 
 
-def _core_bwd(heads, mxu, pallas, interpret, residuals, do):
-    q, k, kb, vb, gc, states = residuals
+def _core_bwd(heads, eps, mxu, pallas, interpret, residuals, do):
     if pallas:
         from pyspark_tf_gke_tpu.ops.pallas import kda as kernels
 
-        return kernels.backward(q, k, kb, vb, gc, states, do, heads=heads,
-                                mxu=mxu, interpret=interpret)
-    return _bwd_scan(q, k, kb, vb, gc, states, do, heads, mxu)
+        return kernels.backward(*residuals, do, heads=heads, eps=eps, mxu=mxu,
+                                interpret=interpret)
+    return _bwd_scan(*residuals, do, heads, eps, mxu)
 
 
 _kda_core.defvjp(_core_fwd, _core_bwd)
 
 
-def kda(q: jnp.ndarray,                # [B, S, H, Dk], normalised and scaled
-        k: jnp.ndarray,                # [B, S, H, Dk], normalised
-        v: jnp.ndarray,                # [B, S, H, Dv]
-        g: jnp.ndarray,                # [B, S, H, Dk] log-decay <= 0
+def kda(q: jnp.ndarray,                # [B, S, H*Dk] not normalised
+        k: jnp.ndarray,                # [B, S, H*Dk] not normalised
+        v: jnp.ndarray,                # [B, S, H*Dv]
+        g: jnp.ndarray,                # [B, S, H*Dk] log-decay a token, <= 0
         beta: jnp.ndarray,             # [B, S, H] in (0, 1)
-        *, pallas: Optional[bool] = None,
+        *, heads: int, eps: float, pallas: Optional[bool] = None,
         interpret: bool = False) -> jnp.ndarray:
-    """Chunked KDA, forward and backward (module docstring). Returns
-    ``o [B, S, H, Dv]`` in ``v``'s dtype. The state starts at 0 in every row.
-    ``pallas=None`` takes the kernels on the TPU and ``lax.scan`` elsewhere;
-    ``interpret`` runs the kernels in the Pallas interpreter (tests).
-    The chunk's matmuls take their operands in ``q``'s dtype; decays,
-    ``beta`` and the state are float32."""
-    b, s, h, dk = q.shape
-    dv = v.shape[-1]
+    """Chunked KDA, forward and backward (module docstring), of operands as the
+    projections write them: head ``h`` is columns ``[h D, (h + 1) D)``.
+    Per head ``q`` and ``k`` are L2-normalised (``q`` also times ``Dk^-1/2``)
+    and the recurrence's output is divided by the RMS of its ``Dv`` columns
+    (``eps`` under the root); the caller's norm scale and gate come after.
+    Returns ``o [B, S, H*Dv]`` in ``v``'s dtype. The state starts at 0 in
+    every row. ``pallas=None`` takes the kernels on the TPU and ``lax.scan``
+    elsewhere; ``interpret`` runs the kernels in the Pallas interpreter
+    (tests). The chunk's matmuls take their operands in ``q``'s dtype; the
+    norms' statistics, ``beta``, decays, their sums and the state are
+    float32."""
+    b, s, wide = q.shape
+    if wide % heads or v.shape[-1] % heads or beta.shape[-1] != heads:
+        raise ValueError(
+            f"kda: {heads} heads do not divide q's {wide} and v's {v.shape[-1]} "
+            f"columns, or are not beta's {beta.shape[-1]}")
     block_rows(s)                                           # refuses a ragged sequence
     if pallas is None:
         pallas = on_tpu() or interpret
-    mxu = jnp.dtype(q.dtype)
-    beta = beta.astype(jnp.float32)[..., None]
-    kb = (k.astype(jnp.float32) * beta).astype(k.dtype)
-    vb = (v.astype(jnp.float32) * beta).astype(v.dtype)
-    gc = jnp.cumsum(g.astype(jnp.float32).reshape(b, s // CHUNK, CHUNK, h * dk),
-                    axis=2).reshape(b, s, h * dk)
-    o = _kda_core(q.reshape(b, s, h * dk), k.reshape(b, s, h * dk),
-                  kb.reshape(b, s, h * dk), vb.reshape(b, s, h * dv), gc,
-                  h, mxu, bool(pallas), bool(interpret))
-    return o.reshape(b, s, h, dv)
+    return _kda_core(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32),
+                     int(heads), float(eps), jnp.dtype(q.dtype), bool(pallas),
+                     bool(interpret))

@@ -21,7 +21,7 @@ from lib import weights_kimi_linear as K  # noqa: E402
 from reference import kimi_linear as R  # noqa: E402
 
 from pyspark_tf_gke_tpu.models.hybrid_lm import (HybridLM, HybridLMConfig,  # noqa: E402
-                                                 config_from_file)
+                                                 KDAAttention, config_from_file)
 
 REAL = os.path.join(ROOT, "benchmark", "configs", "kimi-linear-48b-a3b.json")
 TINY = os.path.join(ROOT, "benchmark", "tests", "data", "configs", "tiny-kimi.json")
@@ -81,6 +81,79 @@ def test_the_cut_configuration_is_five_layers_and_602_million_parameters():
     count = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(tree))
     with open(REAL) as f:
         assert count == K.param_count(json.load(f)) == 602_434_432
+
+
+def test_the_cut_configurations_tree_is_the_benchmarks_leaf_table():
+    """Paths, shapes and dtypes at the real widths: the benchmark makes its
+    weights by these names (``A_log (32,)``, ``dt_bias (4096,)``,
+    ``o_norm/scale (128,)``, ``q_conv/kernel (4, 4096)``), all float32."""
+    with open(REAL) as f:
+        real = json.load(f)
+    model = HybridLM(config_from_file(real))
+    tree = W.flatten(nn.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 64), jnp.int32)))["params"]))
+    assert {n: (v.shape, v.dtype) for n, v in tree.items()} == {
+        n: (tuple(s), jnp.float32) for n, s in K.leaf_shapes(real).items()}
+    kda = {n.split("attention/")[1]: v.shape for n, v in tree.items()
+           if n.startswith("layer_0/attention/")}
+    assert (kda["A_log"], kda["dt_bias"], kda["o_norm/scale"], kda["q_conv/kernel"]) == (
+        (32,), (4096,), (128,), (4, 4096))
+
+
+def _equations(jaxpr, skipped):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, but for what a
+    ``custom_vjp`` call holds (counted in ``skipped``)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("custom_vjp_call"):
+            skipped.append(eqn)
+            continue
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, skipped)
+
+
+def test_kda_attention_never_leaves_the_flat_layout(tiny):
+    """Outside ``kda``'s own ``custom_vjp`` call (whose ``lax.scan`` walk off
+    the TPU blocks its operands as it likes) the layer holds no ``[B, S, H,
+    D]`` view and sums no decay: on the chip each such view of a float32
+    ``[2, 8192, 4096]`` is a re-tiling of 268 MB."""
+    cfg = config_from_file(tiny, dtype=jnp.float32)
+    layer = KDAAttention(cfg)
+    hidden = jnp.zeros((1, 128, cfg.hidden_size), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), hidden)
+    skipped = []
+    eqns = list(_equations(jax.make_jaxpr(layer.apply)(params, hidden).jaxpr, skipped))
+    assert len(skipped) == 1 and len(eqns) > 20
+    # five arrays in, the sequence on axis 1, nothing of rank 4
+    assert [v.aval.shape for v in skipped[0].invars[-5:]] == [(1, 128, 256)] * 4 + [(1, 128, 2)]
+    for eqn in eqns:
+        name = eqn.primitive.name
+        assert name not in ("cumsum", "cumlogsumexp", "cummax", "cumprod"), eqn
+        assert not name.startswith("reduce_window"), eqn
+        if name in ("reshape", "broadcast_in_dim", "transpose"):
+            assert all(len(v.aval.shape) < 4 for v in eqn.outvars), eqn
+
+
+def test_kda_attention_per_shard_over_rows_and_heads(tiny):
+    """Under a ``dp=2, tp=2`` mesh of the CPU's devices each shard runs ``kda``
+    on its rows and its heads' columns of all five flat operands (``beta``'s
+    last axis is its heads): outputs and gradients are the one-device layer's."""
+    from pyspark_tf_gke_tpu.parallel.mesh import make_mesh
+
+    cfg = config_from_file(tiny, dtype=jnp.float32)
+    hidden = jax.random.normal(jax.random.PRNGKey(1), (2, 128, cfg.hidden_size))
+    plain = KDAAttention(cfg)
+    params = jax.tree.map(
+        lambda x: x + 0.1 * jax.random.normal(jax.random.PRNGKey(3), x.shape),
+        nn.unbox(plain.init(jax.random.PRNGKey(0), hidden)))
+    mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    sharded = KDAAttention(cfg, mesh=mesh)
+    loss = lambda layer: lambda p, h: jnp.sum(jnp.square(layer.apply(p, h)))
+    with mesh:
+        got = jax.jit(jax.value_and_grad(loss(sharded)))(params, hidden)
+    want = jax.value_and_grad(loss(plain))(params, hidden)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-4 * float(jnp.max(jnp.abs(w)))
 
 
 @pytest.mark.parametrize("kw,match", [(dict(decode=True), "Reach 3 and 4"),

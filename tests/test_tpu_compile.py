@@ -42,15 +42,20 @@ def test_kda_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu
 
     b, s, h, d = 1, 1024, 4, 128
     mxu = jnp.dtype(mxu)
-    x = jax.ShapeDtypeStruct((b, s, h * d), jnp.bfloat16, sharding=one_chip)
-    g = jax.ShapeDtypeStruct((b, s, h * d), jnp.float32, sharding=one_chip)
-    kw = dict(heads=h, mxu=mxu, interpret=False, caller="")
-    fwd = jax.jit(lambda *a: K._forward(*a, **kw)).lower(x, x, x, x, g).compile()
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    x, g = shape((b, s, h * d), jnp.bfloat16), shape((b, s, h * d), jnp.float32)
+    beta = shape((b, s, h), jnp.float32)
+    kw = dict(heads=h, eps=1e-5, mxu=mxu, interpret=False, caller="")
+    fwd = jax.jit(lambda *a: K._forward(*a, **kw)).lower(x, x, x, g, beta).compile()
     assert fwd.as_text().count("tpu_custom_call") >= 1
-    kept = jax.ShapeDtypeStruct((b, h, s // K.block_rows(s), d, d), jnp.float32,
-                                sharding=one_chip)
-    bwd = jax.jit(lambda *a: K._backward(*a, **kw)).lower(x, x, x, x, g, kept, x).compile()
-    assert bwd.as_text().count("tpu_custom_call") >= 1
+    nb = s // K.block_rows(s)
+    kept = shape((b, h, nb, d, d), jnp.float32)
+    lowered = jax.jit(lambda *a: K._backward(*a, **kw)).lower(x, x, x, g, beta, kept, x)
+    # dq dk dv as q k v, dg float32, and dbeta a lane-dense row a head a block
+    assert [(o.shape, o.dtype) for o in lowered.out_info] == [
+        (x.shape, x.dtype)] * 3 + [(g.shape, g.dtype),
+                                   ((b, h, nb, 1, s // nb), jnp.dtype("float32"))]
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 1
 
 
 def test_flash_kernels_compile_with_keys_of_192_and_values_of_128(one_chip, no_compile_cache):
