@@ -76,8 +76,8 @@ def write_tfrecord_shards(
     worker thread per shard up to ``num_workers`` (default
     ``min(num_shards, cpu_count)``; 1 = the serial path). Output bytes
     are identical either way — the parallel writer is a pure throughput
-    change (``bench.py io`` A/Bs it; the native writer's encode/IO path
-    releases the GIL so threads genuinely overlap). A worker exception
+    change (the native writer's encode/IO path releases the GIL so
+    threads genuinely overlap). A worker exception
     cancels the write and re-raises at the caller with the shard's
     partial file removed — matching the ``data/pipeline.py`` prefetch
     relay contract: no silent half-written shard can reach a manifest.

@@ -28,8 +28,7 @@ which, in order:
    verifying the row count round-trips.
 
 Every step lands in the JSON summary printed as the last stdout line,
-with ``"skipped"`` + reason for steps the environment cannot run —
-the same disclosure stance as the bench evidence trail.
+with ``"skipped"`` + reason for steps the environment cannot run.
 """
 
 from __future__ import annotations

@@ -43,13 +43,13 @@ from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES, ambient_mesh
 
 
 # Shared flash-vs-dense dispatch constants (ops/pallas/common.py) —
-# re-exported here for callers that think in model terms (bench.py).
+# re-exported here for callers that think in model terms.
 from pyspark_tf_gke_tpu.ops.pallas.common import FLASH_MIN_SEQ, on_tpu  # noqa: E402
 
 
 def resolve_use_flash(cfg: "BertConfig", seq_len: int) -> bool:
     """The model's flash-vs-dense dispatch, resolved for a sequence
-    length. Single source of truth — bench.py reports this too."""
+    length. Single source of truth: the decoders import it too."""
     if cfg.use_flash is not None:
         return cfg.use_flash
     return on_tpu() and seq_len >= FLASH_MIN_SEQ

@@ -208,7 +208,7 @@ class CausalSelfAttention(nn.Module):
         # through the cache write) and only drop it — re-constraining
         # after the repeat below — when it cannot divide. The extent is
         # derived from LOGICAL_RULES ("heads" → whatever axis the rules
-        # map), not a hardcoded "tp" (round-3 ADVICE).
+        # map), not a hardcoded "tp".
         tp = mesh_extent_for("heads", self.mesh)
         kv_axes = ("batch", "seq", "heads" if hkv % tp == 0 else None,
                    "head_dim")

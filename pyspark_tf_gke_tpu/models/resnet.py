@@ -28,8 +28,7 @@ def space_to_depth(x: jnp.ndarray, block: int = 2) -> jnp.ndarray:
     stem so the first conv contracts over C*block^2 channels instead of
     3 — the stem's MXU contraction dim grows from KH*KW*3 toward the
     128-lane tile the systolic array actually loads, which is the
-    standard TPU ResNet stem optimization (cf. MLPerf ResNet and the
-    roofline analysis in docs/PARITY.md)."""
+    standard TPU ResNet stem optimization (cf. MLPerf ResNet)."""
     b, h, w, c = x.shape
     if h % block or w % block:
         raise ValueError(
@@ -119,10 +118,9 @@ class WSConv(nn.Module):
     unit-variance output at init, with a learnable per-channel ``gain``
     on top. The whole standardization runs in weight space — cost is
     per-parameter, not per-activation, which is the entire point: the
-    8.2 ms/step of activation-norm HBM traffic named by the MFU probe
-    (docs/PARITY.md) has no analog here. Convs stay XLA convs (the
-    Pallas replacements measured slower — PARITY's fused-BN negative
-    result), and XLA hoists nothing: the standardize recomputes each
+    activation-norm HBM traffic between convs has no analog here.
+    Convs stay XLA convs (the Pallas replacements of
+    ``FusedBottleneckBlock`` measured slower), and XLA hoists nothing: the standardize recomputes each
     step in f32 over ~25M weights, noise next to the conv FLOPs.
 
     Carries a learnable per-channel bias (the ScaledStdConv recipe):
@@ -230,7 +228,7 @@ class FusedBottleneckBlock(nn.Module):
 
     The round-4 MFU probe measured normalization at 8.2 ms = 29% of the
     ResNet-50 step — all unfused HBM read-modify-writes of activation
-    tensors between convs (docs/PARITY.md). This block removes the
+    tensors between convs. This block removes the
     removable passes:
 
     - conv1/conv3/proj write their raw output AND its per-channel
@@ -424,11 +422,10 @@ class ResNet(nn.Module):
     # at stride 2, i.e. the 7x7 window padded by one). Same output
     # shape; ~31% more raw stem MACs (192 vs 147 per output element —
     # the stem is <1% of total model FLOPs) traded for a contraction
-    # dim the MXU can actually fill. A disclosed bench variant
-    # (``bench.py resnet50 --s2d``), not a drop-in weight-compatible
-    # swap.
+    # dim the MXU can actually fill. A variant, not a drop-in
+    # weight-compatible swap.
     s2d_stem: bool = False
-    # Normalization lever for the MFU investigation (docs/PARITY.md):
+    # Normalization lever:
     # "bn" (default, bf16 normalize / f32 stats), "bn_f32" (the whole
     # norm in f32 — isolates bf16 round-trips around the stat
     # reductions), "gn" (GroupNorm-32: no batch reduction, fuses as
@@ -437,10 +434,9 @@ class ResNet(nn.Module):
     # the bottleneck 1x1 convs as Pallas kernels absorbing the norm
     # passes — see FusedBottleneckBlock), "nf" (normalizer-free: scaled
     # weight-standardized convs + analytic variance tracking, no
-    # activation norms AT ALL — the lever the fused-kernel negative
-    # result points at: don't fuse the 8.2 ms normalize pass, delete
-    # it; ``bench.py resnet50 --nf``). Measured by tools/mfu_probe.py
-    # on hardware; the training default stays "bn".
+    # activation norms AT ALL: where fusing the normalize pass into
+    # the convs did not pay, this deletes the pass). No cell of the
+    # benchmark runs any of them; the training default stays "bn".
     norm_variant: str = "bn"
 
     @nn.compact

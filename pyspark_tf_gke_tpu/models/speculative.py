@@ -67,10 +67,8 @@ from pyspark_tf_gke_tpu.models.causal_lm import CausalLM, _prefill
 #
 # Both speculative drivers here AND the continuous-batching engine's
 # in-slot speculation (train/continuous.py ``_spec_chunk``) accept a
-# draft proposal through these helpers; the standalone ``spec`` bench
-# workload is a thin caller of the same code, so the acceptance
-# semantics cannot drift between the latency tool and the serving
-# plane.
+# draft proposal through these helpers, so the acceptance semantics
+# cannot drift between the standalone drivers and the serving plane.
 # ---------------------------------------------------------------------------
 
 

@@ -3,8 +3,8 @@
 The reference platform's only observability was the Spark Web UI and
 ``kubectl top`` polling (SURVEY §5); our reproduction grew three
 disjoint stores in response — an ad-hoc step timer,
-``BundleServer.metrics_text``'s ad-hoc counters, and the bench
-evidence trail — that could not be correlated. This package is the
+``BundleServer.metrics_text``'s ad-hoc counters, and a JSONL file
+of benchmark results — that could not be correlated. This package is the
 single metrics plane they all converge on:
 
 * :mod:`~pyspark_tf_gke_tpu.obs.metrics` — thread-safe

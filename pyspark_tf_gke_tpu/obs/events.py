@@ -8,10 +8,8 @@ events share a wall-clock second) and a UTC timestamp.
 
 Append semantics: one ``write()`` of one ``\\n``-terminated line on an
 ``O_APPEND`` descriptor — POSIX keeps concurrent appenders from
-interleaving mid-line, which is the same guarantee the bench evidence
-trail (``tools/bench_history.jsonl``) has always relied on implicitly;
-:func:`append_jsonl_line` is that primitive exposed on its own for
-bench.py and other out-of-process writers.
+interleaving mid-line; :func:`append_jsonl_line` is that primitive
+exposed on its own for out-of-process writers.
 
 Bounded: when the file exceeds ``max_bytes`` it rotates to ``.1``
 (one generation — the trail is operational evidence, not archival

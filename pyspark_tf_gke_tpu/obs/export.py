@@ -167,7 +167,7 @@ def handle_obs_request(
     if route == "/stepz" and stepstats is not None:
         # the step-telemetry ring (obs/stepstats.py): newest-first raw
         # records plus the windowed summary the /loadz fraction and
-        # the cb bench's step_phases block derive from. ?min_ms= is
+        # ``engine.stats``' step_phases block derive from. ?min_ms= is
         # the slow-step filter (pair with a /traces slow_ms capture:
         # a slow request, its slow steps, and an xprof window all
         # cross-link through the step seq + trace ids).

@@ -469,8 +469,7 @@ class StepStatsRing:
     def summary(self) -> dict:
         """Windowed aggregate: record count, host-overhead fraction,
         per-phase p50/p99, wall p50/p99, tokens/sec and MFU — the
-        ``step_phases`` block ``engine.stats`` (and therefore the cb
-        bench trail) carries."""
+        ``step_phases`` block ``engine.stats`` carries."""
         with self._lock:
             recs = list(self._ring)[-self.window:]
             frac = self._host_overhead_frac_locked()

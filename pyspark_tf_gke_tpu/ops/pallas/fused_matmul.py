@@ -1,6 +1,7 @@
 """Fused 1x1-conv (matmul) kernels with BN-stat epilogues for ResNet.
 
-Why this exists (the round-4 MFU investigation, docs/PARITY.md): on the
+Why this exists (an investigation on a v5e in 2026-07, before the
+benchmark; no cell runs it, ROADMAP.md Design 3): on the
 v5e, ResNet-50's normalization costs 8.2 ms/step = 29% of the step while
 the conv-only floor is 38.6% MFU. The probe pinned the cost on *pass
 structure*, not the batch reduction: every BatchNorm between a conv and

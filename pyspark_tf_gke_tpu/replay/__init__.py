@@ -19,8 +19,7 @@ targets become derived numbers, and prediction-vs-replay agreement is
 an assertable contract (``tools/smoke_check.py --replay``).
 
 Everything here is stdlib-only and jax-free: the replay plane must run
-from a bastion host (or the bench parent) without initializing a
-device backend. New scenario = new spec file, not new harness code.
+from a bastion host without initializing a device backend. New scenario = new spec file, not new harness code.
 """
 
 from pyspark_tf_gke_tpu.replay.capacity import (  # noqa: F401

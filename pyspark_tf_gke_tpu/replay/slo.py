@@ -24,8 +24,8 @@ bound). The vocabulary:
 
 :func:`evaluate_slo` returns a machine-readable verdict: ``{"pass":
 bool, "checks": [{"name", "bound", "value", "ok"}, ...]}`` — the
-per-scenario object bench trail entries and ``smoke_check --replay``
-embed.
+per-scenario object ``tools/replay.py run`` and ``smoke_check
+--replay`` embed.
 """
 
 from __future__ import annotations

@@ -27,7 +27,7 @@ SPEC_KIND = "pyspark_tf_gke_tpu.workload_spec"
 SPEC_VERSION = 1
 
 # power-of-2 token-length buckets for the shape histogram (shared by
-# the round-trip test and the bench's per-scenario summary); the last
+# the round-trip test and the per-scenario summary); the last
 # bucket is open-ended
 _SHAPE_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 
@@ -169,7 +169,7 @@ class WorkloadSpec:
     def shape_histogram(self) -> dict:
         """Bucketed shape summary — the round-trip equality oracle
         (traces → spec → replay must preserve it) and the compact
-        per-scenario description bench trail entries carry."""
+        per-scenario description a replay report carries."""
 
         def bucket(n: int) -> int:
             for b in _SHAPE_BUCKETS:

@@ -165,8 +165,7 @@ def _post_json(url: str, body: dict, headers: Optional[dict] = None,
 
 class LocalFleetActuator(Actuator):
     """Real actuation against a :class:`router.localfleet.LocalFleet`
-    and its router's admin plane — the shape every scale test and
-    bench drives.
+    and its router's admin plane — the shape every scale test drives.
 
     Scale-up: boot a fresh replica process, pre-warm it DIRECTLY
     (``/v1/warm`` with the configured hot prefixes — the warm happens

@@ -1,14 +1,15 @@
 """Local replica-fleet harness: the ONE copy of the launch scaffolding
-shared by ``bench.py router``, ``tools/smoke_check.py --router``, and
-the slow kill-one-replica soak in ``tests/test_router.py``.
+shared by ``tools/smoke_check.py --router``, ``tools/replay.py run
+--localfleet`` and the slow kill-one-replica soak in
+``tests/test_router.py``.
 
-All three drive the same contract — N tiny CPU ``BundleServer``
+All of them drive the same contract — N tiny CPU ``BundleServer``
 subprocesses behind the real router CLI — and before this module each
 carried its own bundle-export recipe, port allocator, Popen argv, and
 wait-for-healthy loop; a replica CLI flag change had to be edited three
 times and would silently drift. Everything here is stdlib-only and
 keeps the CALLING process jax-free: the tiny serving bundle is exported
-by a CPU-pinned child process, so a bench/smoke parent never
+by a CPU-pinned child process, so a smoke_check parent never
 initializes a jax backend (a chip belongs to one process at a time; a
 router-plane check must not take it).
 """
@@ -282,8 +283,7 @@ class LocalFleet:
     """Context manager owning one complete local fleet: a tiny bundle
     export, N CPU replica subprocesses and (optionally) the real
     router CLI in front — the setup every fleet-level check repeats
-    (``bench.py replay``, ``smoke_check --replay``, ``tools/replay.py
-    run --localfleet``). Exit kills every process and removes the
+    (``smoke_check --replay``, ``tools/replay.py run --localfleet``). Exit kills every process and removes the
     temp dir; a partially-failed boot cleans up the same way."""
 
     def __init__(self, n_replicas: int = 2, *, router: bool = True,

@@ -754,7 +754,7 @@ class Watchtower:
             alerts = [a.as_dict(now) for a in self._alerts.values()]
             raw_history = list(self._history)[-max(1, int(n)):]
         # age the history records at read time (their wall stamps are
-        # absolute; age_s is a convenience for humans + bench)
+        # absolute; age_s is a convenience for humans)
         aged = []
         for t_mono, rec in raw_history:
             r = dict(rec)

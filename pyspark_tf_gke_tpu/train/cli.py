@@ -72,7 +72,8 @@ def run_csv_training(cfg: Config, fault_injector: Optional[FaultInjector] = None
     if cfg.model not in ("", "mlp"):
         raise ValueError(
             f"CSV mode trains the MLP classifier; got --model {cfg.model}. "
-            "ResNet/BERT workloads have dedicated entry points (see bench.py)."
+            "BERT fine-tunes through train.bert_finetune, a causal LM "
+            "trains through train.lm_pretrain."
         )
 
     local_bs = local_batch_size(cfg.batch_size)
@@ -154,7 +155,8 @@ def run_image_training(cfg: Config, fault_injector: Optional[FaultInjector] = No
     if cfg.model not in ("", "cnn"):
         raise ValueError(
             f"Image mode trains the CNN regressor; got --model {cfg.model}. "
-            "ResNet/BERT workloads have dedicated entry points (see bench.py)."
+            "BERT fine-tunes through train.bert_finetune, a causal LM "
+            "trains through train.lm_pretrain."
         )
     mesh = mesh_from_spec(cfg.mesh_axes(), cfg.dcn_mesh_axes())
     model = build_model("cnn", flat=cfg.flat_layer, dtype=_dtype(cfg.compute_dtype))

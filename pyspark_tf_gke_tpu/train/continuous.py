@@ -132,7 +132,7 @@ class _Request:
     # prefill pieces, first token, token deliveries — so the timeline
     # lands on the trace the HTTP layer opened without the engine ever
     # knowing about transports. Every annotation is guarded on None:
-    # bench/direct callers pay one attribute check per event site.
+    # direct callers pay one attribute check per event site.
     span: Optional[object] = None
 
 
@@ -536,7 +536,7 @@ class DwrrScheduler:
     :meth:`pick` only once it has actually seen two distinct tenants —
     a single-tenant engine never enters this class and keeps the exact
     pre-fairness FIFO/LPT admission order (the FIFO-equivalent fast
-    path the cb bench pins)."""
+    path)."""
 
     def __init__(self, weights: Optional[Dict[str, float]] = None,
                  quantum: int = 256):
@@ -1938,7 +1938,7 @@ class ContinuousEngine:
         self._n_solo_admits = 0    # requests admitted one at a time
         self._n_dispatched_steps = 0  # decode steps dispatched (sum of
         #   chunk sizes) — the exact device-work count, immune to link
-        #   noise; see bench.py cb's device_step accounting
+        #   noise
         from collections import deque
 
         # dispatched-but-unsettled chunks, oldest first (_InflightStep)
@@ -2062,7 +2062,7 @@ class ContinuousEngine:
         # actually submitted (``_fair_active``): a single-tenant engine —
         # including every pre-tenancy caller — admits in the exact
         # FIFO/LPT order it always did, at zero extra cost per step (the
-        # cb bench's FIFO-equivalent fast path).
+        # FIFO-equivalent fast path).
         self._fair = DwrrScheduler(tenant_weights)
         self._first_tenant: Optional[str] = None
         self._fair_active = False
@@ -2101,7 +2101,7 @@ class ContinuousEngine:
             draft_params=draft_params if self._spec else None,
             spec_tokens=self.spec_tokens)
         # shared metrics plane: slot occupancy + useful-token counters
-        # (the cb bench's useful_tokens/sec, now scrapable live). One
+        # (useful tokens per second, scrapable live). One
         # lock op per CHUNK, not per token — hot-path safe. ``obs``
         # threads an injected registry's handles through (BundleServer
         # passes its own); default is the process registry.
@@ -2111,7 +2111,7 @@ class ContinuousEngine:
         # phase-exclusive timing + batch composition — into a bounded
         # ring exposed as GET /stepz. The serving front passes ITS
         # ring so history survives engine rebuilds; direct callers
-        # (bench, tests) get a private default-size one. peak_flops
+        # (tests) get a private default-size one. peak_flops
         # arms the windowed serve_mfu gauge (0 = disabled — the CPU
         # default; FLOPs/token is estimated from the model config).
         self.stepstats = (stepstats if stepstats is not None
@@ -2125,7 +2125,7 @@ class ContinuousEngine:
         self._n_prefill_tokens = 0  # prompt tokens actually COMPUTED
         #   by prefill forwards (pieces, buckets, extensions) — the
         #   prefix cache's whole point is keeping this ∝ unique-suffix
-        #   tokens; bench/smoke read it from stats
+        #   tokens; smoke_check reads it from stats
         self._step_prefill_tokens = 0  # this step's piece tokens (the
         #   budget split's prefill half; reset at each step() top)
         self._obs["serve_prefill_inflight"].set(0)
@@ -3924,8 +3924,8 @@ class ContinuousEngine:
             "prefill_chunks": self._n_prefill_chunks,
             "prefill_tokens_computed": self._n_prefill_tokens,
             # windowed step-phase decomposition (obs/stepstats.py):
-            # host-overhead fraction + per-phase p50/p99 — the cb
-            # bench's trail block and the /loadz fraction read this
+            # host-overhead fraction + per-phase p50/p99 — the
+            # /loadz fraction reads this
             "step_phases": self.stepstats.summary(),
             **({"step_token_budget": self.step_token_budget}
                if self.step_token_budget else {}),

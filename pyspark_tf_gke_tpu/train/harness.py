@@ -91,9 +91,8 @@ def make_optimizer(
         # the TPU-idiomatic memory-efficient choice (t5x's default):
         # factored second moments store O(rows+cols) per matrix instead
         # of Adam's O(rows*cols) — at h768 BERT scale the optimizer
-        # state drops ~2x, which the analytic roofline
-        # (tools/roofline.py) counts directly against the per-step HBM
-        # stream the flagship is bound on.
+        # state drops ~2x, and with it that share of the per-step HBM
+        # stream of parameters and optimizer state.
         tx = optax.adafactor(lr, weight_decay_rate=weight_decay or None,
                              weight_decay_mask=(decay_mask if weight_decay
                                                 else None))

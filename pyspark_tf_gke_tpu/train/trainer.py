@@ -323,9 +323,9 @@ class Trainer:
         logical_rules=LOGICAL_RULES,
         ema_decay: float = 0.0,  # >0 maintains an EMA of params (eval/serving)
         mu_dtype: Optional[Any] = None,  # Adam first-moment dtype; bf16
-        # halves that slice of the per-step param/optimizer HBM traffic
-        # — the flagship (43M params, batch 32) is bound on exactly that
-        # stream (tools/roofline.py analytic model). Default f32 keeps
+        # halves that slice of the per-step param/optimizer HBM traffic,
+        # which weighs most where parameters are many and the batch small
+        # (the flagship CNN: 43M params, batch 32). Default f32 keeps
         # reference-parity optimizer numerics; ignored when tx is given.
         metrics_registry=None,  # obs.MetricsRegistry (default: shared)
         event_log=None,  # obs.EventLog (default: shared trail)

@@ -1,8 +1,8 @@
 """Persistent XLA compile cache with a location the caller can place.
 
 Every entry point that compiles (``train/cli.py``, ``lm_pretrain``,
-``bert_finetune``, ``serve``, ``bench.py``'s worker, the kernel check
-under ``chip_smoke.py``) calls :func:`enable_compile_cache` first thing
+``bert_finetune``, ``serve``, the kernel check under
+``chip_smoke.py``) calls :func:`enable_compile_cache` first thing
 in its ``__main__`` block.
 
 The cache path is part of JAX's cache key, so it must not move between

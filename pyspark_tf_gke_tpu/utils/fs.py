@@ -112,9 +112,13 @@ def fs_copy_tree(url: str, local_dir: str) -> str:
 def spool_local(path: str, spool_dir: Optional[str] = None) -> str:
     """Return a local path for ``path``, staging remote objects into a
     spool file (re-used across calls within the spool dir). The cache
-    key includes the object's version metadata (etag/mtime/size from
+    key includes the object's version metadata (etag or mtime from
     ``fs.info``), so an overwritten remote object re-downloads instead
-    of serving a stale copy. Local paths pass through untouched."""
+    of serving a stale copy. A filesystem that gives neither (``memory://``)
+    gives nothing that tells one object's contents from another's at the
+    same path (a size does not: two runs' shards are equally long, and
+    the default spool outlives a run), so its objects are copied anew on
+    every call. Local paths pass through untouched."""
     if not is_remote(path):
         return path
     import fsspec
@@ -122,14 +126,14 @@ def spool_local(path: str, spool_dir: Optional[str] = None) -> str:
     fs, _, _ = fsspec.get_fs_token_paths(path)
     try:
         info = fs.info(path)
-        version = str(info.get("etag") or info.get("mtime") or info.get("size"))
+        version = info.get("etag") or info.get("mtime")
     except Exception:
-        version = ""
+        version = None
     spool_dir = spool_dir or _default_spool_dir()
     os.makedirs(spool_dir, exist_ok=True)
-    digest = hashlib.sha1(f"{path}\0{version}".encode()).hexdigest()[:16]
+    digest = hashlib.sha1(f"{path}\0{version or ''}".encode()).hexdigest()[:16]
     local = os.path.join(spool_dir, f"{digest}-{os.path.basename(path)}")
-    if not os.path.exists(local):
+    if version is None or not os.path.exists(local):
         tmp = f"{local}.tmp.{os.getpid()}"
         with fsspec.open(path, "rb") as src, open(tmp, "wb") as dst:
             shutil.copyfileobj(src, dst)

@@ -149,14 +149,6 @@ def test_beam_eos_id_validated():
                     max_new_tokens=2, num_beams=2, eos_token_id=999)
 
 
-def test_bench_decode_beams_smoke():
-    from bench import bench_decode
-
-    res = bench_decode(smoke=True, num_beams=2)
-    assert res["num_beams"] == 2
-    assert res["value"] > 0
-
-
 def test_reorder_beams_select_path_matches_gather():
     # The large-leaf K-way select path must be element-exact vs the
     # take_along_axis path — including NaN/inf semantics: a non-finite

@@ -201,8 +201,8 @@ def test_engine_multi_tenant_drains_correctly(lm):
 
 def test_engine_single_tenant_keeps_fifo_fast_path(lm):
     """Default-tenant traffic must never flip fair mode on: admission
-    order (and therefore the bench's measured path) is bit-identical
-    to the pre-tenancy engine."""
+    order (and therefore the path a single-tenant deployment runs) is
+    bit-identical to the pre-tenancy engine."""
     model, params = lm
     eng = ContinuousEngine(model, params, num_slots=1, chunk=2)
     for _ in range(3):

@@ -54,6 +54,10 @@ def test_fs_glob_and_spool(tmp_path):
     assert open(local, "rb").read() == b"\x01" * 10
     # second call reuses the spooled copy (content-addressed)
     assert spool_local(got[1], spool_dir=spool) == local
+    # memory:// gives no etag and no mtime: an object overwritten at equal
+    # size must not be served from the older copy
+    _put(got[1], b"\x07" * 10)
+    assert open(spool_local(got[1], spool_dir=spool), "rb").read() == b"\x07" * 10
     # local paths pass through
     assert spool_local("/tmp/x") == "/tmp/x"
 
@@ -70,21 +74,28 @@ def test_native_tfrecord_reader_remote(tmp_path):
         "label": rng.integers(0, 2, (64,)).astype(np.int64),
     }
     schema = schema_for(arrays)
-    paths = ntr.write_tfrecord_shards(arrays, str(tmp_path / "p"), num_shards=4)
-    for p in paths:
-        _put(f"memory://bucket/tfr/{p.rsplit('/', 1)[1]}", open(p, "rb").read())
 
     def read_all(pattern):
         rows = []
+        # one reader thread: with more, rows of different shards interleave
+        # as the threads are scheduled (``native.ExamplePool``), and a
+        # row-by-row comparison of two reads fails on a busy machine
         for b in ntr.read_tfrecord_batches(
             pattern, schema, 8, shuffle=False, repeat=False,
-            process_index=0, process_count=1,
+            process_index=0, process_count=1, nthreads=1,
         ):
             rows.append(b["input_ids"])
         return np.concatenate(rows)
 
-    local_rows = read_all(str(tmp_path / "p-*.tfrecord"))
-    remote_rows = read_all("memory://bucket/tfr/p-*.tfrecord")
+    # an earlier run's shards under the same names, equally long, read
+    # through the default spool (which outlives a run) before this run's
+    older = {k: v[::-1].copy() for k, v in arrays.items()}
+    for run, data in (("older", older), ("this", arrays)):
+        paths = ntr.write_tfrecord_shards(data, str(tmp_path / run / "p"), num_shards=4)
+        for p in paths:
+            _put(f"memory://bucket/tfr/{p.rsplit('/', 1)[1]}", open(p, "rb").read())
+        remote_rows = read_all("memory://bucket/tfr/p-*.tfrecord")
+    local_rows = read_all(str(tmp_path / "this" / "p-*.tfrecord"))
     np.testing.assert_array_equal(local_rows, remote_rows)
 
 

@@ -6,7 +6,7 @@ structure. These tests map parameters between the two module trees and
 require forward outputs, gradients, and running-statistic updates to
 match in f32 (where the rewrite is exact up to reduction order).
 Kernel-level numerics are covered in test_pallas_ops.py-style interpret
-mode; hardware MFU is the bench variant ``resnet50 --fused-bn``.
+mode; nothing runs this path on hardware (ROADMAP.md, Design 3).
 """
 
 from __future__ import annotations

@@ -129,7 +129,7 @@ def test_hybrid_mesh_executes_collectives(devices):
 
 def test_mesh_extent_for_follows_rules(devices):
     # Divisibility guards derive shard extents from LOGICAL_RULES, not
-    # hardcoded mesh-axis names (round-3 ADVICE): remapping a rule must
+    # hardcoded mesh-axis names: remapping a rule must
     # move every guard with it.
     from pyspark_tf_gke_tpu.parallel.sharding import mesh_extent_for
 

@@ -137,7 +137,7 @@ def test_space_to_depth_layout():
 
 def test_resnet50_s2d_stem_shapes_match_plain():
     # The s2d variant must be output-shape-identical to the plain stem
-    # (the bench A/B compares like against like), differing only in the
+    # (an A/B of the two compares like against like), differing only in the
     # stem parameterization: 4x4x12 kernel instead of 7x7x3.
     plain = ResNet50(num_classes=10, dtype=None)
     s2d = ResNet50(num_classes=10, dtype=None, s2d_stem=True)
@@ -229,46 +229,3 @@ def test_resnet_norm_variants_forward_and_trainer_step():
     with pytest.raises(ValueError):
         ResNet50(num_classes=4, norm_variant="bogus").init(
             jax.random.key(0), jnp.zeros((1, 32, 32, 3)), train=True)
-
-
-@pytest.mark.slow
-def test_mfu_probe_variants_and_summary(monkeypatch, capsys):
-    # The MFU diagnostic's plumbing: every requested norm variant builds
-    # and reports, and the summary line is bn-minus-none. Measurement
-    # itself is monkeypatched (the real protocol is bench.measure,
-    # already covered) so the test costs init-compiles only.
-    import json
-
-    import bench
-    from tools import mfu_probe
-
-    times = {"bn": 0.028, "none": 0.020}
-    order = []
-
-    def fake_measure(trainer, state, batch, steps):
-        variant = order[-1]
-        return state, None, times[variant] * steps
-
-    def fake_step_flops(trainer, state, batch):
-        return 1.0e12
-
-    monkeypatch.setattr(bench, "measure", fake_measure)
-    monkeypatch.setattr(bench, "step_flops", fake_step_flops)
-
-    import pyspark_tf_gke_tpu.models as models
-
-    real_resnet = models.ResNet50
-
-    def tracking_resnet(**kw):
-        order.append(kw["norm_variant"])
-        return real_resnet(**kw)
-
-    monkeypatch.setattr(models, "ResNet50", tracking_resnet)
-    rc = mfu_probe.main(["--batch", "8", "--hw", "32", "--steps", "1",
-                         "--variants", "bn", "none"])
-    assert rc == 0
-    out = capsys.readouterr().out.strip().splitlines()
-    rows = [json.loads(line) for line in out]
-    assert [r.get("variant") for r in rows[:2]] == ["bn", "none"]
-    assert rows[2]["summary"] == "norm budget"
-    assert abs(rows[2]["norm_cost_ms"] - 8.0) < 1e-6

@@ -108,15 +108,6 @@ def test_dequantize_embeddings_handles_frozendict():
         assert isinstance(out["l0"]["kernel"], QTensor)
 
 
-def test_bench_decode_int8_smoke():
-    from bench import bench_decode
-
-    res = bench_decode(smoke=True, int8=True)
-    assert res["int8_weights"] is True
-    assert res["value"] > 0
-    assert res["params_mb"] > 0
-
-
 def test_embedding_tables_quantized_per_row():
     """Embedding tables get one scale per ROW (gathered unit): a single
     outlier row must not coarsen every other token's embedding, which is

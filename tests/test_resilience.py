@@ -231,7 +231,7 @@ def test_cli_chaos_recovery_end_to_end(tmp_path):
     # epochs, so history still records 4 epochs.
     assert len(history["loss"]) == 4
     assert all(np.isfinite(v) for v in history["loss"])
-    # default heartbeat path is per-process (round-3 ADVICE): a hung
+    # default heartbeat path is per-process: a hung
     # process must not hide behind a live peer's shared-file beats
     hb = Heartbeat.read(os.path.join(out, "heartbeat-0.json"))
     assert hb is not None and hb["step"] >= 30
@@ -278,7 +278,7 @@ def test_watchdog_cli_detects_stale_and_clean(tmp_path, capsys):
 def test_detect_stall_never_appearing_file(tmp_path):
     # A worker hung before its FIRST beat writes no file at all — after
     # stall_seconds of watchdog runtime a still-missing path is stalled
-    # (round-3 ADVICE: it previously passed as healthy forever).
+    # (it previously passed as healthy forever).
     from pyspark_tf_gke_tpu.train.resilience import detect_stall
 
     missing = str(tmp_path / "never-appears.json")

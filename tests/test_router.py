@@ -806,8 +806,8 @@ def test_router_kill_one_replica_soak(tmp_path):
     mid-traffic: every non-streamed request must land a terminal
     outcome, with zero losses once the router's failover engages.
     Launch scaffolding is the shared ``router/localfleet.py`` harness
-    (one copy across this soak, ``bench.py router``, and
-    ``smoke_check --router``)."""
+    (one copy across this soak, ``smoke_check --router`` and
+    ``tools/replay.py``)."""
     import signal
 
     from pyspark_tf_gke_tpu.router.localfleet import (

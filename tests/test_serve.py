@@ -115,7 +115,7 @@ def test_http_errors(endpoint):
         _post(endpoint, "/v1/nope", {})
     assert e.value.code == 404
     # JSON null for a numeric field → 400, not 500 (int(None) raises
-    # TypeError; round-3 ADVICE)
+    # TypeError)
     with pytest.raises(urllib.error.HTTPError) as e:
         _post(endpoint, "/v1/generate",
               {"prompts": ["ab"], "max_new_tokens": None})

@@ -115,11 +115,15 @@ def test_accept_and_correct_greedy_and_rejection():
                                    temps=temps, topps=topps, keys=keys)
     assert a2.tolist() == [k] * b
     assert all(0 <= int(c) < v for c in corr2)
-    # a draft the target gives ~zero mass must reject at its position
+    # a draft the target gives ~zero mass must reject at its position.
+    # The argmax's neighbour under normal logits is not such a draft (row
+    # 3 gives it p = 0.0147 at this temperature and draws u = 0.0087, a
+    # rightful accept), so the target's logit for it is pushed down too
     bad = drafts.at[:, 0].set((picks[:, 0] + 1) % v)
     bad_dlog = jnp.full((b, k, v), -20.0).at[
         jnp.arange(b), 0, bad[:, 0]].set(20.0)
-    a3, _ = accept_and_correct(bad, bad_dlog, tgt, temps=temps,
+    bad_tgt = tgt.at[jnp.arange(b), 0, bad[:, 0]].set(-40.0)
+    a3, _ = accept_and_correct(bad, bad_dlog, bad_tgt, temps=temps,
                                topps=topps, keys=keys)
     assert a3.tolist() == [0] * b
 

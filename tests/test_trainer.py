@@ -373,9 +373,8 @@ def test_average_checkpoints_tool(tmp_path, mesh_dp):
 
 def test_adam_mu_dtype_bf16(mesh_dp):
     """mu_dtype=bf16: the Adam first-moment leaves store in bfloat16
-    (halving that slice of the per-step optimizer HBM traffic — the
-    flagship's bound stream per tools/roofline.py), training stays
-    finite, and the default remains f32 for reference parity."""
+    (halving that slice of the per-step optimizer HBM traffic), training
+    stays finite, and the default remains f32 for reference parity."""
     x = np.random.default_rng(0).normal(size=(16, 4)).astype(np.float32)
     batch = {"x": x, "y": np.zeros((16,), np.int32)}
 

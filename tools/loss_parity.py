@@ -87,13 +87,28 @@ def make_health_arrays(n: int, num_classes: int = 6, seed: int = 1337):
     return feats.astype(np.float32), labels
 
 
+def build_reference_cnn(input_shape=(256, 320, 3), flat=True):
+    """The reference's build_cnn_model architecture (train_tf_ps.py:346-378),
+    reconstructed from its published Keras summary (43,368,850 parameters at
+    the default shape)."""
+    import tensorflow as tf
+
+    layers = [tf.keras.layers.Input(shape=input_shape)]
+    for i, feats in enumerate((8, 16, 32, 64, 64)):
+        layers.append(tf.keras.layers.Conv2D(feats, 5, padding="same"))
+        layers.append(tf.keras.layers.PReLU())
+        if i < 4:
+            layers.append(tf.keras.layers.MaxPooling2D())
+    layers.append(tf.keras.layers.Flatten() if flat else tf.keras.layers.GlobalAveragePooling2D())
+    layers.append(tf.keras.layers.Dense(2048 if flat else 128, activation="relu"))
+    layers.append(tf.keras.layers.Dense(2, activation="linear"))
+    return tf.keras.Sequential(layers)
+
+
 def run_tf(images, targets, batch_size: int, epochs: int, lr: float = 1e-3):
     """The reference implementation: Keras Sequential B1, model.fit with
     shuffle=False so the batch order matches the JAX run exactly."""
     import tensorflow as tf
-
-    sys.path.insert(0, _HERE)
-    from measure_reference_baseline import build_reference_cnn
 
     tf.keras.utils.set_random_seed(1337)
     model = build_reference_cnn(input_shape=images.shape[1:], flat=True)

@@ -202,10 +202,10 @@ def lint_duplicate_metrics() -> int:
         print("metric lint FAILED — registration record is empty after "
               "the sweep; the lint is observing nothing")
         return 1
-    # presence guard for families the router/bench planes DEPEND on
+    # presence guard for families the router plane DEPENDS on
     # reading (not just naming-conflict-free): the radix prefix cache's
-    # serve_* names feed /loadz's prefix_hit_rate and the bench's hit
-    # accounting — a refactor that drops one must fail here
+    # serve_* names feed /loadz's prefix_hit_rate — a refactor that
+    # drops one must fail here
     required = {"serve_prefix_cache_hits_total",
                 "serve_prefix_cache_hit_tokens_total",
                 "serve_prefix_cache_pages",
@@ -263,14 +263,14 @@ def lint_duplicate_metrics() -> int:
                 "chaos_actions_total",
                 "serve_step_watchdog_reaps_total",
                 # self-draft speculative decoding: /loadz
-                # spec_accept_rate, the cb --spec bench and the
+                # spec_accept_rate and the
                 # capacity model's (1 + k·accept) what-if knob read
                 # these — a rename must fail here first
                 "serve_spec_proposed_total",
                 "serve_spec_accepted_total",
                 "serve_spec_accept_rate",
                 # engine step telemetry (obs/stepstats.py): the
-                # host/device decomposition — /stepz, the cb bench's
+                # host/device decomposition — /stepz, engine.stats'
                 # step_phases block, /loadz step_host_overhead_frac
                 # and the router's autoscale fold all derive from
                 # these families. serve_device_idle_fraction is the
@@ -283,7 +283,7 @@ def lint_duplicate_metrics() -> int:
                 "serve_device_idle_fraction",
                 "serve_mfu",
                 # mid-stream failover: the smoke gate
-                # (--failover-stream), the chaos streaming-mix bench
+                # (--failover-stream)
                 # and docs/OBSERVABILITY.md's resume vocabulary read
                 # these — a rename must fail here first
                 "router_stream_resumes_total",
@@ -293,8 +293,7 @@ def lint_duplicate_metrics() -> int:
                 "router_idempotent_replays_total",
                 # fleet watchtower (router/watchtower.py): the live
                 # SLO burn-rate/alerting plane and the /fleetz
-                # snapshot ring — the --watchtower gate, bench.py
-                # chaos alert timelines and the ROADMAP item-5
+                # snapshot ring — the --watchtower gate and the
                 # autopilot contract read these names
                 "router_slo_burn_rate",
                 "router_alerts_firing",
@@ -303,8 +302,8 @@ def lint_duplicate_metrics() -> int:
                 "router_fleet_snapshot_buckets",
                 # autopilot (router/autopilot.py): the closed-loop
                 # fleet controller's decision/veto/actuation
-                # accounting — the --autopilot gate, bench.py
-                # autopilot A/B and docs/AUTOPILOT.md read these
+                # accounting — the --autopilot gate and
+                # docs/AUTOPILOT.md read these
                 "autopilot_ticks_total",
                 "autopilot_decisions_total",
                 "autopilot_vetoes_total",
@@ -985,10 +984,8 @@ def router_check(grace_s: float = 30.0, n_requests: int = 10) -> int:
        kill (two idle replicas can carry this load),
     3. SIGTERM drains the router and it exits 0.
 
-    The in-process fast variants live in tests/test_router.py; the
-    bench A/B (throughput + p99 + failover goodput) is
-    ``bench.py router``. Launch scaffolding is shared with both via
-    ``router/localfleet.py``."""
+    The in-process fast variants live in tests/test_router.py. Launch
+    scaffolding is shared with them via ``router/localfleet.py``."""
     import signal
     import subprocess
     import tempfile
