@@ -11,8 +11,10 @@ blocks, no biases, no embedding scale, untied head.
   and a step size ``beta = sigmoid(x W_b)``, both float32; the chunked
   recurrence of ``ops/linear_attention.py::kda`` (Pallas kernels ``kda_fwd`` /
   ``kda_bwd`` on the TPU); ``RMSNorm(o) * sigmoid(x W_ga W_gb)`` and the
-  output projection. Everything here stays ``[B, S, heads * 128]``: what is
-  per head (the L2 norms, ``beta``'s products, the output's RMS) is ``kda``'s.
+  output projection. Everything here stays ``[B, S, heads * 128]``, and the
+  projections' outputs go to ``kda`` as they are, with the taps: what is per
+  head and per row (the convolution and its SiLU, the L2 norms, ``beta``'s
+  products, the output's RMS) is ``kda``'s.
 * **MLA without positions** (``MLAAttention``): queries of ``qk_nope + qk_rope``
   = 192 a head, a 512-wide normalised latent expanded to 128 of key and 128
   of value a head, 64 more key columns shared by all heads and not rotated;
@@ -145,18 +147,17 @@ def _per_shard(fn, mesh: Optional[Mesh], *specs):
 
 
 class CausalConv(nn.Module):
-    """Depthwise causal convolution over time, float32: ``x [B, S, C]``,
-    ``kernel [size, C]``, the last tap on the current token; no bias."""
+    """The taps ``kernel [size, features]`` (float32, no bias) of a depthwise
+    causal convolution over time, the last on the current token. ``kda``
+    convolves with them, on the rows its kernels hold."""
 
     size: int
+    features: int
 
     @nn.compact
-    def __call__(self, x):
-        taps = self.param("kernel", nn.initializers.normal(stddev=0.02),
-                          (self.size, x.shape[-1]), jnp.float32)
-        s = x.shape[1]
-        xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (self.size - 1, 0), (0, 0)))
-        return sum(xp[:, j:j + s] * taps[j] for j in range(self.size))
+    def __call__(self):
+        return self.param("kernel", nn.initializers.normal(stddev=0.02),
+                          (self.size, self.features), jnp.float32)
 
 
 class _Scale(nn.Module):
@@ -186,12 +187,10 @@ class KDAAttention(nn.Module):
         def dense(features, name):
             return _dense(features, ("embed", "mlp"), cfg, name=name, use_bias=False)
 
-        def mixed(name):
-            y = CausalConv(cfg.conv_size, name=f"{name}_conv")(
-                dense(wide, f"{name}_proj")(hidden))
-            return jax.nn.silu(y).astype(cfg.dtype)
-
-        q, k, v = mixed("q"), mixed("k"), mixed("v")
+        # q, k, v as their projections write them: the short convolution and the
+        # SiLU after it are ``kda``'s, with these taps
+        q, k, v = (dense(wide, f"{name}_proj")(hidden) for name in "qkv")
+        taps = [CausalConv(cfg.conv_size, wide, name=f"{name}_conv")() for name in "qkv"]
         # the decay, beta and the output gate in float32
         a_log = self.param("A_log", nn.initializers.zeros_init(), (heads,), jnp.float32)
         dt_bias = self.param("dt_bias", nn.initializers.zeros_init(), (wide,), jnp.float32)
@@ -199,10 +198,12 @@ class KDAAttention(nn.Module):
         g = jnp.repeat(-jnp.exp(a_log), dim) * jax.nn.softplus(f + dt_bias)
         beta = jax.nn.sigmoid(dense(heads, "b_proj")(hidden).astype(jnp.float32))
 
-        def attend(q, k, v, g, beta):        # under a mesh: a shard's heads
-            return kda(q, k, v, g, beta, heads=beta.shape[-1], eps=cfg.layer_norm_eps)
+        def attend(q, k, v, g, beta, *taps):  # under a mesh: a shard's heads
+            return kda(q, k, v, g, beta, heads=beta.shape[-1], eps=cfg.layer_norm_eps,
+                       conv=taps)
 
-        o = _per_shard(attend, self.mesh, *[P(DATA_AXES, None, "tp")] * 5)(q, k, v, g, beta)
+        o = _per_shard(attend, self.mesh, *[P(DATA_AXES, None, "tp")] * 5,
+                       *[P(None, "tp")] * 3)(q, k, v, g, beta, *taps)
         gate = dense(wide, "g_b")(dense(cfg.gate_rank, "g_a")(hidden))
         scale = jnp.tile(_Scale(dim, name="o_norm")(), heads)
         o = o.astype(jnp.float32) * scale * jax.nn.sigmoid(gate.astype(jnp.float32))

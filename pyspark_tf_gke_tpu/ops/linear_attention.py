@@ -27,7 +27,11 @@ strictly lower-triangular ``A`` is the product ``(I - A)(I + A^2)(I + A^4)...``,
 which ends because ``A^64 = 0``; it is computed in float32.
 
 The per-head element-wise work around the recurrence is part of the chunk
-(:func:`block_step`), done in float32 on the rows a step holds: the L2 norm of
+(:func:`block_step`), done in float32 on the rows a step holds: the short
+convolution over time of ``q``, ``k`` and ``v`` with the SiLU after it where
+the caller gives its taps (:func:`_mixed`: a token's row and the ``taps - 1``
+before it, which for a block's first rows are the last of the block before,
+its halo; the result is not rounded before the norm), the L2 norm of
 ``q`` and ``k`` (``q`` also scaled by ``Dk^-1/2``), ``beta k`` and ``beta v``
 (not rounded before use), the log-decay summed from each chunk's first row
 (:func:`_running_sum` of each sub-block, a ``[SUB, SUB]`` triangle of ones
@@ -39,8 +43,10 @@ caller hands over what its projections write, ``[B, S, H*D]`` with a head a
 :func:`kda` is one ``jax.custom_vjp``: the forward keeps the state at the start
 of every block of ``BLOCK_CHUNKS`` chunks (float32) and the backward walks the
 blocks from the last to the first, recomputes each block from its kept state
-and pulls the cotangents back through it; the residuals are the five operands
-and those states. On the TPU both walks are Pallas kernels
+and pulls the cotangents back through it; the residuals are the operands
+and those states. Walking back, the cotangent of a block's halo is added to
+the last rows of the block walked next, and the taps' gradients sum up over
+the blocks. On the TPU both walks are Pallas kernels
 (``ops/pallas/kda.py``: the state rides in VMEM scratch across a sequential
 grid axis); elsewhere the same algebra (:func:`block_step`) runs under
 ``lax.scan``. A per-token scan is the reference's
@@ -164,17 +170,49 @@ def _l2_norm(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
 
 
-def block_step(subs, state, head, *, mxu, eps):
+@jax.custom_vjp
+def _sub_blocks(x):
+    """``x [n SUB, D]`` as its ``SUB``-row sub-blocks; backward they are laid
+    end to end again (a slice's own transpose would pad each to the whole)."""
+    return tuple(x[i:i + SUB] for i in range(0, x.shape[0], SUB))
+
+
+_sub_blocks.defvjp(lambda x: (_sub_blocks(x), None),
+                   lambda _, ct: (jnp.concatenate(ct, axis=0),))
+
+
+def _mixed(x, taps, halo):
+    """``silu(conv(x))`` of one operand's sub-blocks ``x`` (``[SUB, D]`` each, in
+    order), as sub-blocks: the depthwise causal convolution over time with
+    ``taps`` (a row of weights ``[1, D]`` or ``[D]`` a tap, the last the current
+    token's) on the block's rows laid end to end behind ``halo [SUB, D]``, the
+    rows before the block, so that a token's row ``j`` back is a slice ``j``
+    rows up. float32, not rounded before the L2 norm."""
+    f32 = jnp.float32
+    rows = jnp.concatenate([halo.astype(f32)] + [sub.astype(f32) for sub in x], axis=0)
+    n = rows.shape[0] - SUB
+    y = sum(taps[-1 - j] * rows[SUB - j:SUB - j + n] for j in range(len(taps)))
+    return _sub_blocks(jax.nn.silu(y))
+
+
+def block_step(subs, state, head, *, mxu, eps, conv=None):
     """A block of chunks, one after another, of head ``head``.
     ``subs = (q, k, v, g, beta)``, each the tuple of the block's ``SUB``-row
     sub-blocks in order, as :func:`kda` takes them: ``q, k [SUB, Dk]`` and
     ``v [SUB, Dv]`` not normalised, ``g [SUB, Dk]`` the log-decay a token,
     ``beta [SUB, H]`` with all the heads' step sizes (column ``head`` is
     picked here, so that its gradient comes out of the same ``vjp``).
+    ``conv = (taps, halos)``, one of each for ``q``, ``k`` and ``v`` as
+    :func:`_mixed` takes them, says that the three are a projection's output
+    still to be convolved over time and passed through SiLU, which is then
+    done here on the block's rows (``halos`` being the ``SUB`` rows before
+    them, zeros at a row's first block).
     Returns ``(tuple of o [CHUNK, Dv] per chunk, each row over its RMS,
     state after the block)``. All of it float32 but the matmul operands."""
     f32 = jnp.float32
     q, k, v, g, beta = subs
+    if conv is not None:
+        q, k, v = (_mixed(x, *each) for x, each in zip((q, k, v), zip(*conv)))
     dk = q[0].shape[-1]
     mine = jax.lax.broadcasted_iota(jnp.int32, beta[0].shape, 1) == head
     per = CHUNK // SUB
@@ -213,19 +251,20 @@ def _split(x):
     return tuple(x[i:i + SUB] for i in range(0, x.shape[0], SUB))
 
 
-def _block_arrays(q, k, v, g, beta, state, head, mxu, eps):
+def _block_arrays(q, k, v, g, beta, state, head, taps, halos, mxu, eps):
     outs, state = block_step(tuple(_split(x) for x in (q, k, v, g, beta)), state, head,
-                             mxu=mxu, eps=eps)
+                             mxu=mxu, eps=eps, conv=taps and (taps, halos))
     return jnp.concatenate(outs, axis=0), state
 
 
 def _scan_step(mxu, eps):
     """:func:`_block_arrays` over rows and heads: ``q, k, v, g [B, H, rows, D]``,
     ``beta [B, rows, H]`` (every head reads the whole block), ``state
-    [B, H, Dv, Dk]``, ``head [H]``."""
+    [B, H, Dv, Dk]``, ``head [H]``, and for ``q``, ``k`` and ``v`` each ``taps
+    [H, taps, D]`` and ``halos [B, H, SUB, D]``, or ``None`` twice."""
     step = functools.partial(_block_arrays, mxu=mxu, eps=eps)
-    return jax.vmap(jax.vmap(step, in_axes=(0, 0, 0, 0, None, 0, 0)),
-                    in_axes=(0, 0, 0, 0, 0, 0, None))
+    return jax.vmap(jax.vmap(step, in_axes=(0, 0, 0, 0, None, 0, 0, 0, 0)),
+                    in_axes=(0, 0, 0, 0, 0, 0, None, None, 0))
 
 
 def _to_blocks(x, heads, rows):
@@ -239,61 +278,85 @@ def _from_blocks(x):
     return x.transpose(1, 0, 3, 2, 4).reshape(b, nb * rows, h * d)
 
 
-def _scan_operands(q, k, v, g, beta, heads, rows):
+def _scan_operands(q, k, v, g, beta, conv, heads, rows):
+    """``(the five a block, the halos a block, the taps a head)``, the last two
+    ``None`` without ``conv``. A block's halo is the last ``SUB`` rows of the
+    block before it, zeros at the first; what is convolved is float32 from
+    here on, so that a halo's cotangent meets its rows' before either is
+    rounded."""
     b, s, _ = q.shape
-    return tuple(_to_blocks(x, heads, rows) for x in (q, k, v, g)) + (
+    if conv is not None:
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    five = tuple(_to_blocks(x, heads, rows) for x in (q, k, v, g)) + (
         beta.reshape(b, s // rows, rows, heads).transpose(1, 0, 2, 3),)  # [NB, B, rows, H]
+    if conv is None:
+        return five, None, None
+    halos = tuple(jnp.concatenate([jnp.zeros_like(x[:1, ..., -SUB:, :]),
+                                   x[:-1, ..., -SUB:, :]]) for x in five[:3])
+    taps = tuple(w.reshape(w.shape[0], heads, -1).transpose(1, 0, 2) for w in conv)
+    return five, halos, taps
 
 
-def _fwd_scan(q, k, v, g, beta, heads, eps, mxu):
+def _fwd_scan(q, k, v, g, beta, conv, heads, eps, mxu):
     b, s, hd = q.shape
     rows, d, dv = block_rows(s), hd // heads, v.shape[-1] // heads
     step, head = _scan_step(mxu, eps), jnp.arange(heads)
+    five, halos, taps = _scan_operands(q, k, v, g, beta, conv, heads, rows)
 
     def body(state, xs):
-        o, new = step(*xs, state, head)
+        *ins, halo = xs
+        o, new = step(*ins, state, head, taps, halo)
         return new, (o, state)
 
     _, (o, states) = jax.lax.scan(body, jnp.zeros((b, heads, dv, d), jnp.float32),
-                                  _scan_operands(q, k, v, g, beta, heads, rows))
+                                  five + (halos,))
     return _from_blocks(o).astype(v.dtype), states.transpose(1, 2, 0, 3, 4)
 
 
-def _bwd_scan(q, k, v, g, beta, states, do, heads, eps, mxu):
+def _bwd_scan(q, k, v, g, beta, conv, states, do, heads, eps, mxu):
     b, s, _ = q.shape
     rows = block_rows(s)
     step, head = _scan_step(mxu, eps), jnp.arange(heads)
+    five, halos, taps = _scan_operands(q, k, v, g, beta, conv, heads, rows)
 
-    def body(dstate, xs):
-        *ins, state, g_o = xs
-        _, pull = jax.vjp(lambda *a: step(*a, head), *ins, state)
-        *d_ins, d_prev = pull((g_o.astype(jnp.float32), dstate))
-        return d_prev, tuple(d_ins)
+    def body(carry, xs):
+        # beside the state's cotangent, the taps' summed over the blocks walked
+        # and the halo's of the block walked last: that of this block's last rows
+        dstate, dtaps, late = carry
+        *ins, halo, state, g_o = xs
+        _, pull = jax.vjp(lambda ins, state, taps, halo: step(*ins, state, head, taps, halo),
+                          ins, state, taps, halo)
+        d_ins, d_prev, d_taps, d_halo = pull((g_o.astype(jnp.float32), dstate))
+        if conv is not None:
+            d_ins = [d.at[..., -SUB:, :].add(ct) for d, ct in zip(d_ins, late)] + d_ins[3:]
+        return (d_prev, jax.tree.map(jnp.add, dtaps, d_taps), d_halo), tuple(d_ins)
 
-    xs = _scan_operands(q, k, v, g, beta, heads, rows) + (
-        states.transpose(2, 0, 1, 3, 4), _to_blocks(do, heads, rows))
-    _, (*grads, dbeta) = jax.lax.scan(body, jnp.zeros_like(states[:, :, 0]), xs,
-                                      reverse=True)
+    xs = five + (halos, states.transpose(2, 0, 1, 3, 4), _to_blocks(do, heads, rows))
+    zeros = functools.partial(jax.tree.map, jnp.zeros_like)       # ``None`` stays ``None``
+    (_, dtaps, _), (*grads, dbeta) = jax.lax.scan(
+        body, (jnp.zeros_like(states[:, :, 0]), zeros(taps),
+               zeros(jax.tree.map(lambda h: h[0], halos))), xs, reverse=True)
     return tuple(_from_blocks(d).astype(x.dtype) for d, x in zip(grads, (q, k, v, g))) + (
-        dbeta.transpose(1, 0, 2, 3).reshape(b, s, heads),)
+        dbeta.transpose(1, 0, 2, 3).reshape(b, s, heads),
+        conv and tuple(d.transpose(1, 0, 2).reshape(w.shape) for d, w in zip(dtaps, conv)))
 
 
 # -- one custom_vjp over either walk -----------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _kda_core(q, k, v, g, beta, heads, eps, mxu, pallas, interpret):
-    return _core_fwd(q, k, v, g, beta, heads, eps, mxu, pallas, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _kda_core(q, k, v, g, beta, conv, heads, eps, mxu, pallas, interpret):
+    return _core_fwd(q, k, v, g, beta, conv, heads, eps, mxu, pallas, interpret)[0]
 
 
-def _core_fwd(q, k, v, g, beta, heads, eps, mxu, pallas, interpret):
+def _core_fwd(q, k, v, g, beta, conv, heads, eps, mxu, pallas, interpret):
     if pallas:
         from pyspark_tf_gke_tpu.ops.pallas import kda as kernels
 
-        o, states = kernels.forward(q, k, v, g, beta, heads=heads, eps=eps, mxu=mxu,
+        o, states = kernels.forward(q, k, v, g, beta, conv, heads=heads, eps=eps, mxu=mxu,
                                     interpret=interpret)
     else:
-        o, states = _fwd_scan(q, k, v, g, beta, heads, eps, mxu)
-    return o, (q, k, v, g, beta, states)
+        o, states = _fwd_scan(q, k, v, g, beta, conv, heads, eps, mxu)
+    return o, (q, k, v, g, beta, conv, states)
 
 
 def _core_bwd(heads, eps, mxu, pallas, interpret, residuals, do):
@@ -313,10 +376,15 @@ def kda(q: jnp.ndarray,                # [B, S, H*Dk] not normalised
         v: jnp.ndarray,                # [B, S, H*Dv]
         g: jnp.ndarray,                # [B, S, H*Dk] log-decay a token, <= 0
         beta: jnp.ndarray,             # [B, S, H] in (0, 1)
-        *, heads: int, eps: float, pallas: Optional[bool] = None,
+        *, heads: int, eps: float, conv=None, pallas: Optional[bool] = None,
         interpret: bool = False) -> jnp.ndarray:
     """Chunked KDA, forward and backward (module docstring), of operands as the
     projections write them: head ``h`` is columns ``[h D, (h + 1) D)``.
+    With ``conv = (wq, wk, wv)``, each ``[taps, H*D]`` float32, ``q``, ``k`` and
+    ``v`` are the projections' own outputs and each is first convolved over
+    time with its taps (depthwise and causal, the last tap on the current
+    token, zeros before a row's first) and passed through SiLU, in float32;
+    without it they come already mixed.
     Per head ``q`` and ``k`` are L2-normalised (``q`` also times ``Dk^-1/2``)
     and the recurrence's output is divided by the RMS of its ``Dv`` columns
     (``eps`` under the root); the caller's norm scale and gate come after.
@@ -324,16 +392,23 @@ def kda(q: jnp.ndarray,                # [B, S, H*Dk] not normalised
     every row. ``pallas=None`` takes the kernels on the TPU and ``lax.scan``
     elsewhere; ``interpret`` runs the kernels in the Pallas interpreter
     (tests). The chunk's matmuls take their operands in ``q``'s dtype; the
-    norms' statistics, ``beta``, decays, their sums and the state are
-    float32."""
+    convolution, the norms' statistics, ``beta``, decays, their sums and the
+    state are float32."""
     b, s, wide = q.shape
     if wide % heads or v.shape[-1] % heads or beta.shape[-1] != heads:
         raise ValueError(
             f"kda: {heads} heads do not divide q's {wide} and v's {v.shape[-1]} "
             f"columns, or are not beta's {beta.shape[-1]}")
     block_rows(s)                                           # refuses a ragged sequence
+    if conv is not None:
+        conv = tuple(w.astype(jnp.float32) for w in conv)
+        if any(w.shape != (conv[0].shape[0], x.shape[-1]) for w, x in zip(conv, (q, k, v))) or (
+                conv[0].shape[0] > SUB):
+            raise ValueError(
+                f"kda: taps {[w.shape for w in conv]} are not [taps, columns] of q, k and v "
+                f"with at most {SUB} taps (a halo is one sub-block)")
     if pallas is None:
         pallas = on_tpu() or interpret
-    return _kda_core(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32),
+    return _kda_core(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32), conv,
                      int(heads), float(eps), jnp.dtype(q.dtype), bool(pallas),
                      bool(interpret))

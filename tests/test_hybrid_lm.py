@@ -115,20 +115,30 @@ def _equations(jaxpr, skipped):
 def test_kda_attention_never_leaves_the_flat_layout(tiny):
     """Outside ``kda``'s own ``custom_vjp`` call (whose ``lax.scan`` walk off
     the TPU blocks its operands as it likes) the layer holds no ``[B, S, H,
-    D]`` view and sums no decay: on the chip each such view of a float32
-    ``[2, 8192, 4096]`` is a re-tiling of 268 MB."""
-    cfg = config_from_file(tiny, dtype=jnp.float32)
+    D]`` view, sums no decay and convolves nothing: on the chip each such
+    view or float32 pass of ``[2, 8192, 4096]`` is a round trip of 268 MB.
+    q, k and v reach the call as their projections' matmuls write them, in
+    ``cfg.dtype``, with the taps beside them."""
+    cfg = config_from_file(tiny, dtype=jnp.bfloat16)
     layer = KDAAttention(cfg)
-    hidden = jnp.zeros((1, 128, cfg.hidden_size), jnp.float32)
+    hidden = jnp.zeros((1, 128, cfg.hidden_size), jnp.bfloat16)
     params = layer.init(jax.random.PRNGKey(0), hidden)
     skipped = []
     eqns = list(_equations(jax.make_jaxpr(layer.apply)(params, hidden).jaxpr, skipped))
     assert len(skipped) == 1 and len(eqns) > 20
-    # five arrays in, the sequence on axis 1, nothing of rank 4
-    assert [v.aval.shape for v in skipped[0].invars[-5:]] == [(1, 128, 256)] * 4 + [(1, 128, 2)]
+    # five arrays in, the sequence on axis 1, nothing of rank 4; then the taps
+    operands = skipped[0].invars[-8:]
+    assert [(v.aval.shape, v.aval.dtype) for v in operands] == [
+        ((1, 128, 256), jnp.bfloat16)] * 3 + [((1, 128, 256), jnp.float32),
+                                              ((1, 128, 2), jnp.float32)] + [
+        ((tiny["linear_attn_config"]["short_conv_kernel_size"], 256), jnp.float32)] * 3
+    made_by = {v: eqn for eqn in eqns for v in eqn.outvars}
+    for v in operands[:3]:            # nothing between a projection's matmul and the call
+        assert made_by[v].primitive.name == "dot_general", made_by[v]
     for eqn in eqns:
         name = eqn.primitive.name
-        assert name not in ("cumsum", "cumlogsumexp", "cummax", "cumprod"), eqn
+        assert name not in ("pad", "conv_general_dilated", "cumsum", "cumlogsumexp", "cummax",
+                            "cumprod"), eqn
         assert not name.startswith("reduce_window"), eqn
         if name in ("reshape", "broadcast_in_dim", "transpose"):
             assert all(len(v.aval.shape) < 4 for v in eqn.outvars), eqn
@@ -137,7 +147,8 @@ def test_kda_attention_never_leaves_the_flat_layout(tiny):
 def test_kda_attention_per_shard_over_rows_and_heads(tiny):
     """Under a ``dp=2, tp=2`` mesh of the CPU's devices each shard runs ``kda``
     on its rows and its heads' columns of all five flat operands (``beta``'s
-    last axis is its heads): outputs and gradients are the one-device layer's."""
+    last axis is its heads) and of the three taps, whose gradients are summed
+    over the shards of rows: outputs and gradients are the one-device layer's."""
     from pyspark_tf_gke_tpu.parallel.mesh import make_mesh
 
     cfg = config_from_file(tiny, dtype=jnp.float32)

@@ -2,7 +2,9 @@
 ``ops/pallas/kda.py`` in interpret mode, and the ``lax.scan`` form) against the
 benchmark's plain reference on operands that are not normalised: its L2 norm,
 per-token recurrence and per-head RMS, outputs and all five gradients, at
-decays slow enough that the state carries across every chunk."""
+decays slow enough that the state carries across every chunk; and with the
+taps of the short convolution, against its ``silu(causal_conv(x))`` before
+that."""
 
 import os
 import sys
@@ -15,7 +17,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
-from reference.kimi_linear import kda_recurrence, l2_norm  # noqa: E402
+from reference.kimi_linear import causal_conv, kda_recurrence, l2_norm  # noqa: E402
 
 from pyspark_tf_gke_tpu.ops import linear_attention as LA  # noqa: E402
 
@@ -108,6 +110,85 @@ def test_a_call_cut_into_chunks_is_the_state_zeroed_at_each(impl):
         [LA.kda(*(x[:, i:i + LA.CHUNK] for x in args), **kw)
          for i in range(0, 192, LA.CHUNK)], axis=1)
     close(pieces, reference(*args, zero_state_every=LA.CHUNK), 1e-5)
+
+
+def taps_for(args, seed=21, taps=4):
+    """Three ``[taps, H*D]``, large enough that every tap weighs."""
+    return tuple(0.5 * jax.random.normal(key, (taps, x.shape[-1]))
+                 for key, x in zip(jax.random.split(jax.random.PRNGKey(seed), 3), args))
+
+
+def mixed(x, w):
+    return jax.nn.silu(causal_conv(x, w))
+
+
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+@pytest.mark.parametrize("seq", [256, 320], ids=["1block_no_halo", "5blocks_4_borders"])
+def test_with_taps_it_is_the_convolution_and_silu_before_it(impl, seq):
+    """``conv=`` against ``silu(causal_conv(x))`` handed to today's call and to
+    the reference, outputs and all eight gradients, the three taps' among
+    them; two rows, so that a row's halo and its taps' sums are its own."""
+    args = inputs(17, b=2, s=seq)
+    conv = taps_for(args)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    fused = lambda five, conv: kda(*five, conv=conv, **IMPLS[impl])
+    composed = lambda five, conv: kda(*(mixed(x, t) for x, t in zip(five, conv)), *five[3:],
+                                      **IMPLS[impl])
+    plain = lambda five, conv: reference(*(mixed(x, t) for x, t in zip(five, conv)), *five[3:])
+    got = fused(args, conv)
+    close(got, plain(args, conv), 1e-5)
+    close(got, composed(args, conv), 1e-5)
+    grads = lambda fn: jax.tree.leaves(
+        jax.grad(lambda five, conv: jnp.sum(fn(five, conv) * w), argnums=(0, 1))(args, conv))
+    got, want, same = grads(fused), grads(plain), grads(composed)
+    assert len(got) == 8
+    for g, r, c in zip(got, want, same):                  # dq dk dv dg dbeta dwq dwk dwv
+        assert g.shape == r.shape
+        close(g, r, 5e-5)
+        close(g, c, 5e-5)
+
+
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+def test_taps_and_bf16_operands(impl):
+    """bf16 projections' outputs with float32 taps, as the program calls it;
+    gradients come back in the operands' dtypes."""
+    args = inputs(19, b=2, s=128)
+    conv = taps_for(args)
+    want = reference(*(mixed(x, t) for x, t in zip(args, conv)), *args[3:])
+    q, k, v = (x.astype(jnp.bfloat16) for x in args[:3])
+    fn = lambda q, k, v, conv: kda(q, k, v, *args[3:], conv=conv, **IMPLS[impl])
+    got = fn(q, k, v, conv)
+    assert got.dtype == jnp.bfloat16
+    close(got.astype(jnp.float32), want, 3e-2)
+    grads = jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)), argnums=(0, 3))(
+        q, k, v, conv)
+    assert grads[0].dtype == jnp.bfloat16
+    assert [g.dtype for g in grads[1]] == [jnp.float32] * 3
+
+
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+def test_a_halo_dropped_between_blocks_is_seen(impl):
+    """What a kernel that lost the three rows before a block would give (every
+    64-row block convolved from zeros, the state carried on as it should be)
+    differs from the reference by far more than the tolerance, from the
+    border's first row on."""
+    args = inputs(23, s=320)
+    conv = taps_for(args)
+    want = reference(*(mixed(x, t) for x, t in zip(args, conv)), *args[3:])
+    rows = LA.block_rows(320)
+    lost = [jnp.concatenate([mixed(x[:, i:i + rows], t) for i in range(0, 320, rows)], axis=1)
+            for x, t in zip(args, conv)]
+    dropped = kda(*lost, *args[3:], **IMPLS[impl])
+    close(dropped[:, :rows], want[:, :rows], 1e-5)        # the first block is right
+    assert float(jnp.max(jnp.abs(dropped[:, rows] - want[:, rows]))) > 1e-2 * float(
+        jnp.max(jnp.abs(want)))
+
+
+def test_taps_that_do_not_fit_the_operands_are_refused():
+    args = inputs(1, s=64)
+    for conv in (taps_for(args)[:2] + (jnp.zeros((4, 128)),), taps_for(args, taps=LA.SUB + 1)):
+        with pytest.raises(ValueError, match="taps"):
+            kda(*args, conv=conv, pallas=False)
 
 
 def test_decays_past_float32_range_of_a_factored_chunk():

@@ -36,8 +36,9 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+@pytest.mark.parametrize("taps", [4, 0], ids=["projections_and_taps", "already_mixed"])
 @pytest.mark.parametrize("mxu", ["bfloat16", "float32"])
-def test_kda_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu):
+def test_kda_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu, taps):
     from pyspark_tf_gke_tpu.ops.pallas import kda as K
 
     b, s, h, d = 1, 1024, 4, 128
@@ -45,16 +46,22 @@ def test_kda_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
     x, g = shape((b, s, h * d), jnp.bfloat16), shape((b, s, h * d), jnp.float32)
     beta = shape((b, s, h), jnp.float32)
+    # the short convolution's taps, or none: q, k, v come convolved
+    conv = (shape((taps, h * d), jnp.float32),) * 3 if taps else None
     kw = dict(heads=h, eps=1e-5, mxu=mxu, interpret=False, caller="")
-    fwd = jax.jit(lambda *a: K._forward(*a, **kw)).lower(x, x, x, g, beta).compile()
-    assert fwd.as_text().count("tpu_custom_call") >= 1
+    lowered = jax.jit(lambda *a: K._forward(*a, **kw)).lower(x, x, x, g, beta, conv)
     nb = s // K.block_rows(s)
     kept = shape((b, h, nb, d, d), jnp.float32)
-    lowered = jax.jit(lambda *a: K._backward(*a, **kw)).lower(x, x, x, g, beta, kept, x)
-    # dq dk dv as q k v, dg float32, and dbeta a lane-dense row a head a block
     assert [(o.shape, o.dtype) for o in lowered.out_info] == [
+        (x.shape, x.dtype), (kept.shape, kept.dtype)]
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 1
+    lowered = jax.jit(lambda *a: K._backward(*a, **kw)).lower(x, x, x, g, beta, conv, kept, x)
+    # dq dk dv as q k v, dg float32, dbeta a lane-dense row a head a block, and
+    # the taps' gradients a row and a head, summed outside
+    assert [(o.shape, o.dtype) for o in jax.tree.leaves(lowered.out_info)] == [
         (x.shape, x.dtype)] * 3 + [(g.shape, g.dtype),
-                                   ((b, h, nb, 1, s // nb), jnp.dtype("float32"))]
+                                   ((b, h, nb, 1, s // nb), jnp.dtype("float32"))] + [
+        ((b, h, taps, d), jnp.dtype("float32"))] * (3 if taps else 0)
     assert lowered.compile().as_text().count("tpu_custom_call") >= 1
 
 
