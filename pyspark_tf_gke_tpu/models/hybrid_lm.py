@@ -1,9 +1,14 @@
 """A decoder whose layers differ in kind: per layer an attention kind
-(``kda`` linear attention or ``mla`` latent attention without positions) and
-an FFN kind (``dense`` SwiGLU or ``experts``, one chip's share of a dropless
-expert layer). Written for the Kimi-Linear family
-(``moonshotai/Kimi-Linear-48B-A3B-Instruct``): all norms RMSNorm, pre-norm
-blocks, no biases, no embedding scale, untied head.
+(``kda`` linear attention, ``mla`` latent attention without positions,
+``mamba2`` a state-space mixer, ``gqa`` grouped-query attention without
+positions, or ``none``) and an FFN kind (``dense`` SwiGLU, ``experts``, one
+chip's share of a dropless expert layer, or ``none``); a layer has one of the
+two or both, each behind a pre-norm of its own. Written for two families: the
+Kimi-Linear one (``moonshotai/Kimi-Linear-48B-A3B-Instruct``: every layer
+attention then FFN) and Nemotron-H (``nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B``,
+``model_type`` ``nemotron_h``: every layer one mixer, ``mamba2``, ``gqa`` or
+``experts`` by the letter of ``hybrid_override_pattern``). All norms RMSNorm,
+no embedding scale, untied head, no bias but the Mamba-2 convolution's.
 
 * **KDA** (``KDAAttention``): ``q, k = L2norm(silu(conv4(x W)))``, ``v =
   silu(conv4(x W_v))`` with a depthwise causal convolution of 4 taps; a
@@ -20,21 +25,35 @@ blocks, no biases, no embedding scale, untied head.
   of value a head, 64 more key columns shared by all heads and not rotated;
   causal softmax attention through the flash kernels, whose values may be
   narrower than their keys. Training uses this expanded form.
-* **Experts**: ``models/moe.py::HeldExpertsLayer``; its counters are sown
-  into the ``counters`` collection and summed here
-  (:meth:`HybridLM.step_counters`).
+* **Mamba-2** (``Mamba2Mixer``): ``[z | xBC | dt] = u W_in``; ``xBC =
+  silu(conv4(xBC) + b)`` (depthwise, causal); ``xBC`` splits into ``x`` (heads
+  of 64), ``B`` and ``C`` (groups of 128 each, a group shared by its heads);
+  ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``, float32; the chunked
+  scan of ``ops/state_space.py::ssd`` (Pallas kernels ``ssd_fwd`` / ``ssd_bwd``
+  on the TPU); ``RMSNorm_group(y * silu(z))`` (the mean square over each
+  group's channels) and the output projection. The convolution, SiLU,
+  softplus and the gated norm are XLA's.
+* **GQA without positions** (``GQAAttention``): 32 query heads of 128 on 2
+  key-value heads, repeated to 32 before the flash kernels; no rotary
+  embedding (the published ``nemotron_h`` applies none).
+* **Experts**: ``models/moe.py::HeldExpertsLayer`` (``swiglu`` experts for
+  Kimi-Linear, ``relu2`` ones with a shared expert of its own width for
+  Nemotron-H); its counters are sown into the ``counters`` collection and
+  summed here (:meth:`HybridLM.step_counters`).
 
-The training path only: ``decode=True`` / ``prefill=True`` raise (the
-engine's cache has neither latent pages nor a per-slot recurrent state yet:
-ROADMAP Reach 3 and 4), and so do ``segment_ids`` (packed documents would
-have to reset KDA's state inside a row). The call contract is ``CausalLM``'s,
-so ``causal_lm_task`` and ``Trainer`` take the model as they take that one.
+The training path only, for every kind: ``decode=True`` / ``prefill=True``
+raise (the engine's cache has neither latent pages nor a per-slot recurrent
+state, KDA's or the scan's, yet: ROADMAP Reach 3 and 4), and so do
+``segment_ids`` (packed documents would have to reset KDA's and the scan's
+state inside a row). The call contract is ``CausalLM``'s, so
+``causal_lm_task`` and ``Trainer`` take the model as they take that one.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -50,25 +69,40 @@ from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
 from pyspark_tf_gke_tpu.models.moe import HeldExpertsLayer, SwiGLU
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
 from pyspark_tf_gke_tpu.ops.linear_attention import kda
+from pyspark_tf_gke_tpu.ops.state_space import ssd
 
-NOT_SERVED = ("HybridLM has no decode or prefill path: the engine's cache holds "
-              "neither latent KV pages nor a per-slot recurrent state "
-              "(ROADMAP Reach 3 and 4)")
+NOT_SERVED = ("HybridLM has no decode or prefill path, whatever its layers' kinds: the "
+              "engine's cache holds neither latent KV pages nor a per-slot recurrent "
+              "state, KDA's or the state-space scan's (ROADMAP Reach 3 and 4)")
+ATTENTION_KINDS = ("kda", "mla", "mamba2", "gqa", "none")
+FFN_KINDS = ("dense", "experts", "none")
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridLMConfig:
     vocab_size: int
     hidden_size: int
-    attention: Tuple[str, ...]            # per layer: "kda" | "mla"
-    ffn: Tuple[str, ...]                  # per layer: "dense" | "experts"
+    attention: Tuple[str, ...]            # per layer: one of ATTENTION_KINDS
+    ffn: Tuple[str, ...]                  # per layer: one of FFN_KINDS
     # KDA
     kda_heads: int = 32
     kda_head_dim: int = 128
     conv_size: int = 4
     gate_rank: int = 128
-    # MLA
+    # Mamba-2
+    mamba_heads: int = 64
+    mamba_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 8
+    ssm_chunk: int = 128
+    # a fresh ``dt_bias`` is ``softplus^-1(dt)``, ``dt`` log-uniform in [min, max], not under the floor
+    time_step_min: float = 1e-3
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # MLA and GQA
     num_heads: int = 32
+    kv_heads: int = 2                     # GQA
+    head_dim: int = 128                   # GQA
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -80,6 +114,8 @@ class HybridLMConfig:
     experts_held: Tuple[int, int] = (0, 8)   # (first, count) this chip holds
     experts_per_token: int = 8
     shared_experts: int = 1
+    shared_intermediate_size: int = 0     # 0 = shared_experts routed widths
+    expert_activation: str = "swiglu"     # "swiglu" | "relu2"
     route_scale: float = 2.446
     layer_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
@@ -89,11 +125,14 @@ class HybridLMConfig:
     def __post_init__(self):
         if len(self.attention) != len(self.ffn):
             raise ValueError("attention and ffn name one kind per layer each")
-        for kinds, known in ((self.attention, ("kda", "mla")),
-                             (self.ffn, ("dense", "experts"))):
+        for kinds, known in ((self.attention, ATTENTION_KINDS), (self.ffn, FFN_KINDS)):
             bad = set(kinds) - set(known)
             if bad:
                 raise ValueError(f"unknown layer kind {sorted(bad)}; know {known}")
+        empty = [i for i, kinds in enumerate(zip(self.attention, self.ffn))
+                 if kinds == ("none", "none")]
+        if empty:
+            raise ValueError(f"layers {empty} have neither an attention nor an FFN kind")
 
     @property
     def num_layers(self) -> int:
@@ -102,14 +141,18 @@ class HybridLMConfig:
 
 def config_from_file(path_or_dict, dtype=jnp.bfloat16,
                      remat: bool = False) -> HybridLMConfig:
-    """A :class:`HybridLMConfig` from a configuration file with the family's
-    published keys (``benchmark/configs/kimi-linear-48b-a3b.json``):
-    ``num_experts`` there counts the experts held on this chip, and
-    ``published.num_experts`` is the router's width."""
+    """A :class:`HybridLMConfig` from a configuration file with its family's
+    published keys, the family by ``model_type``: ``kimi_linear``
+    (``benchmark/configs/kimi-linear-48b-a3b.json``) or ``nemotron_h``
+    (``benchmark/configs/nemotron-3-nano-30b-a3b.json``). The key that counts
+    the routed experts (``num_experts`` / ``n_routed_experts``) there counts
+    those held on this chip, and ``published`` has the router's width."""
     c = path_or_dict
     if not isinstance(c, dict):
         with open(c) as f:
             c = json.load(f)
+    if c.get("model_type") == "nemotron_h":
+        return _nemotron_h_config(c, dtype, remat)
     lin = c["linear_attn_config"]
     layers = range(1, c["num_hidden_layers"] + 1)
     bad = [n for n in layers if (n in lin["kda_layers"]) == (n in lin["full_attn_layers"])]
@@ -137,6 +180,38 @@ def config_from_file(path_or_dict, dtype=jnp.bfloat16,
         layer_norm_eps=float(c["rms_norm_eps"]), dtype=dtype, remat=remat)
 
 
+def _nemotron_h_config(c: dict, dtype, remat: bool) -> HybridLMConfig:
+    """``hybrid_override_pattern`` names one mixer a layer: ``M`` Mamba-2, ``*``
+    attention, ``E`` experts."""
+    pattern = c["hybrid_override_pattern"]
+    if len(pattern) != c["num_hidden_layers"] or set(pattern) - set("M*E"):
+        raise ValueError(f"hybrid_override_pattern {pattern!r} does not name "
+                         f"{c['num_hidden_layers']} layers by M, * and E")
+    if c["mlp_hidden_act"] != "relu2":
+        raise ValueError(f"nemotron_h experts are relu2 here, not {c['mlp_hidden_act']!r}")
+    return HybridLMConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        attention=tuple({"M": "mamba2", "*": "gqa", "E": "none"}[k] for k in pattern),
+        ffn=tuple("experts" if k == "E" else "none" for k in pattern),
+        mamba_heads=c["mamba_num_heads"], mamba_head_dim=c["mamba_head_dim"],
+        ssm_state=c["ssm_state_size"], ssm_groups=c["n_groups"], ssm_chunk=c["chunk_size"],
+        time_step_min=float(c["time_step_min"]), time_step_max=float(c["time_step_max"]),
+        time_step_floor=float(c["time_step_floor"]),
+        conv_size=c["conv_kernel"],
+        num_heads=c["num_attention_heads"], kv_heads=c["num_key_value_heads"],
+        head_dim=c["head_dim"],
+        expert_intermediate_size=c["moe_intermediate_size"],
+        num_experts=c.get("published", {}).get("n_routed_experts", c["n_routed_experts"]),
+        experts_held=(c.get("deployment", {}).get("experts_held_first", 0),
+                      c["n_routed_experts"]),
+        experts_per_token=c["num_experts_per_tok"],
+        shared_experts=c["n_shared_experts"],
+        shared_intermediate_size=c["moe_shared_expert_intermediate_size"] * c["n_shared_experts"],
+        expert_activation="relu2",
+        route_scale=float(c["routed_scaling_factor"]),
+        layer_norm_eps=float(c["layer_norm_epsilon"]), dtype=dtype, remat=remat)
+
+
 def _per_shard(fn, mesh: Optional[Mesh], *specs):
     """``fn`` as it is on one device; under a mesh that shards the batch or
     the heads, per shard (Mosaic kernels are never partitioned)."""
@@ -146,18 +221,33 @@ def _per_shard(fn, mesh: Optional[Mesh], *specs):
                      check_vma=False)
 
 
+def _symmetric_uniform(bound: float):
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+
+
 class CausalConv(nn.Module):
-    """The taps ``kernel [size, features]`` (float32, no bias) of a depthwise
-    causal convolution over time, the last on the current token. ``kda``
-    convolves with them, on the rows its kernels hold."""
+    """The taps ``kernel [size, features]`` (float32) of a depthwise causal
+    convolution over time, the last on the current token; with ``use_bias``
+    ``(kernel, bias [features])``. ``kda`` convolves with the taps on the rows
+    its kernels hold; ``Mamba2Mixer`` convolves by itself, and draws taps and
+    bias as a depthwise ``Conv1d`` is drawn where it was published:
+    ``U(-size^-1/2, size^-1/2)`` (``published_init``)."""
 
     size: int
     features: int
+    use_bias: bool = False
+    published_init: bool = False
 
     @nn.compact
     def __call__(self):
-        return self.param("kernel", nn.initializers.normal(stddev=0.02),
-                          (self.size, self.features), jnp.float32)
+        draw = _symmetric_uniform(self.size ** -0.5) if self.published_init else None
+        kernel = self.param("kernel", draw or nn.initializers.normal(stddev=0.02),
+                            (self.size, self.features), jnp.float32)
+        if not self.use_bias:
+            return kernel
+        return kernel, self.param("bias", draw or nn.initializers.zeros_init(),
+                                  (self.features,), jnp.float32)
 
 
 class _Scale(nn.Module):
@@ -239,15 +329,116 @@ class MLAAttention(nn.Module):
                       use_bias=False)(out.reshape(b, s, heads * dv))
 
     def _causal_attend(self, q, k, v):
+        return _flash_or_dense(self.cfg, self.mesh, q, k, v)
+
+
+def _flash_or_dense(cfg, mesh, q, k, v):
+    """Causal softmax attention of ``q, k, v [B, S, H, D]``: the flash kernels
+    where ``resolve_use_flash`` says so (per shard under a mesh), else dense.
+    Called from a module's ``_causal_attend``, whose name the launches carry."""
+    from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES
+
+    if not resolve_use_flash(cfg, q.shape[1]):
+        return dot_product_attention(q, k, v, causal=True)
+    from pyspark_tf_gke_tpu.ops.pallas.flash_attention import flash_attention
+
+    spec = P(DATA_AXES, None, "tp", None)
+    return _per_shard(lambda qq, kk, vv: flash_attention(qq, kk, vv, causal=True),
+                      mesh, spec, spec, spec)(q, k, v)
+
+
+class GQAAttention(nn.Module):
+    cfg: HybridLMConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, hidden):
+        cfg = self.cfg
+        b, s, _ = hidden.shape
+        heads, kv_heads, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+
+        def dense(features, name):
+            return _dense(features, ("embed", "mlp"), cfg, name=name, use_bias=False)
+
+        q = dense(heads * dim, "q_proj")(hidden).reshape(b, s, heads, dim)
+        # the training pass: K and V repeated to the query heads, so that the
+        # kernels the other decoders use apply (``models/causal_lm.py``)
+        k, v = (jnp.repeat(dense(kv_heads * dim, f"{name}_proj")(hidden).reshape(
+            b, s, kv_heads, dim), heads // kv_heads, axis=2) for name in "kv")
+        out = self._causal_attend(q, k, v)
+        return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, name="o_proj",
+                      use_bias=False)(out.reshape(b, s, heads * dim))
+
+    def _causal_attend(self, q, k, v):
+        return _flash_or_dense(self.cfg, self.mesh, q, k, v)
+
+
+def _log_uniform_a(key, shape, dtype=jnp.float32):
+    """``A_log`` with ``A = exp(A_log)`` uniform in [1, 16], as published."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _inverse_softplus_of_dt(lo: float, hi: float, floor: float):
+    """``dt_bias`` with ``softplus(dt_bias)`` log-uniform in ``[lo, hi]`` and
+    not under ``floor``, as published."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, dtype, math.log(lo), math.log(hi))), floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """A fresh mixer is drawn as the published implementation draws it
+    (``A_log``, ``dt_bias``, ``D`` = 1, the convolution): with ``A_log`` and
+    ``dt_bias`` at nought a state halves every token and nothing outlives a
+    chunk, and with taps of 0.02 the state's part of ``y`` is a thousandth of
+    the skip's ``D x``, so training from scratch would start from a mixer
+    whose scan is inert."""
+
+    cfg: HybridLMConfig
+    mesh: Optional[Mesh] = None
+
+    @nn.compact
+    def __call__(self, hidden):
         from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES
 
-        if not resolve_use_flash(self.cfg, q.shape[1]):
-            return dot_product_attention(q, k, v, causal=True)
-        from pyspark_tf_gke_tpu.ops.pallas.flash_attention import flash_attention
+        cfg, f32 = self.cfg, jnp.float32
+        heads, groups, n = cfg.mamba_heads, cfg.ssm_groups, cfg.ssm_state
+        inner, keys = heads * cfg.mamba_head_dim, groups * n
+        zxbcdt = _dense(2 * inner + 2 * keys + heads, ("embed", "mlp"), cfg, name="in_proj",
+                        use_bias=False)(hidden)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * keys], axis=-1)
+        # the short convolution over time and its SiLU, float32, then cfg.dtype
+        taps, bias = CausalConv(cfg.conv_size, inner + 2 * keys, use_bias=True,
+                                published_init=True, name="conv")()
+        s = xbc.shape[1]
+        padded = jnp.pad(xbc.astype(f32), ((0, 0), (cfg.conv_size - 1, 0), (0, 0)))
+        xbc = jax.nn.silu(sum(padded[:, j:j + s] * taps[j] for j in range(cfg.conv_size))
+                          + bias).astype(cfg.dtype)
+        x, b, c = jnp.split(xbc, [inner, inner + keys], axis=-1)
+        a_log = self.param("A_log", _log_uniform_a, (heads,), f32)
+        dt_bias = self.param("dt_bias", _inverse_softplus_of_dt(
+            cfg.time_step_min, cfg.time_step_max, cfg.time_step_floor), (heads,), f32)
+        skip = self.param("D", nn.initializers.ones_init(), (heads,), f32)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
 
-        spec = P(DATA_AXES, None, "tp", None)
-        return _per_shard(lambda qq, kk, vv: flash_attention(qq, kk, vv, causal=True),
-                          self.mesh, spec, spec, spec)(q, k, v)
+        def scan(x, dt, b, c, a, skip):               # under a mesh: a shard's heads and groups
+            return ssd(x, dt, a, b, c, skip, heads=dt.shape[-1], groups=b.shape[-1] // n,
+                       chunk=cfg.ssm_chunk)
+
+        y = _per_shard(scan, self.mesh, *[P(DATA_AXES, None, "tp")] * 4, P("tp"), P("tp"))(
+            x, dt, b, c, -jnp.exp(a_log), skip)
+        # the gated norm: the mean square over each group's channels, float32
+        y = (y.astype(f32) * jax.nn.silu(z.astype(f32))).reshape(*y.shape[:2], groups, -1)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.layer_norm_eps)
+        y = y.reshape(*y.shape[:2], inner) * _Scale(inner, name="norm")()
+        return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, name="out_proj",
+                      use_bias=False)(y.astype(cfg.dtype))
+
+
+MIXERS = {"kda": KDAAttention, "mla": MLAAttention, "mamba2": Mamba2Mixer,
+          "gqa": GQAAttention}
 
 
 class HybridBlock(nn.Module):
@@ -259,10 +450,14 @@ class HybridBlock(nn.Module):
     def __call__(self, hidden):
         cfg = self.cfg
         norm = lambda name: RMSNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name=name)
-        attend = KDAAttention if cfg.attention[self.layer] == "kda" else MLAAttention
-        hidden = hidden + attend(cfg, self.mesh, name="attention")(norm("ln_attn")(hidden))
+        kind, ffn = cfg.attention[self.layer], cfg.ffn[self.layer]
+        if kind != "none":
+            hidden = hidden + MIXERS[kind](cfg, self.mesh, name="attention")(
+                norm("ln_attn")(hidden))
+        if ffn == "none":
+            return hidden
         m = norm("ln_mlp")(hidden)
-        if cfg.ffn[self.layer] == "dense":
+        if ffn == "dense":
             return hidden + SwiGLU(cfg.hidden_size, cfg.intermediate_size, cfg.dtype,
                                    name="mlp")(m)
         out, counters = HeldExpertsLayer(
@@ -270,7 +465,8 @@ class HybridBlock(nn.Module):
             top_k=cfg.experts_per_token, hidden_size=cfg.hidden_size,
             intermediate_size=cfg.expert_intermediate_size,
             route_scale=cfg.route_scale, shared=cfg.shared_experts,
-            dtype=cfg.dtype, name="mlp")(m)
+            dtype=cfg.dtype, activation=cfg.expert_activation,
+            shared_width=cfg.shared_intermediate_size, name="mlp")(m)
         for name, value in counters.items():
             self.sow("counters", name, value)
         return hidden + out
@@ -297,7 +493,8 @@ class HybridLM(nn.Module):
         if segment_ids is not None:
             raise NotImplementedError(
                 "HybridLM takes no segment_ids yet: packed documents would have "
-                "to reset KDA's state inside a row (ROADMAP Reach 4)")
+                "to reset the recurrent state, KDA's or the state-space scan's, "
+                "inside a row (ROADMAP Reach 4)")
         hidden = TokenEmbed(
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
             embedding_init=nn.with_logical_partitioning(

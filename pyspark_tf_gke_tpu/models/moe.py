@@ -146,7 +146,8 @@ def _slab(start, xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
     """The held assignments ``[start, start + rows)`` of the sorted list
     ``order``, as their weighted outputs scattered to their tokens
     (``[T, H]`` float32). ``ends`` and ``loads`` are the held experts'
-    cumulative and own assignment counts."""
+    cumulative and own assignment counts. ``w_gate`` is ``None`` for experts
+    without a gate (``relu(x W_up)^2 W_down``)."""
     mine = jax.lax.dynamic_slice(order, (start,), (rows,))
     valid = start + jnp.arange(rows) < ends[-1]
     token = mine // k
@@ -156,20 +157,30 @@ def _slab(start, xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
     sizes = sizes.at[-1].add(rows - jnp.sum(sizes))
     cast = lambda w: w.astype(dtype)
     xs = cast(xt[token])
-    mid = jax.nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), sizes)) \
-        * jax.lax.ragged_dot(xs, cast(w_up), sizes)
+    if w_gate is None:                  # relu2: two matrices an expert
+        mid = jnp.square(jax.nn.relu(jax.lax.ragged_dot(xs, cast(w_up), sizes)))
+    else:
+        mid = jax.nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), sizes)) \
+            * jax.lax.ragged_dot(xs, cast(w_up), sizes)
     ys = jax.lax.ragged_dot(mid, cast(w_down), sizes,
                             preferred_element_type=jnp.float32)
     ys = jnp.where(valid[:, None], ys * flat_w[mine][:, None], 0.0)
     return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
 
 
+def _slabs_walked(total, rows):
+    """``ceil(total / rows)`` and never nought: the first slab is walked
+    whatever the load (at a load of nought all its rows are padding), so a
+    layer costs the same at one held assignment or none."""
+    return jnp.maximum(-(-total // rows), 1)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
 def _held_experts(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
                   dtype):
     """Sum of :func:`_slab` over the slabs that hold an assignment: a loop
-    whose trip count follows the load (``ceil(held assignments / rows)``), in
-    the forward and in the backward alike, so no slab's operands are kept."""
+    whose trip count follows the load (:func:`_slabs_walked`), in the forward
+    and in the backward alike, so no slab's operands are kept."""
     return _held_experts_fwd(xt, flat_w, w_gate, w_up, w_down, order, ends, loads,
                              rows, k, dtype)[0]
 
@@ -178,7 +189,7 @@ def _held_experts_fwd(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows
                       k, dtype):
     diff, ints = (xt, flat_w, w_gate, w_up, w_down), (order, ends, loads)
     out = jax.lax.fori_loop(
-        0, -(-ends[-1] // rows),
+        0, _slabs_walked(ends[-1], rows),
         lambda i, acc: acc + _slab(i * rows, *diff, *ints, rows, k, dtype),
         jnp.zeros(xt.shape, jnp.float32))
     return out, (diff, ints)
@@ -192,7 +203,7 @@ def _held_experts_bwd(rows, k, dtype, residuals, g):
             lambda *d: _slab(i * rows, *d, *ints, rows, k, dtype), *diff)
         return jax.tree.map(jnp.add, acc, pull(g))
 
-    grads = jax.lax.fori_loop(0, -(-ints[1][-1] // rows), one,
+    grads = jax.lax.fori_loop(0, _slabs_walked(ints[1][-1], rows), one,
                               jax.tree.map(jnp.zeros_like, diff))
     return (*grads, None, None, None)
 
@@ -224,8 +235,11 @@ class HeldExpertsLayer(nn.Module):
 
     The router keeps its full width ``num_experts`` and its ``top_k``; this
     chip holds the experts ``held = (first, count)`` and computes their part
-    of ``y = sum_chosen w_e E_e(x)``, with ``E(x) = (silu(x W1) * (x W3)) W2``,
-    plus the ``shared`` always-on expert. What the absent experts would add is
+    of ``y = sum_chosen w_e E_e(x)``, with ``E(x) = (silu(x W1) * (x W3)) W2``
+    (``activation`` ``swiglu``) or ``E(x) = relu(x W_up)^2 W_down`` (``relu2``:
+    no ``w_gate`` leaf), plus the ``shared`` always-on expert of the same
+    activation, ``shared_width`` wide where that is given and ``shared`` routed
+    widths otherwise. What the absent experts would add is
     left out; on one chip there is no exchange. Routing is sigmoid scores,
     the top ``top_k`` of ``s + b`` (``router_bias``, a buffer: no gradient),
     weights ``route_scale * s_e / sum_chosen s``. No capacity, no dropped
@@ -233,9 +247,9 @@ class HeldExpertsLayer(nn.Module):
 
     The assignments to held experts are sorted by expert and multiplied by
     groups (``jax.lax.ragged_dot``) in slabs of ``slab_rows`` rows by a loop
-    that runs as many slabs as hold an assignment, so the work follows the
-    load, and however many tokens choose a held expert (at most ``top_k`` x
-    tokens assignments) every one is computed.
+    that runs as many slabs as hold an assignment and at least one, so the
+    work follows the load in whole slabs, and however many tokens choose a
+    held expert (at most ``top_k`` x tokens assignments) every one is computed.
 
     Shape-preserving on ``[B, S, H]``; returns ``(out, counters)`` with
     ``held_assignments`` (assignments to held experts in this call) and
@@ -251,9 +265,14 @@ class HeldExpertsLayer(nn.Module):
     shared: int = 0               # shared experts, as one FFN of that many widths
     slab_rows: int = 0            # 0 = twice the mean load of the held experts
     dtype: Any = jnp.bfloat16
+    activation: str = "swiglu"    # "swiglu" | "relu2"
+    shared_width: int = 0         # the shared expert's width; 0 = ``shared`` routed widths
 
     @nn.compact
     def __call__(self, x: jnp.ndarray):
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"unknown expert activation {self.activation!r}; "
+                             f"know {sorted(ACTIVATIONS)}")
         b, s, h = x.shape
         tokens, k = b * s, self.top_k
         first, count = self.held
@@ -261,7 +280,8 @@ class HeldExpertsLayer(nn.Module):
         init = nn.initializers.normal(stddev=0.02)
         bias = self.param("router_bias", nn.initializers.zeros_init(),
                           (self.num_experts,), jnp.float32)
-        w_gate = self.param("w_gate", init, (count, h, wide), jnp.float32)
+        w_gate = None if self.activation == "relu2" else self.param(
+            "w_gate", init, (count, h, wide), jnp.float32)
         w_up = self.param("w_up", init, (count, h, wide), jnp.float32)
         w_down = self.param("w_down", init, (count, wide, h), jnp.float32)
         xt = x.reshape(tokens, h)
@@ -294,7 +314,29 @@ class HeldExpertsLayer(nn.Module):
         out = _held_experts(xt, weights.reshape(-1), w_gate, w_up, w_down,
                             order, ends, loads, rows, k, jnp.dtype(self.dtype))
         if self.shared:
-            out = out + SwiGLU(h, wide * self.shared, self.dtype, name="shared")(xt)
+            out = out + ACTIVATIONS[self.activation](
+                h, self.shared_width or wide * self.shared, self.dtype, name="shared")(xt)
         counters = {"held_assignments": total.astype(jnp.float32),
                     "held_load_max": jnp.max(loads).astype(jnp.float32)}
         return out.reshape(b, s, h).astype(x.dtype), counters
+
+
+class Relu2FFN(nn.Module):
+    """``relu(x W_up)^2 W_down``, no gate and no biases."""
+
+    hidden_size: int
+    intermediate_size: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            kernel_init=nn.initializers.normal(stddev=0.02), name=name)
+
+        return dense(self.hidden_size, "down")(
+            jnp.square(jax.nn.relu(dense(self.intermediate_size, "up")(x))))
+
+
+# an expert's activation -> the shared expert's module
+ACTIVATIONS = {"swiglu": SwiGLU, "relu2": Relu2FFN}

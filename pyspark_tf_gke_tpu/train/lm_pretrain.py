@@ -45,6 +45,10 @@ from pyspark_tf_gke_tpu.utils.seeding import make_rng
 logger = get_logger("train.lm_pretrain")
 
 
+# --arch -> the ``model_type`` its --model-config file has to state
+HYBRID_ARCHS = {"kimi-linear": "kimi_linear", "nemotron-h": "nemotron_h"}
+
+
 def parse_args(argv=None) -> argparse.Namespace:
     e = os.environ.get
     p = argparse.ArgumentParser(
@@ -87,17 +91,19 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--ffn", default=e("FFN") or None,
                    choices=["gelu", "swiglu"])
     p.add_argument("--arch", default=e("ARCH", ""),
-                   choices=["", "gpt2", "llama", "kimi-linear"],
+                   choices=["", "gpt2", "llama", *HYBRID_ARCHS],
                    help="architecture preset: gpt2 = learned+layernorm+gelu "
                         "(the defaults); llama = rope+rmsnorm+swiglu; "
-                        "kimi-linear = the hybrid decoder of models/hybrid_lm.py "
-                        "(KDA + MLA layers, dense + expert FFNs), sized by "
-                        "--model-config")
+                        "kimi-linear and nemotron-h = the hybrid decoder of "
+                        "models/hybrid_lm.py (kimi-linear: KDA + MLA layers, "
+                        "dense + expert FFNs; nemotron-h: one mixer a layer, "
+                        "Mamba-2, GQA or relu2 experts), sized by --model-config")
     p.add_argument("--model-config", default=e("MODEL_CONFIG", ""),
                    help="configuration file with the family's published keys "
                         "(--arch kimi-linear: e.g. benchmark/configs/"
-                        "kimi-linear-48b-a3b.json); it gives every size, the "
-                        "vocabulary among them")
+                        "kimi-linear-48b-a3b.json; --arch nemotron-h: e.g. "
+                        "benchmark/configs/nemotron-3-nano-30b-a3b.json); it "
+                        "gives every size, the vocabulary among them")
     p.add_argument("--doc-masking", action="store_true",
                    default=_env_bool("DOC_MASKING", False),
                    help="confine attention within document boundaries in "
@@ -162,11 +168,21 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def _hybrid_config(args, tokenizer, dtype):
-    """``--arch kimi-linear``: every size from ``--model-config``; the
-    tokenizer's ids have to fit the file's (possibly sliced) vocabulary."""
+    """``--arch kimi-linear`` / ``nemotron-h``: every size from
+    ``--model-config``, a file of that family; the tokenizer's ids have to fit
+    the file's (possibly sliced) vocabulary."""
+    import json
+
     from pyspark_tf_gke_tpu.models.hybrid_lm import config_from_file
 
-    cfg = config_from_file(args.model_config, dtype=dtype, remat=args.remat)
+    with open(args.model_config) as f:
+        file = json.load(f)
+    if file.get("model_type") != HYBRID_ARCHS[args.arch]:
+        raise SystemExit(
+            f"--arch {args.arch} takes a --model-config of model_type "
+            f"{HYBRID_ARCHS[args.arch]!r}; {args.model_config} states "
+            f"{file.get('model_type')!r}")
+    cfg = config_from_file(file, dtype=dtype, remat=args.remat)
     if tokenizer.vocab_size > cfg.vocab_size:
         raise SystemExit(
             f"tokenizer {args.tokenizer!r} has {tokenizer.vocab_size} ids, the "
@@ -191,13 +207,14 @@ def main(argv=None) -> dict:
                         "ffn": "gelu"},
                "": {}}
     builtin = {"pos_embedding": "learned", "norm": "layernorm", "ffn": "gelu"}
-    hybrid = args.arch == "kimi-linear"
+    hybrid = args.arch in HYBRID_ARCHS
     if hybrid != bool(args.model_config):
-        raise SystemExit("--arch kimi-linear and --model-config go together")
+        raise SystemExit(f"--arch {' / '.join(HYBRID_ARCHS)} and --model-config go together")
     if hybrid and (args.doc_masking or args.export_bundle):
-        raise SystemExit("--arch kimi-linear trains only: no --doc-masking (KDA's "
-                         "state is not reset inside a row) and no --export-bundle "
-                         "(no decode path) yet")
+        raise SystemExit(f"--arch {args.arch} trains only: no --doc-masking (the "
+                         "recurrent state, KDA's or the state-space scan's, is not "
+                         "reset inside a row) and no --export-bundle (no decode "
+                         "path) yet")
     preset = presets.get(args.arch, {})
     for name, default in builtin.items():
         explicit = getattr(args, name)

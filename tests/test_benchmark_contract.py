@@ -38,9 +38,11 @@ from lib import spans as S  # noqa: E402
 from lib import trace as T  # noqa: E402
 
 STEPS = 2                                    # of the traced ``fit``
-# a cell's runner -> its family's toy configuration, run under TOY_TRAFFIC
-TOY = {"train": "tiny-gpt2", "train_kimi_linear": "tiny-kimi"}
-TOY_TRAFFIC = "train.tiny-seq128"
+# a cell's runner -> its family's toy configuration and the toy traffic it runs under
+# (the state-space scan's chunk is 128 rows: its family's toy rows hold two)
+TOY = {"train": ("tiny-gpt2", "train.tiny-seq128"),
+       "train_kimi_linear": ("tiny-kimi", "train.tiny-seq128"),
+       "train_nemotron_h": ("tiny-nemotron", "train.tiny-seq256")}
 # Readers without module constants: the ``ctx`` keys they read. The runner
 # fills each from ``Trainer.fit``'s history: a counter is the window's mean
 # under its own name, ``steps`` is the window sized by ``step_time_ms``.
@@ -49,6 +51,10 @@ CTX_KEYS = {
     "mfu.train.kimi-linear": ("steps", "moe_held_assignments"),
     "moe_held_tokens_per_expert.train": ("moe_held_assignments",),
     "moe_held_load_max_over_mean.train": ("moe_held_assignments", "moe_held_load_max"),
+    "mfu.train.nemotron-h": ("steps", "moe_held_assignments"),
+    "moe_held_tokens_per_expert.train.nemotron-h": ("moe_held_assignments",),
+    "moe_held_load_max_over_mean.train.nemotron-h": ("moe_held_assignments",
+                                                     "moe_held_load_max"),
 }
 FROM_HISTORY = {"steps": "step_time_ms"}
 
@@ -84,31 +90,34 @@ def _gpt2_model(cfg, tr, mesh):
         dtype=jnp.bfloat16, remat=bool(tr["remat"]), use_flash=True), mesh=mesh)
 
 
-def _kimi_model(cfg, tr, mesh):
+def _hybrid_model(cfg, tr, mesh):
+    """Either family of ``models/hybrid_lm.py``, by the file's ``model_type``."""
     from pyspark_tf_gke_tpu.models.hybrid_lm import HybridLM, config_from_file
 
     mcfg = config_from_file(cfg, dtype=jnp.bfloat16, remat=bool(tr["remat"]))
     return HybridLM(dataclasses.replace(mcfg, use_flash=True), mesh=mesh)
 
 
-MODELS = {"train": _gpt2_model, "train_kimi_linear": _kimi_model}
+MODELS = {"train": _gpt2_model, "train_kimi_linear": _hybrid_model,
+          "train_nemotron_h": _hybrid_model}
 
 
 def written(runner, trace_dir):
     """What a trainer of ``runner``'s family writes, by the runner's own lines
     (``benchmark/runners/<runner>.py::build``) at the toy size. Off the TPU
-    neither decoder takes its kernels unasked, so the configuration says
-    ``use_flash`` (flash then interprets by itself) and ``kda`` is told to
-    interpret through the name ``models/hybrid_lm.py`` imports."""
+    no decoder takes its kernels unasked, so the configuration says
+    ``use_flash`` (flash then interprets by itself) and ``kda`` and ``ssd``
+    are told to interpret through the names ``models/hybrid_lm.py`` imports."""
     from pyspark_tf_gke_tpu.models import hybrid_lm
     from pyspark_tf_gke_tpu.obs.trace import TraceRecorder
     from pyspark_tf_gke_tpu.parallel.mesh import make_mesh
     from pyspark_tf_gke_tpu.train.harness import make_optimizer
     from pyspark_tf_gke_tpu.train.trainer import Trainer, causal_lm_task
 
-    cfg = _json(DATA, "configs", TOY[runner] + ".json")
-    cell = _json(DATA, "cells", f"{TOY[runner]}.{TOY_TRAFFIC}.json")
-    mix = _json(DATA, "traffic", TOY_TRAFFIC + ".json")
+    toy, traffic = TOY[runner]
+    cfg = _json(DATA, "configs", toy + ".json")
+    cell = _json(DATA, "cells", f"{toy}.{traffic}.json")
+    mix = _json(DATA, "traffic", traffic + ".json")
     tr = cell["train"]
     rows, seq = int(tr["rows_per_chip"]), int(mix["seq_len"])
 
@@ -119,6 +128,7 @@ def written(runner, trace_dir):
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(hybrid_lm, "kda", functools.partial(hybrid_lm.kda, interpret=True))
+        patch.setattr(hybrid_lm, "ssd", functools.partial(hybrid_lm.ssd, interpret=True))
         mesh = make_mesh(tr["mesh"], devices=jax.devices()[:1])
         tracer = TraceRecorder()
         trainer = Trainer(MODELS[runner](cfg, tr, mesh),

@@ -180,7 +180,7 @@ def test_what_is_not_built_yet_raises(tiny, ids, kw, match):
 
 def test_config_refuses_unknown_kinds_and_ragged_lists(tiny):
     with pytest.raises(ValueError, match="unknown layer kind"):
-        HybridLMConfig(vocab_size=8, hidden_size=8, attention=("gqa",), ffn=("dense",))
+        HybridLMConfig(vocab_size=8, hidden_size=8, attention=("swa",), ffn=("dense",))
     with pytest.raises(ValueError, match="one kind per layer"):
         HybridLMConfig(vocab_size=8, hidden_size=8, attention=("kda", "mla"), ffn=("dense",))
     bad = dict(tiny, linear_attn_config=dict(tiny["linear_attn_config"], kda_layers=[1, 2]))

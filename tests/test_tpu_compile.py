@@ -1,5 +1,5 @@
 """The new kernels compiled for a described TPU v5e at the widths the
-Kimi-Linear cell runs them at: what interpret mode cannot show (tiling, VMEM,
+Kimi-Linear and Nemotron-H cells run them at: what interpret mode cannot show (tiling, VMEM,
 what Mosaic lowers). Nothing runs; no chip is needed. One file, so that one
 xdist worker loads the TPU's library."""
 
@@ -62,6 +62,34 @@ def test_kda_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu
         (x.shape, x.dtype)] * 3 + [(g.shape, g.dtype),
                                    ((b, h, nb, 1, s // nb), jnp.dtype("float32"))] + [
         ((b, h, taps, d), jnp.dtype("float32"))] * (3 if taps else 0)
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("mxu", ["bfloat16", "float32"])
+def test_ssd_kernels_compile_at_the_cells_widths(one_chip, no_compile_cache, mxu):
+    """64 heads of 64 in 8 groups, a state of 128, chunks of 128: a group's
+    block is four 128-lane slabs of two heads, ``dt`` a 64-lane block."""
+    from pyspark_tf_gke_tpu.ops.pallas import ssd as K
+
+    b, s, h, g, p, n = 1, 1024, 64, 8, 64, 128
+    dtype = jnp.dtype(mxu)
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+    x, keys = shape((b, s, h * p), dtype), shape((b, s, g * n), dtype)
+    dt, per_head = shape((b, s, h), jnp.float32), shape((h,), jnp.float32)
+    kw = dict(heads=h, groups=g, chunk=128, mxu=dtype, interpret=False, caller="")
+    lowered = jax.jit(lambda *a: K._forward(*a, **kw)).lower(x, dt, per_head, keys, keys, per_head)
+    kept = shape((b, g, s // 256, 4, 128, n), jnp.float32)
+    assert [(o.shape, o.dtype) for o in lowered.out_info] == [
+        (x.shape, x.dtype), (kept.shape, kept.dtype)]
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 1
+    lowered = jax.jit(lambda *a: K._backward(*a, **kw)).lower(
+        x, dt, per_head, keys, keys, per_head, kept, x)
+    # dx as x, ddt a group's own [S, H] (zero off its columns), da and dd a
+    # row and a group, all summed outside; db and dc as b and c
+    sums = ((b, g, 1, h), jnp.dtype("float32"))
+    assert [(o.shape, o.dtype) for o in lowered.out_info] == [
+        (x.shape, x.dtype), ((b, g, s, h), jnp.dtype("float32")), sums,
+        (keys.shape, keys.dtype), (keys.shape, keys.dtype), sums]
     assert lowered.compile().as_text().count("tpu_custom_call") >= 1
 
 
