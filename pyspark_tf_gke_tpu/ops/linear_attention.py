@@ -24,7 +24,13 @@ sub-block's decays relative to its own first row, so every exponent is at most
 15 tokens' worth (up to ``_EXP_CAP`` = 80 is exact; a mean decay past 5.3 a
 token a channel over 15 tokens is not supported). ``(I + A)^-1`` of the
 strictly lower-triangular ``A`` is the product ``(I - A)(I + A^2)(I + A^4)...``,
-which ends because ``A^64 = 0``; it is computed in float32.
+which ends because ``A^64 = 0``. Its factors are polynomials in ``A`` and
+commute, so each is multiplied on from the left, where it shares its left
+operand with the squaring that makes the next one: ``P [P | T]`` is the next
+power and the update in one 64 x 64 x 128 product, and six such float32
+products make ``T``, those of a block's chunks issued side by side. Backward
+it is differentiated in closed form, ``dA = -T^T dT T^T``, two more
+(:func:`_unit_lower_inverse`).
 
 The per-head element-wise work around the recurrence is part of the chunk
 (:func:`block_step`), done in float32 on the rows a step holds: the short
@@ -82,8 +88,8 @@ def _dot(a, b, dims, mxu):
                                preferred_element_type=jnp.float32)
 
 
-def _dot32(a, b):
-    return jax.lax.dot_general(a, b, _NN, precision=jax.lax.Precision.HIGHEST,
+def _dot32(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
 
@@ -110,32 +116,62 @@ _running_sum.defvjp(lambda g, reverse: (_running_sum(g, reverse), None),
                     lambda reverse, _, ct: (_running_sum(ct, not reverse),))
 
 
-def _unit_lower_inverse(a):
-    """``(I + a)^-1`` of a strictly lower-triangular ``a [C, C]``, float32."""
-    c = a.shape[0]
-    r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    t = jnp.where(r == col, 1.0, 0.0) - a        # holds the powers below 2
-    p, span = a, 2
-    while span < c:
-        p = _dot32(p, p)                         # a^span
-        t = t + _dot32(t, p)
+@jax.custom_vjp
+def _unit_lower_inverse(chunks):
+    """``T = (I + a)^-1`` of every ``a [C, C]`` of the tuple ``chunks``, float32,
+    each of which has to be strictly lower-triangular (what is on or above the
+    diagonal is not masked here: :func:`_scores`'s ``where`` does it, and masks
+    the cotangent through its own transpose). ``a^C = 0``, so ``T = (I - a)
+    (I + a^2)(I + a^4)...`` ends with the factor of ``a^(C/2)``. Every factor
+    is a polynomial in ``a`` and they commute, so a round's update is taken
+    from the left, ``t + p t``, where the round's squaring ``p p`` has the
+    same left operand: the two are one product ``p [p | t]``, ``[C, C] x
+    [C, 2C]``, the MXU's 128 columns for ``C`` = 64. ``[p | t]`` stays one
+    array from round to round (laying two side by side on the lanes every
+    round costs more than the product saves): ``p`` is its left half, read in
+    place, and ``t`` comes out of its right half once, at the end. That is
+    ``a [a | I]``, four rounds and a last ``t + p t``: six products, all
+    float32 at ``HIGHEST``, each a link of one dependent chain. The chunks'
+    chains are issued side by side, link by link: a block's chunks do not wait
+    for one another here as they do for the state, and the compiler keeps the
+    order it is given. Backward it is no transpose of that series: ``dT = -T
+    da T`` gives the cotangent ``-T^T ct T^T`` in two products of the one
+    residual ``T``."""
+    c = chunks[0].shape[0]
+    r = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    right, eye = col >= c, jnp.where(col == r + c, 1.0, 0.0)                 # [0 | I]
+    ws = [_dot32(a, jnp.concatenate([a, jnp.zeros_like(a)], axis=1) + eye)
+          for a in chunks]                                                   # [a a | a]
+    # [p | t]: p = a^span, t the series' powers below span
+    ws, span = [jnp.where(right, eye - w, w) for w in ws], 2
+    while 2 * span < c:
+        ws = [_dot32(w[:, :c], w) + jnp.where(right, w, 0.0) for w in ws]    # [p p | t + p t]
         span *= 2
-    return t
+    # the last product's left half, p p, is not used: the MXU's columns are there either way
+    return tuple((w + _dot32(w[:, :c], w))[:, c:] for w in ws)
 
 
-def _chunk(q, k, kb, vb, gc, state, mxu):
-    """One chunk. ``q, k, kb, vb, gc``: lists of the chunk's ``SUB``-row
-    sub-blocks ``[SUB, D]`` (``kb = beta k``, ``vb = beta v``, ``gc`` the
-    log-decay summed from the chunk's first row, float32); ``state [Dv, Dk]``
-    float32, the transpose of ``S``. Returns ``(o [CHUNK, Dv], next state)``.
-    Matmul operands are cast to ``mxu``; sums, decays and the state are
-    float32."""
-    f32 = jnp.float32
-    q, k, kb, vb = ([x.astype(f32) for x in xs] for xs in (q, k, kb, vb))
+def _unit_lower_inverse_fwd(chunks):
+    ts = _unit_lower_inverse(chunks)
+    return ts, ts
+
+
+def _unit_lower_inverse_bwd(ts, cts):
+    return (tuple(-_dot32(t, _dot32(ct, t, _NT), _TN) for t, ct in zip(ts, cts)),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _scores(q, k, kb, gc, mxu):
+    """The half of a chunk that needs no state: ``(A, tril(Q K^T exp(G_r -
+    G_i)))`` of the module docstring, ``[CHUNK, CHUNK]`` each, the first
+    strictly lower-triangular. ``q, k, kb, gc``: lists of the chunk's
+    ``SUB``-row sub-blocks ``[SUB, Dk]``, float32 (``kb = beta k``, ``gc`` the
+    log-decay summed from the chunk's first row). Matmul operands are cast to
+    ``mxu``."""
     n = len(q)
-    last = jax.lax.broadcasted_iota(jnp.int32, (SUB, 1), 0) == SUB - 1
-    g_end = jnp.sum(jnp.where(last, gc[-1], 0.0), axis=0, keepdims=True)   # [1, Dk]
     zeros = jnp.zeros_like(k[0])
     akk, aqk = [], []
     for a in range(n):
@@ -153,9 +189,17 @@ def _chunk(q, k, kb, vb, gc, state, mxu):
     c = akk.shape[0]
     r = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    t = _unit_lower_inverse(jnp.where(r > col, akk, 0.0))
-    aqk = jnp.where(r >= col, aqk, 0.0)
+    return jnp.where(r > col, akk, 0.0), jnp.where(r >= col, aqk, 0.0)
 
+
+def _chunk(t, aqk, q, k, kb, vb, gc, state, mxu):
+    """The half of a chunk that waits for the state: ``t = (I + A)^-1`` and
+    ``aqk`` from :func:`_scores`, the sub-blocks as there with ``vb = beta v
+    [SUB, Dv]``; ``state [Dv, Dk]`` float32, the transpose of ``S``. Returns
+    ``(o [CHUNK, Dv], next state)``. Matmul operands are cast to ``mxu``;
+    sums, decays and the state are float32."""
+    last = jax.lax.broadcasted_iota(jnp.int32, (SUB, 1), 0) == SUB - 1
+    g_end = jnp.sum(jnp.where(last, gc[-1], 0.0), axis=0, keepdims=True)   # [1, Dk]
     g_all = jnp.concatenate(gc, axis=0)
     decay = jnp.exp(g_all)
     kbg = jnp.concatenate(kb, axis=0) * decay
@@ -196,7 +240,10 @@ def _mixed(x, taps, halo):
 
 
 def block_step(subs, state, head, *, mxu, eps, conv=None):
-    """A block of chunks, one after another, of head ``head``.
+    """A block of chunks of head ``head``: first of every chunk what needs no
+    state (:func:`_scores`) and the triangles' inverses, all chunks' at once
+    (:func:`_unit_lower_inverse`), then the chunks one after another through
+    the state (:func:`_chunk`).
     ``subs = (q, k, v, g, beta)``, each the tuple of the block's ``SUB``-row
     sub-blocks in order, as :func:`kda` takes them: ``q, k [SUB, Dk]`` and
     ``v [SUB, Dv]`` not normalised, ``g [SUB, Dk]`` the log-decay a token,
@@ -216,7 +263,7 @@ def block_step(subs, state, head, *, mxu, eps, conv=None):
     dk = q[0].shape[-1]
     mine = jax.lax.broadcasted_iota(jnp.int32, beta[0].shape, 1) == head
     per = CHUNK // SUB
-    outs = []
+    triangles, rest = [], []          # what needs no state, of every chunk first
     for c in range(len(q) // per):
         qs, ks, kb, vb, gc = [], [], [], [], []
         before = 0.0                  # the chunk's log-decay before this sub-block
@@ -229,7 +276,12 @@ def block_step(subs, state, head, *, mxu, eps, conv=None):
             vb.append(b_i * v[i].astype(f32))
             gc.append(_running_sum(g[i]) + before)
             before = before + jnp.sum(g[i], axis=0, keepdims=True)
-        o, state = _chunk(qs, ks, kb, vb, gc, state, mxu)
+        akk, aqk = _scores(qs, ks, kb, gc, mxu)
+        triangles.append(akk)
+        rest.append((aqk, qs, ks, kb, vb, gc))
+    outs = []
+    for t, operands in zip(_unit_lower_inverse(tuple(triangles)), rest):
+        o, state = _chunk(t, *operands, state, mxu)
         outs.append(o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps))
     return tuple(outs), state
 

@@ -28,11 +28,13 @@ mixed.
   block it reruns :func:`block_step` from the kept state and pulls the output's
   and the later blocks' cotangents back through it (``jax.vjp`` inside the
   kernel body: the backward is the transpose of the very algebra the forward
-  ran), carrying the state's cotangent in scratch. Heads are a ``parallel``
-  grid axis, so no two of them may write one ``[rows, H]`` block of
-  ``dbeta``: each head writes lane-dense rows ``[B, H, S / rows, 1, rows]`` (as
-  flash attention lays out ``lse``; a block's last two axes are the array's
-  whole ones at any length), transposed outside: 1 MB. With ``conv`` the
+  ran, but for the chunk's triangular inverse, whose ``custom_vjp`` pulls
+  ``-T^T dT T^T`` back in two products where the transpose of its series
+  would take twenty), carrying the state's cotangent in scratch. Heads are a
+  ``parallel`` grid axis, so no two of them may write one ``[rows, H]`` block
+  of ``dbeta``: each head writes lane-dense rows ``[B, H, S / rows, 1, rows]``
+  (as flash attention lays out ``lse``; a block's last two axes are the
+  array's whole ones at any length), transposed outside: 1 MB. With ``conv`` the
   cotangent of the rows before a block belongs to the block walked next: it
   waits in scratch (float32, ``[SUB, D]`` an operand) and is added to that
   block's last rows before they are written; and the taps' gradients sum up

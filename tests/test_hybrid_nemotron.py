@@ -306,8 +306,10 @@ def _paths(tree, dtypes=True):
 # sha256 of the parameter tree's (path, shape, dtype) list and of the jaxpr of
 # the loss's value and gradient (bf16, remat, matmul precision "highest" as
 # tests/conftest.py sets it), made by this test's own lines on an unpacked
-# ``git archive`` of the PARENT commit (cb2cd98, PR 31)
-PARENT = {"tree": "c53b307284d821a5", "step": "af53ce61cfd5052e",
+# ``git archive`` of the PARENT commit (cb2cd98, PR 31); "step" again on PR 33's
+# tree, whose KDA inverts a block's triangles side by side in six products each
+# and pulls back through them in closed form (af53ce61cfd5052e before)
+PARENT = {"tree": "c53b307284d821a5", "step": "540a2eb4e1075bfc",
           "layer_tree": "e6935d7c7d3bcc3c", "layer": "500b56275c2b57ea"}
 
 

@@ -236,3 +236,155 @@ def test_the_decays_running_sum_is_exact_to_float32(reverse):
 def test_block_rows_take_the_most_chunks_that_divide():
     assert LA.block_rows(8192) == 256 and LA.block_rows(320) == 64
     assert LA.block_rows(384) == 192 and LA.block_rows(64) == 64
+
+
+# -- the chunk's triangular inverse --------------------------------------------------
+
+SCALES = {"entries_0.05": 0.05, "entries_0.6": 0.6}
+
+
+def strictly_lower(seed, scale, c=LA.CHUNK):
+    return jnp.tril(scale * jax.random.normal(jax.random.PRNGKey(seed), (c, c)), -1)
+
+
+def inverse(a):
+    """The program's, of one chunk."""
+    (t,) = LA._unit_lower_inverse((a,))
+    return t
+
+
+def ten_product_series(a):
+    """What the program ran before PR 33, differentiated as it stands: five
+    rounds of ``p = p p; t = t + t p``, and backward the transpose of each."""
+    t, p, span = jnp.eye(a.shape[0], dtype=a.dtype) - a, a, 2
+    while span < a.shape[0]:
+        p = LA._dot32(p, p)
+        t = t + LA._dot32(t, p)
+        span *= 2
+    return t
+
+
+def pulled_through_the_mask(fn, a, ct):
+    """The cotangent as :func:`_chunk` gets it: through the ``where`` that keeps
+    the strict lower triangle."""
+    return jax.grad(lambda x: jnp.sum(fn(jnp.tril(x, -1)) * ct))(a)
+
+
+@pytest.mark.parametrize("scale", sorted(SCALES))
+def test_the_inverse_and_its_cotangent_against_float64(scale):
+    a = strictly_lower(31, SCALES[scale])
+    ct = jax.random.normal(jax.random.PRNGKey(33), a.shape)
+    a64, ct64 = np.asarray(a, np.float64), np.asarray(ct, np.float64)
+    want = np.linalg.inv(np.eye(a.shape[0]) + a64)
+    if scale == "entries_0.6":         # the series cut a round short would not pass
+        cut = np.linalg.matrix_power(a64, 32) @ want
+        assert np.max(np.abs(cut)) > 10 * 2e-6 * np.max(np.abs(want))
+    got = inverse(a)
+    assert got.dtype == jnp.float32
+    close(got, want, 2e-6)
+    pulled = jax.grad(lambda x: jnp.sum(inverse(x) * ct))(a)
+    close(pulled, -want.T @ ct64 @ want.T, 2e-6)
+
+
+@pytest.mark.parametrize("scale", sorted(SCALES))
+def test_six_products_are_the_ten_product_series(scale):
+    a = strictly_lower(35, SCALES[scale])
+    ct = jax.random.normal(jax.random.PRNGKey(37), a.shape)
+    close(inverse(a), ten_product_series(a), 2e-6)
+    got = pulled_through_the_mask(inverse, a, ct)
+    assert float(jnp.max(jnp.abs(jnp.triu(got)))) == 0.0
+    close(got, pulled_through_the_mask(ten_product_series, a, ct), 2e-6)
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.tree.leaves(eqn.params, is_leaf=lambda x: hasattr(x, "eqns") or hasattr(
+                x, "jaxpr")):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from equations(sub)
+
+
+def products(jaxpr):
+    return [e for e in equations(jaxpr) if e.primitive.name == "dot_general"]
+
+
+def float32_at_highest(eqn):
+    precision = eqn.params["precision"]
+    precision = precision if isinstance(precision, tuple) else (precision,) * 2
+    return (all(p == jax.lax.Precision.HIGHEST for p in precision)
+            and all(v.aval.dtype == jnp.float32 for v in (*eqn.invars, *eqn.outvars)))
+
+
+def test_the_inverse_is_six_products_and_its_cotangent_two():
+    """A count on the jaxpr, so that neither half can fall back to the series
+    and its transpose unseen: six ``[C, C] x [C, 2C]`` forward (``a [a | I]``,
+    four rounds of ``p [p | t]`` and the last update); two backward, and a
+    ``vjp`` holds the eight and no more."""
+    a, c = strictly_lower(39, 0.3), LA.CHUNK
+    forward = products(jax.make_jaxpr(inverse)(a).jaxpr)
+    assert [e.outvars[0].aval.shape for e in forward] == [(c, 2 * c)] * 6
+    cotangent = products(jax.make_jaxpr(LA._unit_lower_inverse_bwd)((a,), (a,)).jaxpr)
+    assert [e.outvars[0].aval.shape for e in cotangent] == [(c, c)] * 2
+    # the transposing is the contraction's: no product's operand is a transpose
+    assert [e.params["dimension_numbers"] for e in cotangent] == [LA._NT, LA._TN]
+    both = products(jax.make_jaxpr(lambda a, ct: jax.vjp(inverse, a)[1](ct))(a, a).jaxpr)
+    assert len(both) == 8
+    assert all(float32_at_highest(e) for e in forward + cotangent + both)
+
+
+def test_the_chunks_chains_are_issued_link_by_link():
+    """Three chunks' inverses at once: every chain's first product, then every
+    chain's second, and so on (a chain after a chain costs the kernels a fifth
+    of a forward launch: the compiler keeps the order it is given); each is
+    the inverse of its own chunk, and so is each cotangent."""
+    chunks = tuple(strictly_lower(seed, 0.3) for seed in (45, 47, 49))
+    c, n = LA.CHUNK, len(chunks)
+    forward = products(jax.make_jaxpr(LA._unit_lower_inverse)(chunks).jaxpr)
+    assert [e.outvars[0].aval.shape for e in forward] == [(c, 2 * c)] * 6 * n
+    for i in range(0, 6 * n, n):                    # a link's left operands: one chain each
+        assert len({e.invars[0] for e in forward[i:i + n]}) == n
+    cts = tuple(jax.random.normal(jax.random.PRNGKey(51 + i), (c, c)) for i in range(n))
+    got, pull = jax.vjp(LA._unit_lower_inverse, chunks)
+    for a, t, ct, pulled in zip(chunks, got, cts, pull(cts)[0]):
+        close(t, inverse(a), 0.0)
+        close(pulled, jax.vjp(inverse, a)[1](ct)[0], 0.0)
+
+
+def test_inside_the_kernels_the_inverse_is_the_custom_vjp(monkeypatch):
+    """``[1, 256, 2 * 128]`` through the kernels in the Pallas interpreter: the
+    backward's body, ``jax.vjp`` of the block, holds a chunk's six products and
+    the cotangent's two (the series and its transpose would be thirty), and
+    outputs and all five gradients are those of the same kernels with the
+    ten-product series under them."""
+    from pyspark_tf_gke_tpu.ops.pallas import kda as K
+
+    args = inputs(41, s=256)
+    w = jax.random.normal(jax.random.PRNGKey(43), args[2].shape)
+    run = lambda: (kda(*args, pallas=True, interpret=True),) + jax.grad(
+        lambda *a: jnp.sum(kda(*a, pallas=True, interpret=True) * w), argnums=range(5))(*args)
+
+    def inverse_products(fn, *more):
+        kw = dict(heads=2, eps=EPS, mxu=jnp.dtype("float32"), interpret=True, caller="")
+        traced = jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args, None, *more)
+        (call,) = [e for e in equations(traced.jaxpr) if e.primitive.name == "pallas_call"]
+        return [e for e in products(call.params["jaxpr"])
+                if float32_at_highest(e) and e.invars[0].aval.shape == (LA.CHUNK,) * 2]
+
+    chunks = 256 // LA.CHUNK
+    assert len(inverse_products(K._forward)) == 6 * chunks
+    kept = jnp.zeros((1, 2, 1, 128, 128), jnp.float32)
+    assert len(inverse_products(K._backward, kept, w)) == 8 * chunks
+    try:
+        with monkeypatch.context() as patch:
+            patch.setattr(LA, "_unit_lower_inverse",
+                          lambda chunks: tuple(ten_product_series(a) for a in chunks))
+            jax.clear_caches()                  # the launches are jitted: trace them anew
+            assert len(inverse_products(K._backward, kept, w)) == 30 * chunks
+            want = run()
+    finally:
+        jax.clear_caches()
+    for g, r in zip(run(), want):               # o dq dk dv dg dbeta
+        close(g, r, 1e-5)
