@@ -1,14 +1,20 @@
 """A decoder whose layers differ in kind: per layer an attention kind
 (``kda`` linear attention, ``mla`` latent attention without positions,
 ``mamba2`` a state-space mixer, ``gqa`` grouped-query attention without
-positions, or ``none``) and an FFN kind (``dense`` SwiGLU, ``experts``, one
-chip's share of a dropless expert layer, or ``none``); a layer has one of the
-two or both, each behind a pre-norm of its own. Written for two families: the
+positions, ``gated_sliding`` / ``gated_full`` gated grouped-query attention
+inside a window with rotary positions / global without positions, or
+``none``) and an FFN kind (``dense`` SwiGLU, ``experts``, one chip's share of a
+dropless expert layer, or ``none``); a layer has one of the two or both, each
+behind a pre-norm of its own (and, with ``sandwich_norms``, a norm of its own
+after it, before the residual sum). Written for three families: the
 Kimi-Linear one (``moonshotai/Kimi-Linear-48B-A3B-Instruct``: every layer
-attention then FFN) and Nemotron-H (``nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B``,
+attention then FFN), Nemotron-H (``nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B``,
 ``model_type`` ``nemotron_h``: every layer one mixer, ``mamba2``, ``gqa`` or
-``experts`` by the letter of ``hybrid_override_pattern``). All norms RMSNorm,
-no embedding scale, untied head, no bias but the Mamba-2 convolution's.
+``experts`` by the letter of ``hybrid_override_pattern``) and AFMoE
+(``arcee-ai/Trinity-Mini``, ``model_type`` ``afmoe``: every layer attention
+then FFN, ``gated_sliding`` or ``gated_full`` by ``layer_types``, sandwich
+norms, the embedding times ``sqrt(hidden_size)``). All norms RMSNorm, untied
+head, no bias but the Mamba-2 convolution's.
 
 * **KDA** (``KDAAttention``): ``q, k = L2norm(silu(conv4(x W)))``, ``v =
   silu(conv4(x W_v))`` with a depthwise causal convolution of 4 taps; a
@@ -36,6 +42,14 @@ no embedding scale, untied head, no bias but the Mamba-2 convolution's.
 * **GQA without positions** (``GQAAttention``): 32 query heads of 128 on 2
   key-value heads, repeated to 32 before the flash kernels; no rotary
   embedding (the published ``nemotron_h`` applies none).
+* **Gated GQA, in a window or global** (``GatedAttention``): 32 query heads of
+  128 on 4 key-value heads; ``q`` and ``k`` RMS-normed a head (a learned scale
+  of 128 each); on a ``gated_sliding`` layer rotated (``causal_lm.py::
+  apply_rope``, the half-rotation convention, all 128 columns) and confined to
+  ``sliding_window`` keys, the row's own among them, which the flash kernels
+  take as their ``window`` (launches named ``window_flash_*``); on a
+  ``gated_full`` layer neither; the heads' outputs times ``sigmoid(x W_g)``
+  before the output projection.
 * **Experts**: ``models/moe.py::HeldExpertsLayer`` (``swiglu`` experts for
   Kimi-Linear, ``relu2`` ones with a shared expert of its own width for
   Nemotron-H); its counters are sown into the ``counters`` collection and
@@ -52,6 +66,7 @@ state inside a row). The call contract is ``CausalLM``'s, so
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 from typing import Any, Optional, Tuple
@@ -64,7 +79,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from pyspark_tf_gke_tpu.models.bert import (_data_shards, _dense,
                                             resolve_use_flash)
-from pyspark_tf_gke_tpu.models.causal_lm import RMSNorm
+from pyspark_tf_gke_tpu.models.causal_lm import RMSNorm, apply_rope
 from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
 from pyspark_tf_gke_tpu.models.moe import HeldExpertsLayer, SwiGLU
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
@@ -73,8 +88,12 @@ from pyspark_tf_gke_tpu.ops.state_space import ssd
 
 NOT_SERVED = ("HybridLM has no decode or prefill path, whatever its layers' kinds: the "
               "engine's cache holds neither latent KV pages nor a per-slot recurrent "
-              "state, KDA's or the state-space scan's (ROADMAP Reach 3 and 4)")
-ATTENTION_KINDS = ("kda", "mla", "mamba2", "gqa", "none")
+              "state, KDA's or the state-space scan's (ROADMAP Reach 3 and 4), and its "
+              "page allocator knows one kind of layer, not window layers beside global "
+              "ones (Reach 2)")
+# kimi_linear files give kda / mla, nemotron_h files mamba2 / gqa / none, afmoe
+# files gated_sliding / gated_full (``config_from_file``)
+ATTENTION_KINDS = ("kda", "mla", "mamba2", "gqa", "gated_sliding", "gated_full", "none")
 FFN_KINDS = ("dense", "experts", "none")
 
 
@@ -101,8 +120,10 @@ class HybridLMConfig:
     time_step_floor: float = 1e-4
     # MLA and GQA
     num_heads: int = 32
-    kv_heads: int = 2                     # GQA
-    head_dim: int = 128                   # GQA
+    kv_heads: int = 2                     # GQA, gated or not
+    head_dim: int = 128                   # GQA, gated or not
+    sliding_window: int = 2048            # gated_sliding: keys a row sees, its own among them
+    rope_theta: float = 10000.0           # gated_sliding
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -117,6 +138,8 @@ class HybridLMConfig:
     shared_intermediate_size: int = 0     # 0 = shared_experts routed widths
     expert_activation: str = "swiglu"     # "swiglu" | "relu2"
     route_scale: float = 2.446
+    sandwich_norms: bool = False          # a norm after each mixer and FFN too
+    scale_embedding: bool = False         # the embedding times sqrt(hidden_size)
     layer_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = False
@@ -143,8 +166,9 @@ def config_from_file(path_or_dict, dtype=jnp.bfloat16,
                      remat: bool = False) -> HybridLMConfig:
     """A :class:`HybridLMConfig` from a configuration file with its family's
     published keys, the family by ``model_type``: ``kimi_linear``
-    (``benchmark/configs/kimi-linear-48b-a3b.json``) or ``nemotron_h``
-    (``benchmark/configs/nemotron-3-nano-30b-a3b.json``). The key that counts
+    (``benchmark/configs/kimi-linear-48b-a3b.json``), ``nemotron_h``
+    (``benchmark/configs/nemotron-3-nano-30b-a3b.json``) or ``afmoe``
+    (``benchmark/configs/trinity-mini.json``). The key that counts
     the routed experts (``num_experts`` / ``n_routed_experts``) there counts
     those held on this chip, and ``published`` has the router's width."""
     c = path_or_dict
@@ -153,6 +177,8 @@ def config_from_file(path_or_dict, dtype=jnp.bfloat16,
             c = json.load(f)
     if c.get("model_type") == "nemotron_h":
         return _nemotron_h_config(c, dtype, remat)
+    if c.get("model_type") == "afmoe":
+        return _afmoe_config(c, dtype, remat)
     lin = c["linear_attn_config"]
     layers = range(1, c["num_hidden_layers"] + 1)
     bad = [n for n in layers if (n in lin["kda_layers"]) == (n in lin["full_attn_layers"])]
@@ -210,6 +236,40 @@ def _nemotron_h_config(c: dict, dtype, remat: bool) -> HybridLMConfig:
         expert_activation="relu2",
         route_scale=float(c["routed_scaling_factor"]),
         layer_norm_eps=float(c["layer_norm_epsilon"]), dtype=dtype, remat=remat)
+
+
+def _afmoe_config(c: dict, dtype, remat: bool) -> HybridLMConfig:
+    """``layer_types`` names each layer's attention (``sliding_attention`` /
+    ``full_attention``); the first ``num_dense_layers`` have a dense FFN, the
+    others experts."""
+    kinds = {"sliding_attention": "gated_sliding", "full_attention": "gated_full"}
+    types = c["layer_types"]
+    if len(types) != c["num_hidden_layers"] or set(types) - set(kinds):
+        raise ValueError(f"layer_types {types!r} does not name {c['num_hidden_layers']} "
+                         f"layers by {sorted(kinds)}")
+    if c["score_func"] != "sigmoid" or not c["route_norm"] or c["hidden_act"] != "silu":
+        raise ValueError("afmoe experts are SwiGLU behind a sigmoid router with "
+                         "renormalised weights here (score_func, route_norm, hidden_act)")
+    if c["n_group"] != 1 or c["topk_group"] != 1:
+        raise ValueError("afmoe routing is not grouped here (n_group, topk_group)")
+    return HybridLMConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        attention=tuple(kinds[t] for t in types),
+        ffn=tuple("dense" if n < c["num_dense_layers"] else "experts"
+                  for n in range(len(types))),
+        num_heads=c["num_attention_heads"], kv_heads=c["num_key_value_heads"],
+        head_dim=c["head_dim"], sliding_window=c["sliding_window"],
+        rope_theta=float(c["rope_theta"]),
+        intermediate_size=c["intermediate_size"],
+        expert_intermediate_size=c["moe_intermediate_size"],
+        num_experts=c.get("published", {}).get("num_experts", c["num_experts"]),
+        experts_held=(c.get("deployment", {}).get("experts_held_first", 0),
+                      c["num_experts"]),
+        experts_per_token=c["num_experts_per_tok"],
+        shared_experts=c["num_shared_experts"],
+        route_scale=float(c["route_scale"]),
+        sandwich_norms=True, scale_embedding=bool(c["mup_enabled"]),
+        layer_norm_eps=float(c["rms_norm_eps"]), dtype=dtype, remat=remat)
 
 
 def _per_shard(fn, mesh: Optional[Mesh], *specs):
@@ -332,19 +392,21 @@ class MLAAttention(nn.Module):
         return _flash_or_dense(self.cfg, self.mesh, q, k, v)
 
 
-def _flash_or_dense(cfg, mesh, q, k, v):
-    """Causal softmax attention of ``q, k, v [B, S, H, D]``: the flash kernels
-    where ``resolve_use_flash`` says so (per shard under a mesh), else dense.
-    Called from a module's ``_causal_attend``, whose name the launches carry."""
+def _flash_or_dense(cfg, mesh, q, k, v, window=None):
+    """Causal softmax attention of ``q, k, v [B, S, H, D]``, inside ``window``
+    keys where one is given: the flash kernels where ``resolve_use_flash`` says
+    so (per shard under a mesh), else dense. Called from a module's
+    ``_causal_attend``, whose name the launches carry."""
     from pyspark_tf_gke_tpu.parallel.mesh import DATA_AXES
 
     if not resolve_use_flash(cfg, q.shape[1]):
-        return dot_product_attention(q, k, v, causal=True)
+        return dot_product_attention(q, k, v, causal=True, window=window)
     from pyspark_tf_gke_tpu.ops.pallas.flash_attention import flash_attention
 
     spec = P(DATA_AXES, None, "tp", None)
-    return _per_shard(lambda qq, kk, vv: flash_attention(qq, kk, vv, causal=True),
-                      mesh, spec, spec, spec)(q, k, v)
+    return _per_shard(
+        lambda qq, kk, vv: flash_attention(qq, kk, vv, causal=True, window=window),
+        mesh, spec, spec, spec)(q, k, v)
 
 
 class GQAAttention(nn.Module):
@@ -371,6 +433,47 @@ class GQAAttention(nn.Module):
 
     def _causal_attend(self, q, k, v):
         return _flash_or_dense(self.cfg, self.mesh, q, k, v)
+
+
+class GatedAttention(nn.Module):
+    """AFMoE's attention (module docstring): ``sliding`` layers rotate ``q``
+    and ``k`` and see ``cfg.sliding_window`` keys, the others have no position
+    signal and see every key before them."""
+
+    cfg: HybridLMConfig
+    mesh: Optional[Mesh] = None
+    sliding: bool = False
+
+    @nn.compact
+    def __call__(self, hidden):
+        cfg = self.cfg
+        b, s, _ = hidden.shape
+        heads, kv_heads, dim = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+
+        def dense(features, name):
+            return _dense(features, ("embed", "mlp"), cfg, name=name, use_bias=False)
+
+        def head_norm(name):
+            return RMSNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name=name)
+
+        q = head_norm("q_norm")(dense(heads * dim, "q_proj")(hidden).reshape(b, s, heads, dim))
+        k = head_norm("k_norm")(dense(kv_heads * dim, "k_proj")(hidden).reshape(
+            b, s, kv_heads, dim))
+        v = dense(kv_heads * dim, "v_proj")(hidden).reshape(b, s, kv_heads, dim)
+        gate = dense(heads * dim, "gate_proj")(hidden)
+        if self.sliding:
+            positions = jnp.arange(s)[None]
+            q, k = (apply_rope(x, positions, cfg.rope_theta) for x in (q, k))
+        # the training pass: K and V repeated to the query heads (``GQAAttention``)
+        k, v = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (k, v))
+        out = self._causal_attend(q, k, v).reshape(b, s, heads * dim)
+        out = out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return _dense(cfg.hidden_size, ("mlp", "embed"), cfg, name="o_proj",
+                      use_bias=False)(out.astype(cfg.dtype))
+
+    def _causal_attend(self, q, k, v):
+        return _flash_or_dense(self.cfg, self.mesh, q, k, v,
+                               window=self.cfg.sliding_window if self.sliding else None)
 
 
 def _log_uniform_a(key, shape, dtype=jnp.float32):
@@ -438,7 +541,8 @@ class Mamba2Mixer(nn.Module):
 
 
 MIXERS = {"kda": KDAAttention, "mla": MLAAttention, "mamba2": Mamba2Mixer,
-          "gqa": GQAAttention}
+          "gqa": GQAAttention, "gated_full": GatedAttention,
+          "gated_sliding": functools.partial(GatedAttention, sliding=True)}
 
 
 class HybridBlock(nn.Module):
@@ -451,15 +555,20 @@ class HybridBlock(nn.Module):
         cfg = self.cfg
         norm = lambda name: RMSNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype, name=name)
         kind, ffn = cfg.attention[self.layer], cfg.ffn[self.layer]
+
+        def after(name, out):
+            # with sandwich norms, what a mixer or an FFN gives is normed before the sum
+            return norm(name)(out) if cfg.sandwich_norms else out
+
         if kind != "none":
-            hidden = hidden + MIXERS[kind](cfg, self.mesh, name="attention")(
-                norm("ln_attn")(hidden))
+            hidden = hidden + after("ln_post_attn", MIXERS[kind](
+                cfg, self.mesh, name="attention")(norm("ln_attn")(hidden)))
         if ffn == "none":
             return hidden
         m = norm("ln_mlp")(hidden)
         if ffn == "dense":
-            return hidden + SwiGLU(cfg.hidden_size, cfg.intermediate_size, cfg.dtype,
-                                   name="mlp")(m)
+            return hidden + after("ln_post_mlp", SwiGLU(
+                cfg.hidden_size, cfg.intermediate_size, cfg.dtype, name="mlp")(m))
         out, counters = HeldExpertsLayer(
             num_experts=cfg.num_experts, held=cfg.experts_held,
             top_k=cfg.experts_per_token, hidden_size=cfg.hidden_size,
@@ -469,7 +578,7 @@ class HybridBlock(nn.Module):
             shared_width=cfg.shared_intermediate_size, name="mlp")(m)
         for name, value in counters.items():
             self.sow("counters", name, value)
-        return hidden + out
+        return hidden + after("ln_post_mlp", out)
 
 
 class HybridLM(nn.Module):
@@ -500,6 +609,8 @@ class HybridLM(nn.Module):
             embedding_init=nn.with_logical_partitioning(
                 nn.initializers.normal(stddev=0.02), ("vocab", "embed")),
             name="wte")(input_ids, one_hot=train)
+        if cfg.scale_embedding:
+            hidden = (hidden.astype(jnp.float32) * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
         block_cls = nn.remat(HybridBlock) if cfg.remat else HybridBlock
         for i in range(cfg.num_layers):
             hidden = block_cls(cfg, self.mesh, i, name=f"layer_{i}")(hidden)

@@ -40,13 +40,20 @@ def dot_product_attention(
     v: jnp.ndarray,  # [B, Sk, H, D]
     mask: Optional[jnp.ndarray] = None,  # broadcastable to [B, H, Sq, Sk]
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Standard attention in float32 accumulation, bf16-friendly inputs."""
+    """Standard attention in float32 accumulation, bf16-friendly inputs.
+    ``window`` (causal only) confines a row to its own position and the
+    ``window - 1`` before it, as ``flash_attention``'s does."""
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     if causal:
         sq, sk = q.shape[1], k.shape[1]
         cm = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window is not None and window < sk:
+            cm &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq - window)
         scores = jnp.where(cm[None, None], scores, NEG_INF)
     if mask is not None:
         scores = jnp.where(mask, scores, NEG_INF)

@@ -14,7 +14,7 @@ is no bias operand at all. Optional ``[BH, 1, S]`` segment ids confine
 attention within a packed sequence.
 
 **The schedule** (``_schedule``, a pure function of ``(S, block_q, block_k,
-causal)``; the three kernels take every loop bound from it through
+causal, window)``; the three kernels take every loop bound from it through
 ``_sweep``). The grid block is ``block_q`` rows (``block_k`` keys in dK/dV).
 Seen from a grid block, the other axis falls into three kinds of *tile*:
 
@@ -30,6 +30,34 @@ Seen from a grid block, the other axis falls into three kinds of *tile*:
   through the mask, a triangle that is the same for every diagonal tile and
   is built once a grid step; the strip's other tiles are full, and the
   tiles above the diagonal inside the square are skipped.
+
+With a **window** (``window`` keys a row: its own position and the
+``window - 1`` before it; causal only) the walk starts at the window's far
+edge instead of the sequence's start::
+
+    keys ->      far edge                     diagonal
+    . . . . . | % # # # | # # # # | # # # # | % . . . |      . skipped
+    . . . . . | . % # # | # # # # | # # # # | # % . . |      # full
+    . . . . . | . . % # | # # # # | # # # # | # # % . |      % masked tile
+    . . . . . | . . . % | # # # # | # # # # | # # # % |
+      skipped   edge        full steps          diagonal
+                strips                          strips
+
+Tiles wholly behind the window are skipped like those above the diagonal,
+tiles wholly inside it are full, and where the window is a multiple of the
+grid block the far edge gets the mirror of the diagonal's treatment: the
+*edge square* (the block against the positions ``window`` before its own, or
+after them in dK/dV) is taken in strips of which only the edge tile is
+masked, by the complement of the diagonal's triangle. A grid block whose
+edge square lies off the sequence (the first ``window / block`` blocks of
+rows, the last ones of keys) has none: one ``lax.cond`` a grid step. A
+window that is no multiple of the block (``_Schedule.mirrored`` false)
+still skips what lies wholly behind it, and passes every tile it computes
+through a mask of positions (``band``): right for any window, and slower.
+That path is kept for windows shorter than a block (windows of 128 keys are
+published, a quarter of the default block of 512); no cell runs it yet: it
+has run in interpret mode and compiled for a v5e (the tests), not on a chip.
+A window of the sequence's length or more is no window.
 
 Not causal, every tile is full and there is no square. Where a block has
 no 128-multiple divisor (the tests' 16- and 32-wide blocks, a sequence
@@ -107,18 +135,34 @@ class _Schedule(NamedTuple):
     tile: int         # side of a tile; a sub-block's extent along the grid axis
     causal: bool
     walks_rows: bool  # dK/dV: the visible side of the walk axis is the far one
+    window: Optional[int] = None  # keys a row sees, its own among them; under s
+
+    @property
+    def mirrored(self) -> bool:
+        """The window's far edge is taken as the diagonal is, in strips."""
+        return self.window is not None and self.window % self.block == 0
 
     def full_steps(self, i):
-        """Half-open range of walk steps wholly visible to grid block ``i``
-        (a Python or a traced integer)."""
-        per_block = self.block // self.walk
+        """Half-open range of the walk steps grid block ``i`` (a Python or a
+        traced integer) takes whole: wholly visible, or with a window that is
+        not ``mirrored`` not wholly behind it."""
+        per_block, steps = self.block // self.walk, self.s // self.walk
         if not self.causal:
-            return 0, self.s // self.walk
+            return 0, steps
         if self.block == self.s:
             return 0, 0
-        if self.walks_rows:
-            return (i + 1) * per_block, self.s // self.walk
-        return 0, i * per_block
+        lo, hi = ((i + 1) * per_block, steps) if self.walks_rows else (0, i * per_block)
+        if self.window is None:
+            return lo, hi
+        if self.mirrored:
+            reach = self.window // self.walk
+            if self.walks_rows:
+                return lo, jnp.minimum(hi, i * per_block + reach)
+            return jnp.maximum(lo, (i + 1) * per_block - reach), hi
+        if self.walks_rows:       # the last row that sees the block's last key
+            return lo, jnp.minimum(
+                hi, (i * self.block + self.block + self.window - 2) // self.walk + 1)
+        return jnp.maximum(i * self.block - self.window + 1, 0) // self.walk, hi
 
     def sub_blocks(self):
         """``(first, size)`` of the block's sub-blocks along the grid axis."""
@@ -137,37 +181,78 @@ class _Schedule(NamedTuple):
                     for first, _ in self.sub_blocks()]
         return [(0, first + size) for first, size in self.sub_blocks()]
 
+    def edge_strips(self):
+        """The edge square's strips (``mirrored``), as :meth:`strips` gives the
+        diagonal square's, from the edge square's corner: the mirror image,
+        the edge tile the strip's first (its last, where the kernel walks
+        rows)."""
+        if self.walks_rows:
+            return [(0, first + size) for first, size in self.sub_blocks()]
+        return [(first, self.block - first) for first, _ in self.sub_blocks()]
+
+    def has_edge(self, i):
+        """Whether grid block ``i``'s edge square lies on the sequence."""
+        reach = self.window // self.block
+        return i < self.s // self.block - reach if self.walks_rows else i >= reach
+
     def counts(self):
         """(computed, through the mask, thrown away) score elements a head."""
         if not self.causal:
             return self.s * self.s, 0, 0
         blocks, t = self.s // self.block, self.tile
         diagonal_tiles = blocks * len(self.sub_blocks())
-        full = self.block ** 2 * blocks * (blocks - 1) // 2
         square = t * sum(width for _, width in self.strips())
-        return (full + blocks * square, diagonal_tiles * t * t,
-                diagonal_tiles * t * (t - 1) // 2)
+        if self.window is None:
+            full = self.block ** 2 * blocks * (blocks - 1) // 2
+            return (full + blocks * square, diagonal_tiles * t * t,
+                    diagonal_tiles * t * (t - 1) // 2)
+        steps = [int(hi) - int(lo) for lo, hi in map(self.full_steps, range(blocks))]
+        full = self.block * self.walk * sum(n for n in steps if n > 0)
+        if self.mirrored:
+            edges = sum(bool(self.has_edge(i)) for i in range(blocks))
+            masked = (diagonal_tiles + edges * len(self.sub_blocks())) * t * t
+            # an edge tile keeps what the diagonal tile throws away
+            return (full + (blocks + edges) * square, masked,
+                    diagonal_tiles * t * (t - 1) // 2
+                    + edges * len(self.sub_blocks()) * t * (t + 1) // 2)
+        computed = full + blocks * square
+        rows = np.arange(self.s)
+        visible = int(np.sum(np.minimum(rows + 1, self.window)))
+        return computed, computed, computed - visible
 
 
 def _schedule(s: int, block_q: int, block_k: int, causal: bool,
-              walks_rows: bool = False) -> _Schedule:
+              walks_rows: bool = False, window: Optional[int] = None) -> _Schedule:
     block, other = (block_k, block_q) if walks_rows else (block_q, block_k)
     walk = pick_block(math.gcd(block, other) if causal else other, WALK, 128)
     return _Schedule(s, block, walk, pick_block(block, TILE, 128), causal,
-                     walks_rows)
+                     walks_rows, window)
 
 
 def _sweep(sched: _Schedule, i, carry, piece):
     """Everything grid block ``i`` computes: ``piece(carry, sub, start,
-    width, tri)`` updates the carry (a tuple of arrays whose first axis is
-    the block's) of the block's positions ``sub`` from ``width`` positions
+    width, mask, band)`` updates the carry (a tuple of arrays whose first axis
+    is the block's) of the block's positions ``sub`` from ``width`` positions
     of the walk axis at ``start``. The full steps take the whole block; then
-    each strip of the diagonal square takes its sub-block's slice."""
+    each strip of the edge square (a ``mirrored`` window's) and of the
+    diagonal square takes its sub-block's slice. ``mask`` is ``None`` or the
+    strip's one masked tile, ``(triangle, whether it is the strip's last)``;
+    ``band`` is ``None`` or, for a window that is not ``mirrored``, what
+    :func:`_scores` masks every tile by."""
     whole = slice(0, sched.block)
+    banded = sched.window is not None and not sched.mirrored
+
+    def band(sub, start):
+        # row - key < window, with ``d`` rows and ``e`` keys past the piece's
+        # first: d - e < window - (first row - first key)
+        if not banded:
+            return None
+        ahead = i * sched.block + sub.start - start
+        return sched.window + ahead if sched.walks_rows else sched.window - ahead
 
     def step(j, carry):
-        return piece(carry, whole, pl.multiple_of(j * sched.walk, sched.walk),
-                     sched.walk, None)
+        start = pl.multiple_of(j * sched.walk, sched.walk)
+        return piece(carry, whole, start, sched.walk, None, band(whole, start))
 
     lo, hi = sched.full_steps(i)
     # a few steps with static bounds unroll into one basic block, where the
@@ -178,34 +263,53 @@ def _sweep(sched: _Schedule, i, carry, piece):
         return carry
     tri = _triangle(sched.tile, keys_first=sched.walks_rows)
     corner = pl.multiple_of(i * sched.block, sched.block)
-    strips = [
-        piece(tuple(c[first:first + size] for c in carry),
-              slice(first, first + size), corner + start, width, tri)
-        for (first, size), (start, width) in zip(sched.sub_blocks(),
-                                                 sched.strips())]
-    return tuple(jnp.concatenate(c) for c in zip(*strips))
+
+    def square(carry, origin, strips, mask):
+        done = []
+        for (first, size), (start, width) in zip(sched.sub_blocks(), strips):
+            sub = slice(first, first + size)
+            mine, at = tuple(c[sub] for c in carry), origin + start
+            done.append(piece(mine, sub, at, width, mask, band(sub, at)))
+        return tuple(jnp.concatenate(c) for c in zip(*done))
+
+    if sched.mirrored:
+        reach = sched.window if sched.walks_rows else -sched.window
+        carry = jax.lax.cond(
+            sched.has_edge(i),
+            lambda c: square(c, corner + reach, sched.edge_strips(),
+                             (~tri, sched.walks_rows)),
+            lambda c: c, carry)
+    return square(carry, corner, sched.strips(), (tri, not sched.walks_rows))
 
 
-def _scores(a, b, scale, bias, seg_a, seg_b, tri, diagonal_last):
+def _scores(a, b, scale, bias, seg_a, seg_b, mask, band=None, keys_first=False):
     """f32 scores of ``a`` [m, D] against ``b`` [n, D], masked: [m, n].
-    ``bias`` and the segment ids broadcast against [m, n]; ``tri`` [m, m]
-    masks the diagonal tile alone, the last m columns or the first."""
+    ``bias`` and the segment ids broadcast against [m, n]; ``mask`` is
+    ``(tri [t, t], last)``: ``tri`` masks one tile alone, the last t columns
+    or the first. ``band`` masks the whole piece by position: kept where
+    the row is under ``band`` positions past the key, counted from the
+    piece's first row and key (``a`` holds the keys where ``keys_first``)."""
     s = jax.lax.dot_general(a, b, _NT,
                             preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias
     if seg_a is not None:
         s = jnp.where(seg_a == seg_b, s, NEG_INF)
-    if tri is not None:
+    if mask is not None:
+        tri, last = mask
         t = tri.shape[0]
         if s.shape[1] == t:
             s = jnp.where(tri, s, NEG_INF)
-        elif diagonal_last:
+        elif last:
             s = jnp.concatenate(
                 [s[:, :-t], jnp.where(tri, s[:, -t:], NEG_INF)], axis=1)
         else:
             s = jnp.concatenate(
                 [jnp.where(tri, s[:, :t], NEG_INF), s[:, t:]], axis=1)
+    if band is not None:
+        d = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where((-d if keys_first else d) < band, s, NEG_INF)
     return s
 
 
@@ -265,7 +369,7 @@ def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
     q = q_ref[0]                                         # [bq, D]
     segq = segq_ref[0, 0][:, None] if use_segs else None  # [bq, 1]
 
-    def attend(carry, rows, start, width, tri):
+    def attend(carry, rows, start, width, mask, band):
         # online-softmax update of the block's ``rows`` with keys
         # [start, start + width)
         m, l, acc = carry
@@ -276,7 +380,7 @@ def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
             _row(bias_ref, start, width) if use_bias else None,
             segq[rows] if use_segs else None,
             _row(segk_ref, start, width) if use_segs else None,
-            tri, True)                                   # [rows, width] f32
+            mask, band)                                  # [rows, width] f32
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -303,7 +407,8 @@ def _fwd_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
 
 
 def _flash_fwd_bh(q, k, v, bias=None, segs=None, *, causal: bool,
-                  block_q: int, block_k: int, interpret: bool):
+                  block_q: int, block_k: int, interpret: bool,
+                  window: Optional[int] = None):
     """q,k: [BH, S, D]; v: [BH, S, Dv]; bias: optional [BH, 1, S] additive
     (0 / NEG_INF); segs: optional [BH, 1, S] int32 packed-sequence ids.
     Returns (out [BH, S, Dv], lse [BH, 1, S])."""
@@ -313,23 +418,30 @@ def _flash_fwd_bh(q, k, v, bias=None, segs=None, *, causal: bool,
     if s % block_q or s % block_k:
         raise ValueError(f"seq len {s} must be divisible by blocks ({block_q},{block_k})")
     return _fwd_call(q, k, v, bias, segs, causal=causal, block_q=block_q,
-                     block_k=block_k, interpret=interpret, caller=caller_scope())
+                     block_k=block_k, interpret=interpret, caller=caller_scope(),
+                     window=window)
 
 
 # The launches are jitted so that a model's layers, which call with the same
 # shapes, share one trace and one lowering of a kernel's body: its strips are
 # static Python loops, and 72 traces of them a step program cost the set-up
 # 20 s (PERF.md §6, PR 26).
-_LAUNCH_STATICS = ("causal", "block_q", "block_k", "interpret", "caller")
+_LAUNCH_STATICS = ("causal", "block_q", "block_k", "interpret", "caller", "window")
+
+
+def _launch_name(kernel: str, window: Optional[int]) -> str:
+    """A windowed launch has a name of its own in the trace, so that a reader
+    tells it from a global layer's launch at the same shapes."""
+    return kernel if window is None else f"window_{kernel}"
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCH_STATICS)
 def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
-              caller):
+              caller, window=None):
     bh, s, d = q.shape
     dv = v.shape[2]
     kernel = functools.partial(
-        _fwd_kernel, sched=_schedule(s, block_q, block_k, causal),
+        _fwd_kernel, sched=_schedule(s, block_q, block_k, causal, window=window),
         scale=d ** -0.5, use_bias=bias is not None, use_segs=segs is not None)
     mem = {"memory_space": pltpu.VMEM}
     grid = (bh, s // block_q)
@@ -362,7 +474,7 @@ def _fwd_call(q, k, v, bias, segs, *, causal, block_q, block_k, interpret,
         interpret=interpret,
         **_vmem(s, d, dv, q.dtype),
     )
-    with kernel_scope("flash_fwd", caller):
+    with kernel_scope(_launch_name("flash_fwd", window), caller):
         return call(*args)
 
 
@@ -382,7 +494,7 @@ def _dq_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
     delta = delta_ref[0, 0][:, None]                     # [bq, 1]
     segq = segq_ref[0, 0][:, None] if use_segs else None
 
-    def grad(carry, rows, start, width, tri):
+    def grad(carry, rows, start, width, mask, band):
         # dq of the block's ``rows`` from keys [start, start + width)
         (acc,) = carry
         k_blk = k_ref[0, pl.ds(start, width), :]
@@ -392,7 +504,7 @@ def _dq_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
             _row(bias_ref, start, width) if use_bias else None,
             segq[rows] if use_segs else None,
             _row(segk_ref, start, width) if use_segs else None,
-            tri, True)
+            mask, band)
         p = jnp.exp(s - lse[rows])                       # exact probs via saved lse
         dp = jax.lax.dot_general(do[rows], v_blk, _NT,
                                  preferred_element_type=jnp.float32)
@@ -421,7 +533,7 @@ def _dkv_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
     bias = bias_ref[0, 0][:, None] if use_bias else None  # [bk, 1]
     segk = segk_ref[0, 0][:, None] if use_segs else None  # [bk, 1]
 
-    def grad(carry, keys, start, width, tri):
+    def grad(carry, keys, start, width, mask, band):
         # dk, dv of the block's ``keys`` from rows [start, start + width)
         dk, dv = carry
         q_blk = q_ref[0, pl.ds(start, width), :]
@@ -431,7 +543,7 @@ def _dkv_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
             bias[keys] if use_bias else None,
             segk[keys] if use_segs else None,
             _row(segq_ref, start, width) if use_segs else None,
-            tri, False)                                  # [keys, width] f32
+            mask, band, keys_first=True)                 # [keys, width] f32
         p = jnp.exp(s - _row(lse_ref, start, width))
         dv = dv + jax.lax.dot_general(
             p.astype(do_blk.dtype), do_blk, _NN,
@@ -455,17 +567,17 @@ def _dkv_kernel(*refs, sched: _Schedule, scale: float, use_bias: bool,
 
 
 def _flash_bwd_bh(q, k, v, bias, lse, out, do, segs=None, *, causal, block_q,
-                  block_k, interpret, delta_shift=None):
+                  block_k, interpret, delta_shift=None, window=None):
     s = q.shape[1]
     return _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift,
                      causal=causal, block_q=min(block_q, s),
                      block_k=min(block_k, s), interpret=interpret,
-                     caller=caller_scope())
+                     caller=caller_scope(), window=window)
 
 
 @functools.partial(jax.jit, static_argnames=_LAUNCH_STATICS)
 def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
-              block_q, block_k, interpret, caller):
+              block_q, block_k, interpret, caller, window=None):
     bh, s, d = q.shape
     dv = v.shape[2]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -503,7 +615,8 @@ def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
 
     dq_call = pl.pallas_call(
         functools.partial(
-            _dq_kernel, sched=_schedule(s, block_q, block_k, causal), **static),
+            _dq_kernel, sched=_schedule(s, block_q, block_k, causal, window=window),
+            **static),
         grid=(bh, s // block_q),
         in_specs=dq_specs,
         out_specs=qblock,
@@ -511,13 +624,14 @@ def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
         interpret=interpret,
         **_vmem(s, d, dv, q.dtype),
     )
-    with kernel_scope("flash_dq", caller):
+    with kernel_scope(_launch_name("flash_dq", window), caller):
         dq = dq_call(*args)
 
     dkv_call = pl.pallas_call(
         functools.partial(
             _dkv_kernel,
-            sched=_schedule(s, block_q, block_k, causal, walks_rows=True),
+            sched=_schedule(s, block_q, block_k, causal, walks_rows=True,
+                            window=window),
             **static),
         grid=(bh, s // block_k),
         in_specs=dkv_specs,
@@ -529,30 +643,32 @@ def _bwd_call(q, k, v, bias, lse, out, do, segs, delta_shift, *, causal,
         interpret=interpret,
         **_vmem(s, d, dv, q.dtype),
     )
-    with kernel_scope("flash_dkv", caller):
+    with kernel_scope(_launch_name("flash_dkv", window), caller):
         dk, dv = dkv_call(*args)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_bh(q, k, v, bias, segs, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_bh(q, k, v, bias, segs, causal, block_q, block_k, interpret,
+              window=None):
     out, _ = _flash_fwd_bh(q, k, v, bias, segs, causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=interpret)
+                           block_k=block_k, interpret=interpret, window=window)
     return out
 
 
-def _flash_bh_fwd(q, k, v, bias, segs, causal, block_q, block_k, interpret):
+def _flash_bh_fwd(q, k, v, bias, segs, causal, block_q, block_k, interpret,
+                  window):
     out, lse = _flash_fwd_bh(q, k, v, bias, segs, causal=causal,
                              block_q=block_q, block_k=block_k,
-                             interpret=interpret)
+                             interpret=interpret, window=window)
     return out, (q, k, v, bias, segs, lse, out)
 
 
-def _flash_bh_bwd(causal, block_q, block_k, interpret, residuals, g):
+def _flash_bh_bwd(causal, block_q, block_k, interpret, window, residuals, g):
     q, k, v, bias, segs, lse, out = residuals
     dq, dk, dv = _flash_bwd_bh(q, k, v, bias, lse, out, g, segs, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               interpret=interpret)
+                               interpret=interpret, window=window)
     return dq, dk, dv, None, None
 
 
@@ -630,16 +746,26 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused attention; drop-in for ``dot_product_attention`` on TPU.
     ``segment_ids`` confines attention within matching ids (packed
     sequences / block-diagonal masking), composable with ``kv_mask``
-    and ``causal``."""
+    and ``causal``. ``window`` (a static integer, causal only) confines a
+    row to its own position and the ``window - 1`` before it: the kernels
+    skip what lies behind the window (module docstring), and their launches
+    are named ``window_flash_*``. ``None``, or the sequence's length or
+    more, is plain causal attention."""
     b, s, h, d = q.shape
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"a window ({window}) is of at least one key and causal")
+        window = None if window >= s else int(window)
     qb, kb, vb, bias, segs, block_q, block_k, interpret = _prep_bh(
         q, k, v, kv_mask, segment_ids, block_q, block_k, interpret
     )
-    out = _flash_bh(qb, kb, vb, bias, segs, causal, block_q, block_k, interpret)
+    out = _flash_bh(qb, kb, vb, bias, segs, causal, block_q, block_k, interpret,
+                    window)
     return out.reshape(b, h, s, v.shape[-1]).transpose(0, 2, 1, 3)
 
 

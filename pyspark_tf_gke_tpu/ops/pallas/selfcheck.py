@@ -37,14 +37,20 @@ def _shapes(tiny: bool) -> dict:
     if tiny:
         return dict(slots=4, heads=4, head_dim=16, page=8, max_pages=4,
                     pages=16, chunk=8, gqa_kv=2, train_b=2, seq=256,
-                    hidden=64)
+                    hidden=64, window=dict(seq=256, heads=2, head_dim=16,
+                                           window=128, block=64))
     # what chip_smoke.py's trainer and server legs run: lm_pretrain
     # defaults at --seq-len 1024 --batch-size 8; serve
     # --continuous-slots 8 --prefill-chunk 128 on a page-64 bundle with
     # slots x 16 pages
     return dict(slots=8, heads=12, head_dim=64, page=64, max_pages=16,
                 pages=128, chunk=128, gqa_kv=4, train_b=8, seq=1024,
-                hidden=768)
+                hidden=768,
+                # a window layer of the hybrid decoder at its cell's shape
+                # (models/hybrid_lm.py::GatedAttention): heads of 128, the
+                # default 512-row blocks, four blocks to a window
+                window=dict(seq=8192, heads=4, head_dim=128, window=2048,
+                            block=None))
 
 
 def _cases(tiny: bool, interpret: bool
@@ -144,6 +150,24 @@ def _cases(tiny: bool, interpret: bool
 
     yield f"flash_attention[causal,fwd,S={s}]", flash_fwd
     yield f"flash_attention[causal,bwd,S={s}]", flash_bwd
+
+    win = sh["window"]
+    wq, wk, wv, wg = (normal((1, win["seq"], win["heads"], win["head_dim"]))
+                      for _ in range(4))
+    windowed = dict(causal=True, window=win["window"])
+
+    def window_flash():
+        def both(fn):
+            out, pull = jax.vjp(fn, wq, wk, wv)
+            return out, pull(wg)
+
+        return (both(lambda *a: flash_attention(
+                    *a, block_q=win["block"], block_k=win["block"],
+                    interpret=interpret, **windowed)),
+                both(lambda *a: dot_product_attention(*a, **windowed)))
+
+    yield (f"flash_attention[window={win['window']},fwd+bwd,S={win['seq']}]",
+           window_flash)
 
     hid = sh["hidden"]
     x, r = normal((tb * s, hid)), normal((tb * s, hid))

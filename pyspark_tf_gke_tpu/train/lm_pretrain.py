@@ -46,7 +46,7 @@ logger = get_logger("train.lm_pretrain")
 
 
 # --arch -> the ``model_type`` its --model-config file has to state
-HYBRID_ARCHS = {"kimi-linear": "kimi_linear", "nemotron-h": "nemotron_h"}
+HYBRID_ARCHS = {"kimi-linear": "kimi_linear", "nemotron-h": "nemotron_h", "afmoe": "afmoe"}
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -94,15 +94,19 @@ def parse_args(argv=None) -> argparse.Namespace:
                    choices=["", "gpt2", "llama", *HYBRID_ARCHS],
                    help="architecture preset: gpt2 = learned+layernorm+gelu "
                         "(the defaults); llama = rope+rmsnorm+swiglu; "
-                        "kimi-linear and nemotron-h = the hybrid decoder of "
-                        "models/hybrid_lm.py (kimi-linear: KDA + MLA layers, "
+                        "kimi-linear, nemotron-h and afmoe = the hybrid decoder "
+                        "of models/hybrid_lm.py (kimi-linear: KDA + MLA layers, "
                         "dense + expert FFNs; nemotron-h: one mixer a layer, "
-                        "Mamba-2, GQA or relu2 experts), sized by --model-config")
+                        "Mamba-2, GQA or relu2 experts; afmoe: gated GQA in a "
+                        "window with rotary positions beside global layers "
+                        "without, sandwich norms, dense + expert FFNs), sized "
+                        "by --model-config")
     p.add_argument("--model-config", default=e("MODEL_CONFIG", ""),
                    help="configuration file with the family's published keys "
                         "(--arch kimi-linear: e.g. benchmark/configs/"
                         "kimi-linear-48b-a3b.json; --arch nemotron-h: e.g. "
-                        "benchmark/configs/nemotron-3-nano-30b-a3b.json); it "
+                        "benchmark/configs/nemotron-3-nano-30b-a3b.json; "
+                        "--arch afmoe: e.g. benchmark/configs/trinity-mini.json); it "
                         "gives every size, the vocabulary among them")
     p.add_argument("--doc-masking", action="store_true",
                    default=_env_bool("DOC_MASKING", False),
@@ -168,7 +172,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def _hybrid_config(args, tokenizer, dtype):
-    """``--arch kimi-linear`` / ``nemotron-h``: every size from
+    """``--arch kimi-linear`` / ``nemotron-h`` / ``afmoe``: every size from
     ``--model-config``, a file of that family; the tokenizer's ids have to fit
     the file's (possibly sliced) vocabulary."""
     import json

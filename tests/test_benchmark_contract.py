@@ -42,7 +42,8 @@ STEPS = 2                                    # of the traced ``fit``
 # (the state-space scan's chunk is 128 rows: its family's toy rows hold two)
 TOY = {"train": ("tiny-gpt2", "train.tiny-seq128"),
        "train_kimi_linear": ("tiny-kimi", "train.tiny-seq128"),
-       "train_nemotron_h": ("tiny-nemotron", "train.tiny-seq256")}
+       "train_nemotron_h": ("tiny-nemotron", "train.tiny-seq256"),
+       "train_afmoe": ("tiny-afmoe", "train.tiny-seq256")}
 # Readers without module constants: the ``ctx`` keys they read. The runner
 # fills each from ``Trainer.fit``'s history: a counter is the window's mean
 # under its own name, ``steps`` is the window sized by ``step_time_ms``.
@@ -55,6 +56,9 @@ CTX_KEYS = {
     "moe_held_tokens_per_expert.train.nemotron-h": ("moe_held_assignments",),
     "moe_held_load_max_over_mean.train.nemotron-h": ("moe_held_assignments",
                                                      "moe_held_load_max"),
+    "mfu.train.afmoe": ("steps", "moe_held_assignments"),
+    "moe_held_tokens_per_expert.train.afmoe": ("moe_held_assignments",),
+    "moe_held_load_max_over_mean.train.afmoe": ("moe_held_assignments", "moe_held_load_max"),
 }
 FROM_HISTORY = {"steps": "step_time_ms"}
 
@@ -91,7 +95,7 @@ def _gpt2_model(cfg, tr, mesh):
 
 
 def _hybrid_model(cfg, tr, mesh):
-    """Either family of ``models/hybrid_lm.py``, by the file's ``model_type``."""
+    """Any family of ``models/hybrid_lm.py``, by the file's ``model_type``."""
     from pyspark_tf_gke_tpu.models.hybrid_lm import HybridLM, config_from_file
 
     mcfg = config_from_file(cfg, dtype=jnp.bfloat16, remat=bool(tr["remat"]))
@@ -99,7 +103,7 @@ def _hybrid_model(cfg, tr, mesh):
 
 
 MODELS = {"train": _gpt2_model, "train_kimi_linear": _hybrid_model,
-          "train_nemotron_h": _hybrid_model}
+          "train_nemotron_h": _hybrid_model, "train_afmoe": _hybrid_model}
 
 
 def written(runner, trace_dir):

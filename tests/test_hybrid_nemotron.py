@@ -237,7 +237,7 @@ def test_lm_pretrain_arch_and_model_config_go_together(arch, config):
 
     for argv in (["--data-pattern", "x", "--arch", arch],
                  ["--data-pattern", "x", "--model-config", config]):
-        with pytest.raises(SystemExit, match="kimi-linear / nemotron-h and --model-config go"):
+        with pytest.raises(SystemExit, match="kimi-linear / nemotron-h / afmoe and --model-config go"):
             lm_pretrain.main(argv)
     both = ["--data-pattern", "x", "--arch", arch, "--model-config", config]
     with pytest.raises(SystemExit, match=f"--arch {arch} trains only.*state-space scan"):
@@ -255,7 +255,7 @@ def test_lm_pretrain_refuses_a_file_of_the_other_family(tmp_path):
     with pytest.raises(SystemExit, match="model_type 'kimi_linear'.*states 'nemotron_h'"):
         lm_pretrain.main(common + ["--arch", "kimi-linear", "--model-config", TINY])
     assert lm_pretrain.HYBRID_ARCHS == {"kimi-linear": "kimi_linear",
-                                        "nemotron-h": "nemotron_h"}
+                                        "nemotron-h": "nemotron_h", "afmoe": "afmoe"}
 
 
 def test_a_fresh_mamba2_mixer_carries_its_state_past_a_chunk(tiny):
@@ -339,6 +339,29 @@ def test_the_toy_kimi_decoder_keeps_the_parents_tree_and_traced_step(slabs):
     with jax.default_matmul_precision("highest"):
         step = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(tree, ids)
     assert (_digest(str(step)) == PARENT["step"]) == (slabs == "parents_loop")
+
+
+# sha256 of the same two things for the toy Nemotron-H decoder, made by the test's
+# own lines on an unpacked ``git archive`` of PR 35's PARENT commit (1883bd5, PR 33)
+PARENT_NEMOTRON = {"tree": "67e4567cd2412ebd", "step": "666d53fc8302499e"}
+
+
+def test_the_toy_nemotron_decoder_keeps_the_parents_tree_and_traced_step():
+    """Two more attention kinds, the sandwich norms, the embedding's scale and
+    a third family of keys in ``config_from_file`` (PR 35) change nothing a
+    ``nemotron_h`` file builds: same leaves, same program."""
+    model = HybridLM(config_from_file(load(TINY), dtype=jnp.bfloat16, remat=True))
+    ids = jnp.zeros((2, 128), jnp.int32)
+    tree = nn.unbox(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))["params"])
+    assert _digest(_paths(tree)) == PARENT_NEMOTRON["tree"]
+
+    def loss(p, ids):
+        logits, sown = model.apply({"params": p}, ids, mutable=["counters"])
+        return jnp.sum(logits), sown
+
+    with jax.default_matmul_precision("highest"):
+        step = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(tree, ids)
+    assert _digest(str(step)) == PARENT_NEMOTRON["step"]
 
 
 def test_swiglu_held_experts_keep_the_parents_tree_and_traced_layer(slabs):

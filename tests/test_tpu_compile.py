@@ -1,5 +1,5 @@
 """The new kernels compiled for a described TPU v5e at the widths the
-Kimi-Linear and Nemotron-H cells run them at: what interpret mode cannot show (tiling, VMEM,
+Kimi-Linear, Nemotron-H and Trinity-Mini cells run them at: what interpret mode cannot show (tiling, VMEM,
 what Mosaic lowers). Nothing runs; no chip is needed. One file, so that one
 xdist worker loads the TPU's library."""
 
@@ -109,3 +109,25 @@ def test_flash_kernels_compile_with_keys_of_192_and_values_of_128(one_chip, no_c
         jax.jit(lambda q, k, v: F._fwd_call(q, k, v, None, None, **kw)).lower(q, q, v).compile()
         jax.jit(lambda q, k, v, l, o, do: F._bwd_call(
             q, k, v, None, l, o, do, None, None, **kw)).lower(q, q, v, lse, v, v).compile()
+
+
+@pytest.mark.parametrize("window", [2048, 1000], ids=["mirrored", "banded"])
+def test_flash_kernels_compile_inside_a_window(one_chip, no_compile_cache, window):
+    """A window layer of the Trinity-Mini cell: heads of 128 at S 8192, four
+    512-row blocks to the window (the edge strips behind one ``lax.cond`` a
+    grid step, which interpret mode does not lower); and a window that is no
+    multiple of the block (every tile through a mask of positions)."""
+    F = importlib.import_module("pyspark_tf_gke_tpu.ops.pallas.flash_attention")
+    bh, s, d = 4, 8192, 128
+    q = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, 1, s), jnp.float32, sharding=one_chip)
+    blk = F._pick_seq_block(s, F.DEFAULT_BLOCK_Q)
+    kw = dict(causal=True, block_q=blk, block_k=blk, interpret=False, caller="a", window=window)
+    assert F._schedule(s, blk, blk, True, window=window).mirrored == (window == 2048)
+    with jax.default_matmul_precision("default"):
+        fwd = jax.jit(lambda q, k, v: F._fwd_call(q, k, v, None, None, **kw)).lower(q, q, q)
+        assert "window_flash_fwd" in fwd.compile().as_text()
+        bwd = jax.jit(lambda q, k, v, l, o, do: F._bwd_call(
+            q, k, v, None, l, o, do, None, None, **kw)).lower(q, q, q, lse, q, q)
+        text = bwd.compile().as_text()
+        assert "window_flash_dq" in text and "window_flash_dkv" in text
