@@ -51,9 +51,12 @@ head, no bias but the Mamba-2 convolution's.
   ``gated_full`` layer neither; the heads' outputs times ``sigmoid(x W_g)``
   before the output projection.
 * **Experts**: ``models/moe.py::HeldExpertsLayer`` (``swiglu`` experts for
-  Kimi-Linear, ``relu2`` ones with a shared expert of its own width for
-  Nemotron-H); its counters are sown into the ``counters`` collection and
-  summed here (:meth:`HybridLM.step_counters`).
+  Kimi-Linear and AFMoE, ``relu2`` ones with a shared expert of its own width
+  for Nemotron-H), which walks the assignments to the experts this chip holds
+  in steps of an eighth of their mean load, as many as the load asks for; its
+  counters (the held assignments, the busiest held expert's, the rows walked)
+  are sown into the ``counters`` collection and summed here
+  (:meth:`HybridLM.step_counters`).
 
 The training path only, for every kind: ``decode=True`` / ``prefill=True``
 raise (the engine's cache has neither latent pages nor a per-slot recurrent
@@ -626,12 +629,13 @@ class HybridLM(nn.Module):
     @staticmethod
     def step_counters(sown) -> dict:
         """The step's counters from the ``counters`` collection: assignments
-        to held experts summed over the expert layers, and the busiest held
-        expert's load in any of them."""
+        to held experts and the rows their walks took, each summed over the
+        expert layers, and the busiest held expert's load in any of them."""
         layers = [c for c in sown.values() if "held_assignments" in c]
         if not layers:
             return {}
         # ``sow`` keeps a tuple a name: one value a call
         return {"moe_held_assignments": sum(c["held_assignments"][0] for c in layers),
                 "moe_held_load_max": jnp.max(jnp.stack(
-                    [c["held_load_max"][0] for c in layers]))}
+                    [c["held_load_max"][0] for c in layers])),
+                "moe_held_rows_walked": sum(c["held_rows_walked"][0] for c in layers)}

@@ -141,71 +141,166 @@ class MoELayer(nn.Module):
         return out.astype(x.dtype), aux_loss.astype(jnp.float32)
 
 
-def _slab(start, xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
-          dtype):
-    """The held assignments ``[start, start + rows)`` of the sorted list
-    ``order``, as their weighted outputs scattered to their tokens
-    (``[T, H]`` float32). ``ends`` and ``loads`` are the held experts'
-    cumulative and own assignment counts. ``w_gate`` is ``None`` for experts
-    without a gate (``relu(x W_up)^2 W_down``)."""
-    mine = jax.lax.dynamic_slice(order, (start,), (rows,))
-    valid = start + jnp.arange(rows) < ends[-1]
-    token = mine // k
-    sizes = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - loads - start, 0, rows)
-    # rows past the last assignment join the last group: they are computed
-    # on real tokens and discarded, never left undefined
-    sizes = sizes.at[-1].add(rows - jnp.sum(sizes))
-    cast = lambda w: w.astype(dtype)
-    xs = cast(xt[token])
-    if w_gate is None:                  # relu2: two matrices an expert
-        mid = jnp.square(jax.nn.relu(jax.lax.ragged_dot(xs, cast(w_up), sizes)))
-    else:
-        mid = jax.nn.silu(jax.lax.ragged_dot(xs, cast(w_gate), sizes)) \
-            * jax.lax.ragged_dot(xs, cast(w_up), sizes)
-    ys = jax.lax.ragged_dot(mid, cast(w_down), sizes,
-                            preferred_element_type=jnp.float32)
-    ys = jnp.where(valid[:, None], ys * flat_w[mine][:, None], 0.0)
-    return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
+# the walk's step is this part of the held experts' mean load, and a step's
+# window the experts that this many steps span at that load: settled by a sweep
+# on the chip with the layer alone at three cells' sizes (PERF.md §6, PR 36)
+STEP_SHARE = 8
+WINDOW_STEPS = 2
+
+# [rows, K] x [rows, N] -> [groups, K, N]: a group's rows contracted
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=[0],
+    rhs_group_dimensions=[])
+
+
+def step_and_window(tokens, top_k, count, num_experts, rows=0):
+    """``(rows, experts)`` of one step of the walk: ``rows`` sorted held
+    assignments (``rows`` as given, or a ``STEP_SHARE``-th of the held experts'
+    mean load rounded up to 512: XLA's ``ragged_dot`` takes 768 rows in the time
+    of 1,024) against a window of ``experts`` consecutive held experts (those
+    that ``WINDOW_STEPS`` steps span at the mean load, at least 2). From the
+    shapes alone, the same for every caller."""
+    mean_load = max(tokens * top_k * count // num_experts, 1)
+    rows = min(rows or -(-mean_load // (STEP_SHARE * 512)) * 512, tokens * top_k)
+    return rows, min(max(-(-WINDOW_STEPS * rows * count // mean_load), 2), count)
 
 
 def _slabs_walked(total, rows):
-    """``ceil(total / rows)`` and never nought: the first slab is walked
-    whatever the load (at a load of nought all its rows are padding), so a
-    layer costs the same at one held assignment or none."""
+    """``ceil(total / rows)`` and never nought, the least number of steps: the
+    first step is walked whatever the load (at a load of nought all its rows
+    are padding), so a layer costs the same at one held assignment or none."""
     return jnp.maximum(-(-total // rows), 1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _held_experts(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows, k,
-                  dtype):
-    """Sum of :func:`_slab` over the slabs that hold an assignment: a loop
-    whose trip count follows the load (:func:`_slabs_walked`), in the forward
-    and in the backward alike, so no slab's operands are kept."""
+def _walk(step, carry, ends, rows):
+    """``(steps, carry)`` after ``taken, carry = step(start, carry)`` from the
+    first of the sorted held assignments to the last (``ends[-1]`` of them). A
+    step takes ``rows`` assignments or ends short, at the last expert of its
+    window, so the steps are :func:`_slabs_walked` at least and ``load // rows
+    + ceil(count / window)`` at most."""
+    def more(state):
+        start, steps, _ = state
+        return (start < ends[-1]) | (steps < _slabs_walked(ends[-1], rows))
+
+    def one(state):
+        start, steps, carry = state
+        taken, carry = step(start, carry)
+        return start + taken, steps + 1, carry
+
+    nought = jnp.zeros((), ends.dtype)
+    _, steps, carry = jax.lax.while_loop(more, one, (nought, nought, carry))
+    return steps, carry
+
+
+def _step(start, order, ends, loads, rows, window):
+    """The step that begins at the sorted held assignment ``start``: its
+    ``rows`` assignments (``mine``, indices into ``[T * k]``); how many of them
+    it takes, those of its ``window`` of held experts, from the first expert
+    that still has one; which rows those are (``valid``: the others are the
+    next step's, or past the load); their group sizes over all held experts
+    and over the window alone, and where the window begins."""
+    count = ends.shape[0]
+    mine = jax.lax.dynamic_slice(order, (start,), (rows,))
+    first = jnp.minimum(jnp.sum(ends <= start), count - window)
+    near = jax.lax.dynamic_slice(ends, (first,), (window,))
+    own = jax.lax.dynamic_slice(loads, (first,), (window,))
+    sizes = jnp.clip(near - start, 0, rows) - jnp.clip(near - own - start, 0, rows)
+    taken = jnp.sum(sizes)
+    # rows that do not count join the window's last group: they are computed
+    # on real tokens and discarded, never left undefined
+    sizes = sizes.at[-1].add(rows - taken)
+    held = jax.lax.dynamic_update_slice(jnp.zeros_like(ends), sizes, (first,))
+    return mine, taken, jnp.arange(rows) < taken, held, sizes, first
+
+
+def _activation(pre):
+    """``silu(x W_gate) * (x W_up)`` of ``pre = [x W_gate, x W_up]``, or
+    ``relu(x W_up)^2`` of ``[x W_up]``."""
+    if len(pre) == 1:
+        return jnp.square(jax.nn.relu(pre[0]))
+    return jax.nn.silu(pre[0]) * pre[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def _held_experts(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows,
+                  window, k, dtype):
+    """``(out, steps)``: the sorted held assignments ``order``, each the
+    weighted output of its expert added to its token's row (``[T, H]``
+    float32), and the steps walked. ``ends`` and ``loads`` are the held
+    experts' cumulative and own assignment counts; ``w_gate`` is ``None`` for
+    experts without a gate (``relu(x W_up)^2 W_down``).
+
+    A loop whose trip count follows the load (:func:`_walk`), in the forward
+    and in the backward alike, so no step's operands are kept; a step costs
+    its ``rows`` and, in the backward, its ``window`` of experts, and nothing
+    the size of the layer: the weights' ``dtype`` copies are made before the
+    loop (``ragged_dot`` reads the groups that have rows), and the sums over
+    steps are float32 carries updated in place."""
     return _held_experts_fwd(xt, flat_w, w_gate, w_up, w_down, order, ends, loads,
-                             rows, k, dtype)[0]
+                             rows, window, k, dtype)[0]
 
 
 def _held_experts_fwd(xt, flat_w, w_gate, w_up, w_down, order, ends, loads, rows,
-                      k, dtype):
-    diff, ints = (xt, flat_w, w_gate, w_up, w_down), (order, ends, loads)
-    out = jax.lax.fori_loop(
-        0, _slabs_walked(ends[-1], rows),
-        lambda i, acc: acc + _slab(i * rows, *diff, *ints, rows, k, dtype),
-        jnp.zeros(xt.shape, jnp.float32))
-    return out, (diff, ints)
+                      window, k, dtype):
+    into = [w.astype(dtype) for w in (w_gate, w_up) if w is not None]
+    back = w_down.astype(dtype)
+
+    def step(start, out):
+        mine, taken, valid, held, _, _ = _step(start, order, ends, loads, rows, window)
+        token = mine // k
+        xs = xt[token].astype(dtype)
+        mid = _activation([jax.lax.ragged_dot(xs, w, held) for w in into])
+        ys = jax.lax.ragged_dot(mid, back, held, preferred_element_type=jnp.float32)
+        ys = jnp.where(valid[:, None], ys * flat_w[mine][:, None], 0.0)
+        return taken, out.at[token].add(ys)
+
+    steps, out = _walk(step, jnp.zeros(xt.shape, jnp.float32), ends, rows)
+    return (out, steps), ((xt, flat_w, w_gate, w_up, w_down), (order, ends, loads))
 
 
-def _held_experts_bwd(rows, k, dtype, residuals, g):
-    diff, ints = residuals
+def _held_experts_bwd(rows, window, k, dtype, residuals, cotangents):
+    (xt, flat_w, w_gate, w_up, w_down), (order, ends, loads) = residuals
+    g = cotangents[0]                   # the steps have no gradient
+    into = [w.astype(dtype) for w in (w_gate, w_up) if w is not None]
+    back = w_down.astype(dtype)
+    # the transposes the rows' gradients are multiplied by, made once as well
+    *turned_into, turned_back = [jnp.swapaxes(w, 1, 2) for w in (*into, back)]
+    float32 = dict(preferred_element_type=jnp.float32)
 
-    def one(i, acc):
-        _, pull = jax.vjp(
-            lambda *d: _slab(i * rows, *d, *ints, rows, k, dtype), *diff)
-        return jax.tree.map(jnp.add, acc, pull(g))
+    def step(start, carry):
+        dxt, dflat, dinto, dback = carry
+        mine, taken, valid, held, sizes, first = _step(start, order, ends, loads, rows,
+                                                      window)
+        token = mine // k
+        xs = xt[token].astype(dtype)
+        mid, pull = jax.vjp(_activation, [jax.lax.ragged_dot(xs, w, held) for w in into])
+        ys = jax.lax.ragged_dot(mid, back, held, **float32)
+        gs = jnp.where(valid[:, None], g[token], 0.0)
+        dys = (gs * flat_w[mine][:, None]).astype(dtype)
+        dpre, = pull(jax.lax.ragged_dot(dys, turned_back, held))
+        dxs = sum(jax.lax.ragged_dot(d, w, held, **float32)
+                  for d, w in zip(dpre, turned_into))
 
-    grads = jax.lax.fori_loop(0, _slabs_walked(ints[1][-1], rows), one,
-                              jax.tree.map(jnp.zeros_like, diff))
-    return (*grads, None, None, None)
+        def add(total, rows_in, rows_out):
+            """A weight's gradient from this step's rows, ``[window, K, N]``
+            float32, added where the window's experts lie."""
+            part = jax.lax.ragged_dot_general(rows_in, rows_out, sizes, _ROWS_CONTRACTED,
+                                              **float32)
+            there = jax.lax.dynamic_slice_in_dim(total, first, window)
+            return jax.lax.dynamic_update_slice_in_dim(total, there + part, first, 0)
+
+        return taken, (dxt.at[token].add(dxs),
+                       dflat.at[mine].add(jnp.sum(ys * gs, axis=-1)),
+                       [add(t, xs, d) for t, d in zip(dinto, dpre)],
+                       add(dback, mid, dys))
+
+    zeros = lambda like: jnp.zeros(like.shape, jnp.float32)
+    _, (dxt, dflat, dinto, dback) = _walk(
+        step, (zeros(xt), zeros(flat_w), [zeros(w) for w in into], zeros(back)),
+        ends, rows)
+    dinto = [None] * (w_gate is None) + [d.astype(w_up.dtype) for d in dinto]
+    return (dxt.astype(xt.dtype), dflat.astype(flat_w.dtype), *dinto,
+            dback.astype(w_down.dtype), None, None, None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -245,15 +340,26 @@ class HeldExpertsLayer(nn.Module):
     weights ``route_scale * s_e / sum_chosen s``. No capacity, no dropped
     token, no auxiliary loss.
 
-    The assignments to held experts are sorted by expert and multiplied by
-    groups (``jax.lax.ragged_dot``) in slabs of ``slab_rows`` rows by a loop
-    that runs as many slabs as hold an assignment and at least one, so the
-    work follows the load in whole slabs, and however many tokens choose a
-    held expert (at most ``top_k`` x tokens assignments) every one is computed.
+    The assignments to held experts are sorted by expert and walked in steps
+    of ``slab_rows`` rows, an eighth of the held experts' mean load by default
+    (:func:`step_and_window`): a step gathers its rows' tokens, multiplies
+    them by groups (``jax.lax.ragged_dot``) against a window of the few
+    consecutive experts its rows fall in, sliced from bf16 copies of the
+    weights made once before the loop, and adds the weighted outputs to their
+    tokens in the loop's float32 carry; it ends after ``slab_rows`` rows or at
+    its window's last expert. The loop runs until the last held assignment and
+    at least once, so the work follows the load, a step costs its rows and its
+    window and nothing the size of the layer, and however many tokens choose a
+    held expert (at most ``top_k`` x tokens assignments) every one is
+    computed. The backward walks the same steps: float32 carries for the
+    gradients of the tokens, of the routing weights and of the expert weights,
+    the last added to a window at a time where the step's rows fall.
 
     Shape-preserving on ``[B, S, H]``; returns ``(out, counters)`` with
-    ``held_assignments`` (assignments to held experts in this call) and
-    ``held_load_max`` (those of the busiest held expert), float32 scalars.
+    ``held_assignments`` (assignments to held experts in this call),
+    ``held_load_max`` (those of the busiest held expert) and
+    ``held_rows_walked`` (steps walked times a step's rows: what the walk
+    cost, of which the assignments are the real part), float32 scalars.
     """
 
     num_experts: int
@@ -263,7 +369,7 @@ class HeldExpertsLayer(nn.Module):
     intermediate_size: int
     route_scale: float = 1.0
     shared: int = 0               # shared experts, as one FFN of that many widths
-    slab_rows: int = 0            # 0 = twice the mean load of the held experts
+    slab_rows: int = 0            # a step's rows; 0 = an eighth of the held experts' mean load
     dtype: Any = jnp.bfloat16
     activation: str = "swiglu"    # "swiglu" | "relu2"
     shared_width: int = 0         # the shared expert's width; 0 = ``shared`` routed widths
@@ -305,19 +411,17 @@ class HeldExpertsLayer(nn.Module):
         loads = jnp.sum(jax.nn.one_hot(group, count, dtype=jnp.int32), axis=0)
         ends = jnp.cumsum(loads)
         total = ends[-1]
-        # a slab of twice the mean load, so that one slab is the usual case
-        mean_load = tokens * k * count // self.num_experts
-        rows = self.slab_rows or -(-2 * max(mean_load, 128) // 256) * 256
-        rows = min(rows, tokens * k)
-        slabs = -(-tokens * k // rows)
-        order = jnp.pad(order, (0, slabs * rows - tokens * k))
-        out = _held_experts(xt, weights.reshape(-1), w_gate, w_up, w_down,
-                            order, ends, loads, rows, k, jnp.dtype(self.dtype))
+        rows, window = step_and_window(tokens, k, count, self.num_experts, self.slab_rows)
+        # a step that begins at the last assignment still reads ``rows`` of them
+        order = jnp.pad(order, (0, rows))
+        out, steps = _held_experts(xt, weights.reshape(-1), w_gate, w_up, w_down, order,
+                                   ends, loads, rows, window, k, jnp.dtype(self.dtype))
         if self.shared:
             out = out + ACTIVATIONS[self.activation](
                 h, self.shared_width or wide * self.shared, self.dtype, name="shared")(xt)
         counters = {"held_assignments": total.astype(jnp.float32),
-                    "held_load_max": jnp.max(loads).astype(jnp.float32)}
+                    "held_load_max": jnp.max(loads).astype(jnp.float32),
+                    "held_rows_walked": (steps * rows).astype(jnp.float32)}
         return out.reshape(b, s, h).astype(x.dtype), counters
 
 

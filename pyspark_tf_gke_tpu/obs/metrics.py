@@ -531,6 +531,11 @@ def platform_families(registry: Optional[MetricsRegistry] = None) -> dict:
             "train_moe_held_load_max",
             "Assignments to the busiest held expert of any expert layer, a "
             "step (mean of the last epoch)"),
+        "train_moe_held_rows_walked": r.gauge(
+            "train_moe_held_rows_walked",
+            "Rows the held experts' walks took, a step, summed over the "
+            "expert layers: steps walked times a step's rows, of which "
+            "train_moe_held_assignments are real (mean of the last epoch)"),
         "train_input_wait_ms": r.histogram(
             "train_input_wait_ms",
             "Per optimizer step, time the loop waited for its next "

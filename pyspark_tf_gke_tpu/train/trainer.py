@@ -794,7 +794,8 @@ class Trainer:
                 self._obs["train_epochs_total"].inc()
                 if "loss" in history:
                     self._obs["train_last_loss"].set(history["loss"][-1])
-                for key in ("moe_held_assignments", "moe_held_load_max"):
+                for key in ("moe_held_assignments", "moe_held_load_max",
+                            "moe_held_rows_walked"):
                     if key in history:
                         self._obs[f"train_{key}"].set(history[key][-1])
                 self._event_log.emit(

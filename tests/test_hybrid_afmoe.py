@@ -98,8 +98,10 @@ def test_logits_loss_and_every_leafs_gradient_against_the_reference(tiny, ids, r
     for name, gap in got["grads"].items():
         assert gap <= GRADS, name
     counters = HybridLM.step_counters(sown)
-    assert set(counters) == {"moe_held_assignments", "moe_held_load_max"}
+    assert set(counters) == {"moe_held_assignments", "moe_held_load_max",
+                             "moe_held_rows_walked"}
     assert 0 < float(counters["moe_held_load_max"]) <= float(counters["moe_held_assignments"])
+    assert float(counters["moe_held_assignments"]) <= float(counters["moe_held_rows_walked"])
 
 
 def test_bf16_compute_fails_each_tolerance(tiny, ids):
@@ -285,6 +287,7 @@ def test_trainer_takes_it_and_its_counters_reach_the_history(tiny, ids, vocab_ch
                                  prefetch=0)
     assert history["loss"][1] < history["loss"][0]
     assert 0 < history["moe_held_load_max"][0] <= history["moe_held_assignments"][0]
+    assert history["moe_held_assignments"][0] <= history["moe_held_rows_walked"][0]
 
 
 def test_lm_pretrain_takes_the_family_by_its_arch():

@@ -58,8 +58,10 @@ def test_loss_and_gradients_against_the_reference(tiny, ids, remat):
         scale = max(float(jnp.max(jnp.abs(r))), 1e-7)
         assert float(jnp.max(jnp.abs(got[name] - r))) <= 2e-4 * scale, name
     counters = HybridLM.step_counters(sown)
-    assert set(counters) == {"moe_held_assignments", "moe_held_load_max"}
+    assert set(counters) == {"moe_held_assignments", "moe_held_load_max",
+                             "moe_held_rows_walked"}
     assert 0 < float(counters["moe_held_load_max"]) <= float(counters["moe_held_assignments"])
+    assert float(counters["moe_held_assignments"]) <= float(counters["moe_held_rows_walked"])
 
 
 def test_the_tree_is_the_one_the_benchmark_makes_weights_for(tiny, ids):
@@ -206,9 +208,11 @@ def test_trainer_takes_it_and_its_counters_reach_metrics_and_registry(tiny, ids,
     assert history["loss"][1] < history["loss"][0]
     assert history["moe_held_assignments"][0] > 0
     assert history["moe_held_load_max"][0] <= history["moe_held_assignments"][0]
+    assert history["moe_held_assignments"][0] <= history["moe_held_rows_walked"][0]
     text = registry.exposition()
     for name, key in (("train_moe_held_assignments", "moe_held_assignments"),
-                      ("train_moe_held_load_max", "moe_held_load_max")):
+                      ("train_moe_held_load_max", "moe_held_load_max"),
+                      ("train_moe_held_rows_walked", "moe_held_rows_walked")):
         line = next(l for l in text.splitlines() if l.startswith(name + " "))
         assert float(line.split()[-1]) == pytest.approx(history[key][-1])
 

@@ -75,8 +75,10 @@ def test_loss_and_gradients_against_the_reference(tiny, ids, remat):
         scale = max(float(jnp.max(jnp.abs(r))), 1e-7)
         assert float(jnp.max(jnp.abs(got[name] - r))) <= 2e-4 * scale, name
     counters = HybridLM.step_counters(sown)
-    assert set(counters) == {"moe_held_assignments", "moe_held_load_max"}
+    assert set(counters) == {"moe_held_assignments", "moe_held_load_max",
+                             "moe_held_rows_walked"}
     assert 0 < float(counters["moe_held_load_max"]) <= float(counters["moe_held_assignments"])
+    assert float(counters["moe_held_assignments"]) <= float(counters["moe_held_rows_walked"])
 
 
 def test_through_the_kernels_it_is_the_scan_form(tiny, ids, monkeypatch):
@@ -224,9 +226,11 @@ def test_trainer_takes_it_and_its_counters_reach_metrics_and_registry(tiny, ids,
     assert history["loss"][1] < history["loss"][0]
     assert history["moe_held_assignments"][0] > 0
     assert history["moe_held_load_max"][0] <= history["moe_held_assignments"][0]
+    assert history["moe_held_assignments"][0] <= history["moe_held_rows_walked"][0]
     text = registry.exposition()
     for name, key in (("train_moe_held_assignments", "moe_held_assignments"),
-                      ("train_moe_held_load_max", "moe_held_load_max")):
+                      ("train_moe_held_load_max", "moe_held_load_max"),
+                      ("train_moe_held_rows_walked", "moe_held_rows_walked")):
         line = next(l for l in text.splitlines() if l.startswith(name + " "))
         assert float(line.split()[-1]) == pytest.approx(history[key][-1])
 
@@ -292,7 +296,7 @@ def test_a_fresh_mamba2_mixer_carries_its_state_past_a_chunk(tiny):
     assert late_change(inert) < 1e-6
 
 
-# -- the Kimi-Linear family is the parent commit's but for the first slab ------------------
+# -- the families' trees and traced steps, pinned: a change that moves one says so here ----
 
 def _digest(text):
     return hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()[:16]
@@ -305,17 +309,20 @@ def _paths(tree, dtypes=True):
 
 # sha256 of the parameter tree's (path, shape, dtype) list and of the jaxpr of
 # the loss's value and gradient (bf16, remat, matmul precision "highest" as
-# tests/conftest.py sets it), made by this test's own lines on an unpacked
-# ``git archive`` of the PARENT commit (cb2cd98, PR 31); "step" again on PR 33's
-# tree, whose KDA inverts a block's triangles side by side in six products each
-# and pulls back through them in closed form (af53ce61cfd5052e before)
-PARENT = {"tree": "c53b307284d821a5", "step": "540a2eb4e1075bfc",
-          "layer_tree": "e6935d7c7d3bcc3c", "layer": "500b56275c2b57ea"}
+# tests/conftest.py sets it), made by this test's own lines. The two trees on
+# an unpacked ``git archive`` of PR 32's PARENT commit (cb2cd98, PR 31) and
+# unmoved since: checkpoints and the benchmark's weights hang on them. The two
+# programs ("step", "layer") on PR 36's tree (the parent 23e942e, PR 35), whose
+# expert layer walks its held assignments in fine steps with float32 carries,
+# under the ``parents_loop`` trip count below (540a2eb4e1075bfc and
+# 500b56275c2b57ea before, PR 33's KDA and PR 32's one slab a pass)
+PARENT = {"tree": "c53b307284d821a5", "step": "c9fc73cbb082abba",
+          "layer_tree": "e6935d7c7d3bcc3c", "layer": "5232367fe8b46bec"}
 
 
 @pytest.fixture(params=["parents_loop", "first_slab_always"])
 def slabs(request, monkeypatch):
-    """The expert layer's loop as it is, and with the parent's trip count
+    """The expert layer's loop as it is, and with PR 31's least trip count
     (``ceil(load / rows)``, nought at a load of nought) in its place."""
     if request.param == "parents_loop":
         monkeypatch.setattr(moe, "_slabs_walked", lambda total, rows: -(-total // rows))
@@ -325,8 +332,9 @@ def slabs(request, monkeypatch):
 def test_the_toy_kimi_decoder_keeps_the_parents_tree_and_traced_step(slabs):
     """A layer that may lack its attention or its FFN, two more mixers and a
     second family of keys in ``config_from_file`` change nothing a
-    ``kimi_linear`` file builds: same leaves, and the same program but for the
-    expert layers' first slab, which is walked whatever the load."""
+    ``kimi_linear`` file builds: the leaves PR 31 had, and the program pinned
+    above but for the expert layers' first step, which is walked whatever the
+    load."""
     model = HybridLM(config_from_file(load(TINY_KIMI), dtype=jnp.bfloat16, remat=True))
     ids = jnp.zeros((2, 128), jnp.int32)
     tree = nn.unbox(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))["params"])
@@ -342,14 +350,17 @@ def test_the_toy_kimi_decoder_keeps_the_parents_tree_and_traced_step(slabs):
 
 
 # sha256 of the same two things for the toy Nemotron-H decoder, made by the test's
-# own lines on an unpacked ``git archive`` of PR 35's PARENT commit (1883bd5, PR 33)
-PARENT_NEMOTRON = {"tree": "67e4567cd2412ebd", "step": "666d53fc8302499e"}
+# own lines: the tree on an unpacked ``git archive`` of PR 35's PARENT commit
+# (1883bd5, PR 33), the step on PR 36's tree (666d53fc8302499e before, with the
+# expert layers' one slab a pass)
+PARENT_NEMOTRON = {"tree": "67e4567cd2412ebd", "step": "1979d6cac49af06d"}
 
 
 def test_the_toy_nemotron_decoder_keeps_the_parents_tree_and_traced_step():
     """Two more attention kinds, the sandwich norms, the embedding's scale and
     a third family of keys in ``config_from_file`` (PR 35) change nothing a
-    ``nemotron_h`` file builds: same leaves, same program."""
+    ``nemotron_h`` file builds: the leaves PR 33 had, and the program pinned
+    above."""
     model = HybridLM(config_from_file(load(TINY), dtype=jnp.bfloat16, remat=True))
     ids = jnp.zeros((2, 128), jnp.int32)
     tree = nn.unbox(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))["params"])
