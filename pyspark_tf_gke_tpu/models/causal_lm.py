@@ -40,6 +40,7 @@ from pyspark_tf_gke_tpu.models.bert import _data_shards, _dense
 from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
 from pyspark_tf_gke_tpu.parallel.sharding import mesh_extent_for
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
+from pyspark_tf_gke_tpu.ops.pallas.scope import part_scope
 
 NEG_INF = -1e30
 
@@ -532,27 +533,29 @@ class CausalLMBlock(nn.Module):
     @nn.compact
     def __call__(self, hidden, positions=None, segment_ids=None):
         cfg = self.cfg
-        attn_in = _ln(cfg, self.mesh, name="ln_attn")(hidden)
-        hidden = hidden + CausalSelfAttention(cfg, self.mesh, name="attention")(
-            attn_in, decode=self.decode, prefill=self.prefill,
-            positions=positions, segment_ids=segment_ids,
-            slot_decode=self.slot_decode,
-        )
-        mlp_in = _ln(cfg, self.mesh, name="ln_mlp")(hidden)
-        if cfg.ffn == "swiglu":
-            gate = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg,
-                          name="mlp_gate")(mlp_in)
-            up = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg,
-                        name="mlp_in")(mlp_in)
-            mlp = nn.silu(gate) * up
-        elif cfg.ffn == "gelu":
-            mlp = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg,
-                         name="mlp_in")(mlp_in)
-            mlp = nn.gelu(mlp, approximate=True)
-        else:
-            raise ValueError(f"ffn must be 'gelu' or 'swiglu', got {cfg.ffn!r}")
-        mlp = _dense(cfg.hidden_size, ("mlp", "embed"), cfg, name="mlp_out")(mlp)
-        return hidden + mlp
+        with part_scope("mixer"):
+            attn_in = _ln(cfg, self.mesh, name="ln_attn")(hidden)
+            hidden = hidden + CausalSelfAttention(cfg, self.mesh, name="attention")(
+                attn_in, decode=self.decode, prefill=self.prefill,
+                positions=positions, segment_ids=segment_ids,
+                slot_decode=self.slot_decode,
+            )
+        with part_scope("ffn"):
+            mlp_in = _ln(cfg, self.mesh, name="ln_mlp")(hidden)
+            if cfg.ffn == "swiglu":
+                gate = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg,
+                              name="mlp_gate")(mlp_in)
+                up = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg,
+                            name="mlp_in")(mlp_in)
+                mlp = nn.silu(gate) * up
+            elif cfg.ffn == "gelu":
+                mlp = _dense(cfg.intermediate_size, ("embed", "mlp"), cfg,
+                             name="mlp_in")(mlp_in)
+                mlp = nn.gelu(mlp, approximate=True)
+            else:
+                raise ValueError(f"ffn must be 'gelu' or 'swiglu', got {cfg.ffn!r}")
+            mlp = _dense(cfg.hidden_size, ("mlp", "embed"), cfg, name="mlp_out")(mlp)
+            return hidden + mlp
 
 
 class CausalLM(nn.Module):
@@ -608,17 +611,18 @@ class CausalLM(nn.Module):
         )
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-        if cfg.pos_embedding == "rope":
-            hidden = embed(input_ids, one_hot=one_hot)
-        else:
-            pos_embed = TokenEmbed(
-                cfg.max_seq_len, cfg.hidden_size, dtype=cfg.dtype,
-                embedding_init=nn.with_logical_partitioning(
-                    nn.initializers.normal(stddev=0.02), (None, "embed")),
-                name="wpe",
-            )
-            hidden = (embed(input_ids, one_hot=one_hot)
-                      + pos_embed(positions, one_hot=one_hot))
+        with part_scope("embed"):
+            if cfg.pos_embedding == "rope":
+                hidden = embed(input_ids, one_hot=one_hot)
+            else:
+                pos_embed = TokenEmbed(
+                    cfg.max_seq_len, cfg.hidden_size, dtype=cfg.dtype,
+                    embedding_init=nn.with_logical_partitioning(
+                        nn.initializers.normal(stddev=0.02), (None, "embed")),
+                    name="wpe",
+                )
+                hidden = (embed(input_ids, one_hot=one_hot)
+                          + pos_embed(positions, one_hot=one_hot))
 
         block_cls = CausalLMBlock
         if cfg.remat and not (decode or prefill):
@@ -633,16 +637,17 @@ class CausalLM(nn.Module):
                                slot_decode=slot_decode,
                                name=f"layer_{i}")(hidden, rope_pos,
                                                   segment_ids)
-        hidden = _ln(cfg, self.mesh, name="ln_final")(hidden)
-        head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, name="lm_head")
-        if return_hidden:
-            # Chunked-CE training path (ops/chunked_ce.py): the caller
-            # applies the head weight chunk-by-chunk inside the loss, so
-            # full [B,S,V] logits never materialize. Touch the head on a
-            # single position so its params exist under init.
-            head(hidden[:, :1])
-            return hidden
-        return head(hidden).astype(jnp.float32)
+        with part_scope("head_loss"):
+            hidden = _ln(cfg, self.mesh, name="ln_final")(hidden)
+            head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, name="lm_head")
+            if return_hidden:
+                # Chunked-CE training path (ops/chunked_ce.py): the caller
+                # applies the head weight chunk-by-chunk inside the loss, so
+                # full [B,S,V] logits never materialize. Touch the head on a
+                # single position so its params exist under init.
+                head(hidden[:, :1])
+                return hidden
+            return head(hidden).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -87,6 +87,7 @@ from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
 from pyspark_tf_gke_tpu.models.moe import HeldExpertsLayer, SwiGLU
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
 from pyspark_tf_gke_tpu.ops.linear_attention import kda
+from pyspark_tf_gke_tpu.ops.pallas.scope import part_scope
 from pyspark_tf_gke_tpu.ops.state_space import ssd
 
 NOT_SERVED = ("HybridLM has no decode or prefill path, whatever its layers' kinds: the "
@@ -564,24 +565,26 @@ class HybridBlock(nn.Module):
             return norm(name)(out) if cfg.sandwich_norms else out
 
         if kind != "none":
-            hidden = hidden + after("ln_post_attn", MIXERS[kind](
-                cfg, self.mesh, name="attention")(norm("ln_attn")(hidden)))
+            with part_scope("mixer"):
+                hidden = hidden + after("ln_post_attn", MIXERS[kind](
+                    cfg, self.mesh, name="attention")(norm("ln_attn")(hidden)))
         if ffn == "none":
             return hidden
-        m = norm("ln_mlp")(hidden)
-        if ffn == "dense":
-            return hidden + after("ln_post_mlp", SwiGLU(
-                cfg.hidden_size, cfg.intermediate_size, cfg.dtype, name="mlp")(m))
-        out, counters = HeldExpertsLayer(
-            num_experts=cfg.num_experts, held=cfg.experts_held,
-            top_k=cfg.experts_per_token, hidden_size=cfg.hidden_size,
-            intermediate_size=cfg.expert_intermediate_size,
-            route_scale=cfg.route_scale, shared=cfg.shared_experts,
-            dtype=cfg.dtype, activation=cfg.expert_activation,
-            shared_width=cfg.shared_intermediate_size, name="mlp")(m)
-        for name, value in counters.items():
-            self.sow("counters", name, value)
-        return hidden + after("ln_post_mlp", out)
+        with part_scope("ffn"):
+            m = norm("ln_mlp")(hidden)
+            if ffn == "dense":
+                return hidden + after("ln_post_mlp", SwiGLU(
+                    cfg.hidden_size, cfg.intermediate_size, cfg.dtype, name="mlp")(m))
+            out, counters = HeldExpertsLayer(
+                num_experts=cfg.num_experts, held=cfg.experts_held,
+                top_k=cfg.experts_per_token, hidden_size=cfg.hidden_size,
+                intermediate_size=cfg.expert_intermediate_size,
+                route_scale=cfg.route_scale, shared=cfg.shared_experts,
+                dtype=cfg.dtype, activation=cfg.expert_activation,
+                shared_width=cfg.shared_intermediate_size, name="mlp")(m)
+            for name, value in counters.items():
+                self.sow("counters", name, value)
+            return hidden + after("ln_post_mlp", out)
 
 
 class HybridLM(nn.Module):
@@ -607,24 +610,27 @@ class HybridLM(nn.Module):
                 "HybridLM takes no segment_ids yet: packed documents would have "
                 "to reset the recurrent state, KDA's or the state-space scan's, "
                 "inside a row (ROADMAP Reach 4)")
-        hidden = TokenEmbed(
-            cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-            embedding_init=nn.with_logical_partitioning(
-                nn.initializers.normal(stddev=0.02), ("vocab", "embed")),
-            name="wte")(input_ids, one_hot=train)
-        if cfg.scale_embedding:
-            hidden = (hidden.astype(jnp.float32) * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
+        with part_scope("embed"):
+            hidden = TokenEmbed(
+                cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                embedding_init=nn.with_logical_partitioning(
+                    nn.initializers.normal(stddev=0.02), ("vocab", "embed")),
+                name="wte")(input_ids, one_hot=train)
+            if cfg.scale_embedding:
+                hidden = (hidden.astype(jnp.float32)
+                          * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
         block_cls = nn.remat(HybridBlock) if cfg.remat else HybridBlock
         for i in range(cfg.num_layers):
             hidden = block_cls(cfg, self.mesh, i, name=f"layer_{i}")(hidden)
-        hidden = RMSNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                         name="ln_final")(hidden)
-        head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, name="lm_head",
-                      use_bias=False)
-        if return_hidden:
-            head(hidden[:, :1])          # the head's params exist under init
-            return hidden
-        return head(hidden).astype(jnp.float32)
+        with part_scope("head_loss"):
+            hidden = RMSNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             name="ln_final")(hidden)
+            head = _dense(cfg.vocab_size, ("embed", "vocab"), cfg, name="lm_head",
+                          use_bias=False)
+            if return_hidden:
+                head(hidden[:, :1])          # the head's params exist under init
+                return hidden
+            return head(hidden).astype(jnp.float32)
 
     @staticmethod
     def step_counters(sown) -> dict:
@@ -635,7 +641,8 @@ class HybridLM(nn.Module):
         if not layers:
             return {}
         # ``sow`` keeps a tuple a name: one value a call
-        return {"moe_held_assignments": sum(c["held_assignments"][0] for c in layers),
-                "moe_held_load_max": jnp.max(jnp.stack(
-                    [c["held_load_max"][0] for c in layers])),
-                "moe_held_rows_walked": sum(c["held_rows_walked"][0] for c in layers)}
+        with part_scope("ffn"):
+            return {"moe_held_assignments": sum(c["held_assignments"][0] for c in layers),
+                    "moe_held_load_max": jnp.max(jnp.stack(
+                        [c["held_load_max"][0] for c in layers])),
+                    "moe_held_rows_walked": sum(c["held_rows_walked"][0] for c in layers)}

@@ -29,6 +29,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from pyspark_tf_gke_tpu.ops.pallas.scope import part_scope
+
 
 class MoELayer(nn.Module):
     """Expert-parallel FFN block: ``x -> combine(expert_ffn(dispatch(x)))``.
@@ -188,7 +190,10 @@ def _walk(step, carry, ends, rows):
         return start + taken, steps + 1, carry
 
     nought = jnp.zeros((), ends.dtype)
-    _, steps, carry = jax.lax.while_loop(more, one, (nought, nought, carry))
+    # the loop is the step's part ``experts_walk``: entered here, where the
+    # rules of ``_held_experts`` run it, since each is traced apart from its call
+    with part_scope("experts_walk"):
+        _, steps, carry = jax.lax.while_loop(more, one, (nought, nought, carry))
     return steps, carry
 
 

@@ -1,4 +1,5 @@
-"""A stable name of its own for every Pallas kernel in the device trace.
+"""A stable name of its own for every Pallas kernel in the device trace, and
+one scope for each part of the train step (:func:`part_scope`).
 
 XLA names a Mosaic custom call after the INNERMOST name scope alone, and the
 profiler's ``XLA Ops`` events carry that instruction name (plus shapes and
@@ -36,3 +37,21 @@ def kernel_scope(kernel_name: str, caller: Optional[str] = None):
     if caller is None:
         caller = caller_scope()
     return jax.named_scope(f"{caller}.{kernel_name}" if caller else kernel_name)
+
+
+# The parts of a train step, each the name of a scope that every family enters
+# at its own sites (``part_scope``); ``benchmark/lib/scopes.py`` reads each
+# part's device time off the trace by the last ``part.<name>`` in an
+# operation's ``op_name``, so a part entered inside another wins there
+# (``experts_walk`` inside ``ffn``).
+STEP_PARTS = ("embed", "mixer", "ffn", "experts_walk", "head_loss", "optimizer")
+
+
+def part_scope(name: str):
+    """``jax.named_scope("part.<name>")`` for one part of the step. Only the
+    ``op_name`` metadata of what is traced under it changes: enter it around
+    a module's call, never between a ``kernel_scope`` and its launch, whose
+    name is the innermost scope."""
+    if name not in STEP_PARTS:
+        raise ValueError(f"unknown step part {name!r}; the parts are {STEP_PARTS}")
+    return jax.named_scope(f"part.{name}")

@@ -34,6 +34,7 @@ from pyspark_tf_gke_tpu.obs.compiles import install_compile_listener, on_compile
 from pyspark_tf_gke_tpu.obs.events import get_event_log
 from pyspark_tf_gke_tpu.obs.metrics import get_registry, platform_families
 from pyspark_tf_gke_tpu.obs.trace import annotate, get_tracer, span
+from pyspark_tf_gke_tpu.ops.pallas.scope import part_scope
 from pyspark_tf_gke_tpu.parallel.mesh import batch_sharding
 from pyspark_tf_gke_tpu.parallel.sharding import (
     DEFAULT_MIN_SIZE,
@@ -47,6 +48,7 @@ from pyspark_tf_gke_tpu.train.losses import (
     softmax_cross_entropy,
 )
 from pyspark_tf_gke_tpu.train.state import TrainState
+from pyspark_tf_gke_tpu.utils.compile_cache import key_on_names
 from pyspark_tf_gke_tpu.utils.logging import get_logger
 
 logger = get_logger("train.trainer")
@@ -454,6 +456,7 @@ class Trainer:
 
     def _build_steps(self):
         model, task = self.model, self.task
+        key_on_names()     # the steps' part scopes are read off their executables
 
         def train_step(state: TrainState, batch):
             def loss_fn(params):
@@ -461,15 +464,17 @@ class Trainer:
                 if state.batch_stats is not None:
                     variables["batch_stats"] = state.batch_stats
                 preds, new_batch_stats = task.forward(model, variables, batch, True, True)
-                loss, metrics = task.loss_and_metrics(preds, batch)
+                with part_scope("head_loss"):
+                    loss, metrics = task.loss_and_metrics(preds, batch)
                 return loss, (metrics, new_batch_stats)
 
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
             (_, (metrics, new_batch_stats)), grads = grad_fn(state.params)
-            if task.has_batch_stats and new_batch_stats is not None:
-                state = state.apply_gradients(grads, batch_stats=new_batch_stats)
-            else:
-                state = state.apply_gradients(grads)
+            with part_scope("optimizer"):
+                if task.has_batch_stats and new_batch_stats is not None:
+                    state = state.apply_gradients(grads, batch_stats=new_batch_stats)
+                else:
+                    state = state.apply_gradients(grads)
             return state, metrics
 
         def eval_step(state: TrainState, batch):
@@ -506,7 +511,8 @@ class Trainer:
                 if state.batch_stats is not None:
                     variables["batch_stats"] = state.batch_stats
                 preds, new_bs = task.forward(model, variables, batch, True, True)
-                loss, metrics = task.loss_and_metrics(preds, batch)
+                with part_scope("head_loss"):
+                    loss, metrics = task.loss_and_metrics(preds, batch)
                 return loss, (metrics, new_bs)
 
             (_, (metrics, new_bs)), grads = jax.value_and_grad(
@@ -520,12 +526,13 @@ class Trainer:
             return state.apply_gradients(grads)
 
         def apply_mean(state: TrainState, grads_sum, bs_sum, accum):
-            grads = jax.tree.map(lambda g: g / accum, grads_sum)
-            bs = (
-                None if bs_sum is None
-                else jax.tree.map(lambda b: b / accum, bs_sum)
-            )
-            return apply_step(state, grads, bs)
+            with part_scope("optimizer"):
+                grads = jax.tree.map(lambda g: g / accum, grads_sum)
+                bs = (
+                    None if bs_sum is None
+                    else jax.tree.map(lambda b: b / accum, bs_sum)
+                )
+                return apply_step(state, grads, bs)
 
         param_shardings = (
             self.state_shardings.params if self.state_shardings is not None else None
@@ -777,6 +784,10 @@ class Trainer:
                     epoch_span.set("dispatch_ms", self._dispatch.total * 1e3)
                     epoch_span.set(
                         "sync_ms", (self._first_sync.total + self._sync.total) * 1e3)
+                    # the expert layers' counters, summed over the epoch's steps
+                    for k, v in sums_host.items():
+                        if k.startswith("moe_held_"):
+                            epoch_span.set(k, v)
 
                 for k, v in sums_host.items():
                     history.setdefault(k, []).append(v / steps_per_epoch)

@@ -13,6 +13,8 @@ runs: never a tempfile, pid or timestamp path. Two cases only:
   that keeps a warm cache across jobs) owns the location.
 * unset — one fixed directory inside the checkout, ``<repo>/.jax_cache``
   (git-ignored), shared by every process started from this tree.
+
+:func:`key_on_names` makes the key hold each instruction's metadata too.
 """
 
 from __future__ import annotations
@@ -35,3 +37,14 @@ def enable_compile_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR
+
+
+def key_on_names() -> None:
+    """Key the persistent cache on the programs' metadata as well. JAX strips
+    it from the key by default, so a program that differs from a cached one
+    only in its names (the train step's part scopes, ``ops/pallas/scope.py::
+    part_scope``, which a trace is read by) would load the cached executable
+    with the other program's names."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)

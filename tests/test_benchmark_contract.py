@@ -4,12 +4,15 @@ today: one case for each (per-layer metric, cell) pair of ``BENCHMARK.json``.
 A reader (``benchmark/metrics/<name>.py``) finds its events by names the
 program chooses: the jitted step's name, the Pallas kernels' names behind the
 scope of the method that launches them, the trainer's annotations and ring
-spans, JAX's compile spans under them, the step's counters. ``benchmark/tests``
+spans, JAX's compile spans under them, the step's counters and the parts of
+the step that ``ops/pallas/scope.py::part_scope`` names. ``benchmark/tests``
 checks the readers against recorded traces and is not in tier-1; nothing there
 sees a rename in the program, which would read ``null`` on the chip. Here the
 reader's side is its module constants, loaded from its file, and the program's
 side is a trainer of the cell's family at the toy size of
 ``benchmark/tests/data``, built as the cell's runner builds it.
+``tests/test_step_parts.py`` compiles the same toy steps and holds every
+operation of them to a part.
 
 Not asserted: ``tpu_custom_call``, the second half of every ``KERNEL`` pair.
 It is the chip's name for a Mosaic call (``tests/test_tpu_compile.py`` has it
@@ -36,6 +39,7 @@ sys.path.insert(0, BENCH)                    # the readers import ``lib``
 
 from lib import spans as S  # noqa: E402
 from lib import trace as T  # noqa: E402
+from pyspark_tf_gke_tpu.ops.pallas.scope import STEP_PARTS  # noqa: E402
 
 STEPS = 2                                    # of the traced ``fit``
 # a cell's runner -> its family's toy configuration and the toy traffic it runs under
@@ -157,6 +161,7 @@ def written(runner, trace_dir):
     return {
         # as the trace's ``XLA Modules`` line names an execution
         "programs": [(name, 0, 0) for name in re.findall(r"module @(\S+)", lowered)],
+        "parts": set(re.findall(r"part\.(\w+)", lowered)),
         "kernels": [(name, 0, 0) for name in launches(lowered)],
         "host": S.load_host(trace_dir),
         "ring": tracer.traces(limit=1 << 20),
@@ -218,6 +223,25 @@ def test_reader_keys_on_what_the_program_writes(metric, cell, family):
         checked += 1
         assert S.last_root(wrote["ring"], mod.ROOT), (
             f"{metric}: no root span {mod.ROOT!r} in the trainer's ring")
+    if hasattr(mod, "EPOCH"):
+        checked += 1
+        trace, root = S.last_root(wrote["ring"], mod.ROOT)
+        epoch = [s for s in S.children(trace, root) if s["name"] == mod.EPOCH][-1]
+        assert {mod.REAL, mod.WALKED} <= set(epoch["attrs"]), (
+            f"{metric}: the last {mod.EPOCH!r} span holds {sorted(epoch['attrs'])}")
+    parts = set(getattr(mod, "PARTS", ()))
+    if parts:
+        # each part is the program's, each family has one of them at least
+        # (Nemotron's and GPT-2's FFN has no walk), and some family every one
+        checked += 1
+        assert parts <= set(STEP_PARTS), f"{metric}: {parts} are not all of {STEP_PARTS}"
+        assert parts & wrote["parts"], (
+            f"{metric}: none of {parts} in the lowered step, which has {sorted(wrote['parts'])}")
+        cells = next(m.get("workloads") or [c["name"] for c in BENCHMARK["workloads"]]
+                     for m in BENCHMARK["per_layer"] if m["name"] == metric)
+        seen = set().union(*(family(_json(BENCH, "cells", c + ".json")["runner"])["parts"]
+                             for c in cells))
+        assert parts <= seen, f"{metric}: no family of its cells has part.{parts - seen}"
     for name in getattr(mod, "SPANS", ()):
         checked += 1
         assert S.union_under(wrote["ring"], (name,), mod.UNDER) is not None, (
