@@ -71,7 +71,12 @@ matmuls with the next one's vector work (PERF.md §6, PR 26); hence a
 sequence of up to ``WHOLE_SEQ`` positions is one grid block: a head is one
 grid step with no dynamic loop at all. Longer sequences keep the 512-row
 grid block, and causal ones walk their full tiles in a ``fori_loop`` whose
-bounds depend on the grid block.
+bounds depend on the grid block. Inside a ``mirrored`` window every block
+with an edge square takes the same number of full steps, ``window / walk -
+block / walk`` from a start that moves with the block: they stand unrolled
+(up to ``UNROLL``) in the ``lax.cond`` branch that holds the edge strips,
+and the loop is left to the first blocks of rows (the last of keys), whose
+edge square lies off the sequence (``_Schedule.looped_steps``).
 
 Backward: ``jax.custom_vjp`` with **Pallas backward kernels** — the
 forward additionally emits the per-row logsumexp ``L = m + log(l)``, and
@@ -195,6 +200,30 @@ class _Schedule(NamedTuple):
         reach = self.window // self.block
         return i < self.s // self.block - reach if self.walks_rows else i >= reach
 
+    def edge_steps(self) -> int:
+        """The full steps of every block with an edge square (``mirrored``):
+        the window's steps less the block's own, from a start that moves with
+        the block. Checked against :meth:`full_steps` for each such block."""
+        n = self.window // self.walk - self.block // self.walk
+        with jax.ensure_compile_time_eval():   # on the host, inside a kernel's trace too
+            for i in range(self.s // self.block):
+                lo, hi = self.full_steps(i)
+                if self.has_edge(i) and int(hi) - int(lo) != n:
+                    raise ValueError(f"block {i} of {self} takes {int(hi) - int(lo)} "
+                                     f"full steps beside its edge square, not {n}")
+        return n
+
+    def looped_steps(self):
+        """(unrolled, looped) full steps a head: those :func:`_sweep` lays out
+        as straight-line code beside the edge strips, and those it walks in a
+        ``fori_loop`` whose bounds are traced."""
+        blocks = range(self.s // self.block)
+        total = sum(int(hi) - int(lo) for lo, hi in map(self.full_steps, blocks))
+        if not (self.mirrored and self.edge_steps() <= UNROLL):
+            return 0, total
+        unrolled = self.edge_steps() * sum(bool(self.has_edge(i)) for i in blocks)
+        return unrolled, total - unrolled
+
     def counts(self):
         """(computed, through the mask, thrown away) score elements a head."""
         if not self.causal:
@@ -235,10 +264,12 @@ def _sweep(sched: _Schedule, i, carry, piece):
     is the block's) of the block's positions ``sub`` from ``width`` positions
     of the walk axis at ``start``. The full steps take the whole block; then
     each strip of the edge square (a ``mirrored`` window's) and of the
-    diagonal square takes its sub-block's slice. ``mask`` is ``None`` or the
-    strip's one masked tile, ``(triangle, whether it is the strip's last)``;
-    ``band`` is ``None`` or, for a window that is not ``mirrored``, what
-    :func:`_scores` masks every tile by."""
+    diagonal square takes its sub-block's slice. A block with an edge square
+    takes its full steps, a static count, unrolled in the same ``lax.cond``
+    branch as the edge strips; one without walks them in the loop. ``mask``
+    is ``None`` or the strip's one masked tile, ``(triangle, whether it is
+    the strip's last)``; ``band`` is ``None`` or, for a window that is not
+    ``mirrored``, what :func:`_scores` masks every tile by."""
     whole = slice(0, sched.block)
     banded = sched.window is not None and not sched.mirrored
 
@@ -258,7 +289,17 @@ def _sweep(sched: _Schedule, i, carry, piece):
     # a few steps with static bounds unroll into one basic block, where the
     # scheduler overlaps a step's matmuls with its neighbour's vector work
     few = isinstance(lo, int) and isinstance(hi, int) and hi - lo <= UNROLL
-    carry = jax.lax.fori_loop(lo, hi, step, carry, unroll=few or None)
+
+    def walk(carry, n=None):
+        # the full steps: ``n`` of them from ``lo`` unrolled, or the loop
+        if n is None:
+            return jax.lax.fori_loop(lo, hi, step, carry, unroll=few or None)
+        for j in range(n):
+            carry = step(lo + j, carry)
+        return carry
+
+    if not sched.mirrored:
+        carry = walk(carry)
     if not sched.causal:
         return carry
     tri = _triangle(sched.tile, keys_first=sched.walks_rows)
@@ -274,11 +315,12 @@ def _sweep(sched: _Schedule, i, carry, piece):
 
     if sched.mirrored:
         reach = sched.window if sched.walks_rows else -sched.window
+        n = sched.edge_steps()
         carry = jax.lax.cond(
             sched.has_edge(i),
-            lambda c: square(c, corner + reach, sched.edge_strips(),
-                             (~tri, sched.walks_rows)),
-            lambda c: c, carry)
+            lambda c: square(walk(c, n if n <= UNROLL else None), corner + reach,
+                             sched.edge_strips(), (~tri, sched.walks_rows)),
+            walk, carry)
     return square(carry, corner, sched.strips(), (tri, not sched.walks_rows))
 
 

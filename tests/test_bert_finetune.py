@@ -23,10 +23,13 @@ def _clean_env(monkeypatch):
 
 def _write_shards(tmp_path, n=192, vocab=96):
     rng = np.random.default_rng(0)
+    ids = rng.integers(0, vocab, (n, SEQ)).astype(np.int64)
+    # a label the model can learn (the first token's parity): random labels
+    # read by the reader's threads in another order give no falling loss
     arrays = {
-        "input_ids": rng.integers(0, vocab, (n, SEQ)).astype(np.int64),
+        "input_ids": ids,
         "attention_mask": np.ones((n, SEQ), dtype=np.int64),
-        "label": rng.integers(0, 2, (n,)).astype(np.int64),
+        "label": ids[:, 0] % 2,
     }
     prefix = str(tmp_path / "shards" / "train")
     write_tfrecord_shards(arrays, prefix, num_shards=4)

@@ -2,7 +2,9 @@
 kernels in interpret mode against dense attention for windows under, at, a
 multiple of and not a multiple of the grid block and of the sequence's length
 or more; the schedule's count of what it computes against a count of the mask;
-the windowed launches' names; and with no window the launches are the
+which full steps a mirrored window unrolls beside its edge strips, in the
+schedule and in the launches' programs; the windowed launches' names; and with
+no window the launches are the
 parent's, operation for operation (``tests/test_flash_value_width.py`` holds
 their digests)."""
 
@@ -44,8 +46,9 @@ def test_the_dense_window_is_the_rows_own_position_and_those_before_it():
 # (S, block, D, Dv): one tile a block at the hybrid decoders' widths (GQA 128, MLA
 # 192 / 128) and two tiles a block at 64
 SHAPES = {"d128": (512, 128, 128, 128), "mla": (512, 128, 192, 128), "d64": (1024, 256, 64, 64)}
-# windows by what they are to the block: under it, the block, a multiple, not one
-WINDOWS = {"d128": (64, 128, 256, 192), "mla": (128, 384), "d64": (128, 256, 512, 384)}
+# windows by what they are to the block: under it, the block, a multiple, not one;
+# then a multiple whose edge branch unrolls two full steps
+WINDOWS = {"d128": (64, 128, 256, 192, 384), "mla": (128, 384), "d64": (128, 256, 512, 384, 768)}
 
 
 @pytest.mark.parametrize("shape,window", [(n, w) for n in SHAPES for w in WINDOWS[n]])
@@ -88,6 +91,76 @@ def test_a_window_layer_of_the_cell_computes_under_half_of_the_causal_schedule()
     assert windowed[0] - windowed[2] == 2048 * 2049 // 2 + 6144 * 2048 == 14_681_088
     assert causal[0] - causal[2] == 8192 * 8193 // 2
     assert 0.45 < windowed[0] / causal[0] < 0.46
+
+
+@pytest.mark.parametrize("window,steps", [(2048, (36, 6)), (None, (0, 120)), (1000, (0, 29))],
+                         ids=["mirrored", "no_window", "banded"])
+@pytest.mark.parametrize("walks_rows", [False, True], ids=["walks_keys", "walks_rows"])
+def test_a_window_layer_of_the_cell_unrolls_the_full_steps_beside_its_edge_strips(
+        window, steps, walks_rows):
+    """At the cell's shape each block with an edge square (12 of 16) takes
+    three full steps in straight-line code; the four without one loop over
+    0 + 1 + 2 + 3. Without a mirrored window every full step is looped. The
+    work is the same: ``counts`` is what the schedule computed before."""
+    sched = F._schedule(8192, 512, 512, True, walks_rows, window)
+    assert sched.looped_steps() == steps
+    if window == 2048:
+        assert sched.edge_steps() == 3
+        assert sched.counts() == (15_597_568, 1_835_008, 916_480)
+
+
+def test_a_static_count_that_disagrees_with_the_schedule_is_refused():
+    """A walk wider than the grid block (no ``_schedule`` makes one) leaves a
+    block no full step, where the window's count says two."""
+    sched = F._Schedule(8192, 512, 1024, 128, True, False, window=2048)
+    with pytest.raises(ValueError, match="0 full steps beside its edge square, not 2"):
+        sched.edge_steps()
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _count(jaxpr, name):
+    """Equations named ``name`` in ``jaxpr`` and every jaxpr inside it."""
+    return sum((e.primitive.name == name) + sum(_count(j, name) for j in _subjaxprs(e))
+               for e in jaxpr.eqns)
+
+
+def _kernels(jaxpr):
+    """The body of every ``pallas_call`` in ``jaxpr``, in launch order."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            yield e.params["jaxpr"]
+        else:
+            for j in _subjaxprs(e):
+                yield from _kernels(j)
+
+
+def test_a_mirrored_launch_unrolls_its_full_steps_inside_the_edge_branch():
+    """S 2048, block 512, window 1024: one full step beside the edge square's
+    four strips in the ``cond``'s true branch, the loop alone in its false
+    branch, and nothing looped outside the ``cond``."""
+    x = jax.ShapeDtypeStruct((4, 2048, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((4, 1, 2048), jnp.float32)
+    kw = dict(causal=True, block_q=512, block_k=512, interpret=False, caller="a", window=1024)
+    fwd = jax.make_jaxpr(lambda q, k, v: F._fwd_call(q, k, v, None, None, **kw))(x, x, x)
+    bwd = jax.make_jaxpr(lambda q, k, v, l, o, do: F._bwd_call(
+        q, k, v, None, l, o, do, None, None, **kw))(x, x, x, lse, x, x)
+    bodies = [*_kernels(fwd.jaxpr), *_kernels(bwd.jaxpr)]
+    # products a piece: q k and p v forward; q k, dO v and ds k in dQ; k q, p dO,
+    # v dO and ds q in dK/dV
+    for body, products in zip(bodies, (2, 3, 4), strict=True):
+        (cond,) = [e for e in body.eqns if e.primitive.name == "cond"]
+        assert not [e for e in body.eqns if e.primitive.name == "while"]
+        looped, unrolled = (b.jaxpr for b in cond.params["branches"])
+        assert (_count(looped, "while"), _count(unrolled, "while")) == (1, 0)
+        assert _count(looped, "dot_general") == products
+        assert _count(unrolled, "dot_general") == (1 + 4) * products
 
 
 def _launches(window, s=2048, block=512, caller="a"):
