@@ -87,7 +87,7 @@ from pyspark_tf_gke_tpu.models.embedding import TokenEmbed
 from pyspark_tf_gke_tpu.models.moe import HeldExpertsLayer, SwiGLU
 from pyspark_tf_gke_tpu.ops.attention import dot_product_attention
 from pyspark_tf_gke_tpu.ops.linear_attention import kda
-from pyspark_tf_gke_tpu.ops.pallas.scope import part_scope
+from pyspark_tf_gke_tpu.ops.pallas.scope import KERNEL_RESULT_NAMES, part_scope
 from pyspark_tf_gke_tpu.ops.state_space import ssd
 
 NOT_SERVED = ("HybridLM has no decode or prefill path, whatever its layers' kinds: the "
@@ -619,7 +619,12 @@ class HybridLM(nn.Module):
             if cfg.scale_embedding:
                 hidden = (hidden.astype(jnp.float32)
                           * math.sqrt(cfg.hidden_size)).astype(cfg.dtype)
-        block_cls = nn.remat(HybridBlock) if cfg.remat else HybridBlock
+        # Under remat a block keeps what its mixer kernels' forward launches
+        # gave (flash's out and lse, KDA's o and states, the scan's y and
+        # kept): the backward's rerun of the block recomputes the rest, the
+        # kernels' inputs among it, and launches no forward kernel again.
+        block_cls = nn.remat(HybridBlock, policy=jax.checkpoint_policies.save_only_these_names(
+            *KERNEL_RESULT_NAMES)) if cfg.remat else HybridBlock
         for i in range(cfg.num_layers):
             hidden = block_cls(cfg, self.mesh, i, name=f"layer_{i}")(hidden)
         with part_scope("head_loss"):

@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu
+from pyspark_tf_gke_tpu.ops.pallas.scope import name_results
 
 CHUNK = 64
 SUB = 16
@@ -401,6 +402,8 @@ def _kda_core(q, k, v, g, beta, conv, heads, eps, mxu, pallas, interpret):
 
 
 def _core_fwd(q, k, v, g, beta, conv, heads, eps, mxu, pallas, interpret):
+    """``o`` and ``states`` are named for a remat policy (``scope.KERNEL_RESULTS``):
+    a block rematted with it keeps them, and its backward runs no forward again."""
     if pallas:
         from pyspark_tf_gke_tpu.ops.pallas import kda as kernels
 
@@ -408,6 +411,7 @@ def _core_fwd(q, k, v, g, beta, conv, heads, eps, mxu, pallas, interpret):
                                     interpret=interpret)
     else:
         o, states = _fwd_scan(q, k, v, g, beta, conv, heads, eps, mxu)
+    o, states = name_results("kda", o, states)
     return o, (q, k, v, g, beta, conv, states)
 
 

@@ -106,7 +106,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu, pick_block
-from pyspark_tf_gke_tpu.ops.pallas.scope import caller_scope, kernel_scope
+from pyspark_tf_gke_tpu.ops.pallas.scope import caller_scope, kernel_scope, name_results
 
 NEG_INF = -1e30
 
@@ -700,9 +700,12 @@ def _flash_bh(q, k, v, bias, segs, causal, block_q, block_k, interpret,
 
 def _flash_bh_fwd(q, k, v, bias, segs, causal, block_q, block_k, interpret,
                   window):
-    out, lse = _flash_fwd_bh(q, k, v, bias, segs, causal=causal,
-                             block_q=block_q, block_k=block_k,
-                             interpret=interpret, window=window)
+    """The launch's ``out`` and ``lse`` are named for a remat policy
+    (``scope.KERNEL_RESULTS``): a block rematted with it keeps them, and its
+    backward does not launch the forward again."""
+    out, lse = name_results("flash", *_flash_fwd_bh(
+        q, k, v, bias, segs, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret, window=window))
     return out, (q, k, v, bias, segs, lse, out)
 
 
@@ -727,9 +730,10 @@ def _flash_bh_lse(q, k, v, bias, segs, causal, block_q, block_k, interpret):
 
 
 def _flash_bh_lse_fwd(q, k, v, bias, segs, causal, block_q, block_k, interpret):
-    out, lse = _flash_fwd_bh(q, k, v, bias, segs, causal=causal,
-                             block_q=block_q, block_k=block_k,
-                             interpret=interpret)
+    """``out`` and ``lse`` named for a remat policy, as in ``_flash_bh_fwd``."""
+    out, lse = name_results("flash", *_flash_fwd_bh(
+        q, k, v, bias, segs, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret))
     return (out, lse), (q, k, v, bias, segs, lse, out)
 
 

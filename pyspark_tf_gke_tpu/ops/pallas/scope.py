@@ -11,11 +11,15 @@ dq and dkv cannot be told apart. ``pallas_call(name=...)`` and a plain
 method that readers of older traces match. :func:`kernel_scope` therefore
 joins the kernel's name to the scope it is called from:
 ``%attention._causal_attend.flash_fwd.N``.
+
+The mixer kernels' forward results carry names of their own as well
+(:data:`KERNEL_RESULTS`), for a remat policy to keep.
 """
 
 from typing import Optional
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 from jax.extend import source_info_util
 
 
@@ -37,6 +41,25 @@ def kernel_scope(kernel_name: str, caller: Optional[str] = None):
     if caller is None:
         caller = caller_scope()
     return jax.named_scope(f"{caller}.{kernel_name}" if caller else kernel_name)
+
+
+# What each mixer kernel's forward launch gives its backward, one name a result.
+# Each custom-VJP forward rule names exactly what its launch returned
+# (:func:`name_results`); a remat policy that saves these names keeps them, so
+# the backward's rerun of a block does not launch the forward again
+# (``models/hybrid_lm.py::HybridLM``). Outside a remat a name is the identity.
+KERNEL_RESULTS = {"flash": ("flash_out", "flash_lse"),
+                  "kda": ("kda_o", "kda_states"),
+                  "ssd": ("ssd_y", "ssd_kept")}
+KERNEL_RESULT_NAMES = tuple(name for names in KERNEL_RESULTS.values() for name in names)
+
+
+def name_results(kernel: str, *results):
+    """``results`` of one forward launch of ``kernel``, each under its name in
+    :data:`KERNEL_RESULTS`: all of them, since a rerun that lacks one of a
+    launch's results launches it again for that one."""
+    return tuple(checkpoint_name(r, n) for r, n in zip(results, KERNEL_RESULTS[kernel],
+                                                          strict=True))
 
 
 # The parts of a train step, each the name of a scope that every family enters

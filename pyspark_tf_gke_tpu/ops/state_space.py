@@ -52,6 +52,7 @@ import jax.numpy as jnp
 
 from pyspark_tf_gke_tpu.ops.linear_attention import _NN, _NT, _TN, _dot
 from pyspark_tf_gke_tpu.ops.pallas.common import on_tpu
+from pyspark_tf_gke_tpu.ops.pallas.scope import name_results
 
 CHUNK = 128
 BLOCK_CHUNKS = 2          # chunks a grid step (or a scan step) takes
@@ -235,6 +236,8 @@ def _ssd_core(x, dt, a, b, c, d, heads, groups, chunk, mxu, pallas, interpret):
 
 
 def _core_fwd(x, dt, a, b, c, d, heads, groups, chunk, mxu, pallas, interpret):
+    """``y`` and ``kept`` are named for a remat policy (``scope.KERNEL_RESULTS``):
+    a block rematted with it keeps them, and its backward runs no forward again."""
     if pallas:
         from pyspark_tf_gke_tpu.ops.pallas import ssd as kernels
 
@@ -242,6 +245,7 @@ def _core_fwd(x, dt, a, b, c, d, heads, groups, chunk, mxu, pallas, interpret):
                                   mxu=mxu, interpret=interpret)
     else:
         y, kept = _fwd_scan(x, dt, a, b, c, d, heads, groups, chunk, mxu)
+    y, kept = name_results("ssd", y, kept)
     return y, (x, dt, a, b, c, d, kept)
 
 

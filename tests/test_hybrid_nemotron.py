@@ -316,7 +316,9 @@ def _paths(tree, dtypes=True):
 # expert layer walks its held assignments in fine steps with float32 carries,
 # under the ``parents_loop`` trip count below (540a2eb4e1075bfc and
 # 500b56275c2b57ea before, PR 33's KDA and PR 32's one slab a pass)
-PARENT = {"tree": "c53b307284d821a5", "step": "c9fc73cbb082abba",
+# The step is now the one whose remat keeps the mixer kernels' named forward
+# results (c9fc73cbb082abba before it).
+PARENT = {"tree": "c53b307284d821a5", "step": "a17cbe057da8602c",
           "layer_tree": "e6935d7c7d3bcc3c", "layer": "5232367fe8b46bec"}
 
 
@@ -353,7 +355,9 @@ def test_the_toy_kimi_decoder_keeps_the_parents_tree_and_traced_step(slabs):
 # own lines: the tree on an unpacked ``git archive`` of PR 35's PARENT commit
 # (1883bd5, PR 33), the step on PR 36's tree (666d53fc8302499e before, with the
 # expert layers' one slab a pass)
-PARENT_NEMOTRON = {"tree": "67e4567cd2412ebd", "step": "1979d6cac49af06d"}
+# The step is now the one whose remat keeps the mixer kernels' named forward
+# results (1979d6cac49af06d before it).
+PARENT_NEMOTRON = {"tree": "67e4567cd2412ebd", "step": "11b36227f7760bb0"}
 
 
 def test_the_toy_nemotron_decoder_keeps_the_parents_tree_and_traced_step():
